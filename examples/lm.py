@@ -64,9 +64,9 @@ def main():
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
 
-    from geomx_tpu.core.platform import apply_platform_from_env
+    from geomx_tpu.utils.compile_cache import enable_compile_cache
 
-    apply_platform_from_env()
+    enable_compile_cache()
 
     topo_cfg = Config(
         topology=Topology(num_parties=args.parties,

@@ -199,7 +199,9 @@ def _accelerator_live() -> bool:
     """True iff importing jax would land on a non-CPU backend.  Fast
     False (no jax import) when the platform env pins cpu — the tier-1
     / CI posture — so ``auto`` never pays backend-init latency on a
-    host that provably has no accelerator."""
+    host that provably has no accelerator.  A backend that fails to
+    initialize RAISES: answering "cpu" would put a broken chip's
+    training on the host path with exit 0."""
     global _accel_live
     for var in ("JAX_PLATFORMS", "JAX_PLATFORM_NAME"):
         val = os.environ.get(var, "")
@@ -208,12 +210,9 @@ def _accelerator_live() -> bool:
             return False
     with _probe_mu:
         if _accel_live is None:
-            try:
-                import jax
+            import jax
 
-                _accel_live = jax.default_backend() != "cpu"
-            except Exception:
-                _accel_live = False
+            _accel_live = jax.default_backend() != "cpu"
         return _accel_live
 
 
@@ -229,8 +228,9 @@ def resolve_merge_backend(config) -> str:
       is not replayable run-to-run.
     - ``auto`` picks jax iff an accelerator backend is live (TPU/GPU);
       plain CPU hosts keep the numpy reference path.
-    - an explicit ``jax`` on a host whose jax cannot import degrades to
-      numpy loudly at construction (:func:`make_merge_backend`)."""
+    - a resolved ``jax`` whose backend cannot be built raises at
+      construction (:func:`make_merge_backend`) — never a silent host
+      path."""
     if getattr(config, "deterministic", False):
         return "numpy"
     choice = (getattr(config, "merge_backend", "") or "").strip().lower()
@@ -281,18 +281,13 @@ def resolve_codec_device(config) -> bool:
     return True
 
 
-def make_merge_backend(config, node: str = "?") -> MergeBackend:
-    """Construct the resolved backend; an explicit-jax host whose jax
-    stack cannot build one degrades to numpy with a printed reason
-    instead of taking the server down (the merge must never be the
-    component that can't boot)."""
-    kind = resolve_merge_backend(config)
-    if kind == "jax":
-        try:
-            from geomx_tpu.kvstore.jax_backend import JaxBackend
+def make_merge_backend(config) -> MergeBackend:
+    """Construct the resolved backend.  When ``jax`` was resolved —
+    explicitly, or by ``auto`` on a live accelerator — a
+    :class:`JaxBackend` that cannot be built raises: the numpy backend
+    is a reference tests choose, not a fallback that hides the device."""
+    if resolve_merge_backend(config) == "jax":
+        from geomx_tpu.kvstore.jax_backend import JaxBackend
 
-            return JaxBackend(config)
-        except Exception as e:  # missing/broken jax: gate, don't crash
-            print(f"[{node}] merge backend 'jax' unavailable "
-                  f"({type(e).__name__}: {e}); falling back to numpy")
+        return JaxBackend(config)
     return NumpyBackend(config)

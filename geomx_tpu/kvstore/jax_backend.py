@@ -278,9 +278,8 @@ class JaxBackend(MergeBackend):
             return red
         from jax.sharding import PartitionSpec as P
 
-        from geomx_tpu.compat import shard_map
-
         jax = self._jax
+        shard_map = jax.shard_map
         mesh = self._submesh(k)
         if self._quantized and ef:
             from geomx_tpu.parallel.quantized_allreduce import (

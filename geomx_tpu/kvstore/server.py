@@ -372,8 +372,7 @@ class LocalServer:
         # numpy = the host reference path, jax = staged device merge;
         # deterministic forces numpy).  The lanes themselves are built
         # per-backend — a device backend caps how many can usefully run.
-        self._backend = make_merge_backend(self.config,
-                                           str(postoffice.node))
+        self._backend = make_merge_backend(self.config)
         # device-resident WAN codec stage (ISSUE 20): non-None iff the
         # jax backend is active and codec_device resolves on — encode
         # then reads the device merge accumulator directly and the only
@@ -2798,8 +2797,7 @@ class GlobalServer:
         # folds, failover fences, replication snapshots and policy
         # swaps — their atomicity against the data path is unchanged.
         # Lanes are built per merge backend (kvstore/backend.py).
-        self._backend = make_merge_backend(self.config,
-                                           str(postoffice.node))
+        self._backend = make_merge_backend(self.config)
         # device-resident WAN codec stage (ISSUE 20): compressed pushes
         # decode through jitted kernels straight into device arrays the
         # merge lanes seed without re-staging (zero full-tensor host

@@ -890,9 +890,9 @@ def main(argv=None):
         # slot in the TCP plan and dies with a bare KeyError at bind
         ap.error("--join requires --advertise HOST:PORT")
 
-    from geomx_tpu.core.platform import apply_platform_from_env
+    from geomx_tpu.utils.compile_cache import enable_compile_cache
 
-    apply_platform_from_env()
+    enable_compile_cache()
 
     node = NodeId.parse(args.role)
     # env supplies the full documented knob surface (drop injection,

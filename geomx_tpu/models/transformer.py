@@ -31,9 +31,8 @@ from typing import Any, Dict, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from geomx_tpu.compat import shard_map
 
 from geomx_tpu.parallel.ring_attention import (
     dense_attention, fast_dense_attention, ring_attention)
@@ -241,7 +240,8 @@ def _single_device_attention(cfg: TransformerConfig, q, k, v):
         return fast_dense_attention(q, k, v, causal=True)
     if cfg.attn_impl == "flash":
         # jax's pallas TPU flash kernel wants [B, H, T, Dh]; ours is
-        # [B, T, H, Dh].  Real-TPU only (no interpret path wired).
+        # [B, T, H, Dh].  Lowers only for a TPU (tests run it under
+        # the TPU interpreter, chip_smoke.py compiled).
         from jax.experimental.pallas.ops.tpu.flash_attention import (
             flash_attention)
 

@@ -24,8 +24,9 @@ diagonal (fully visible) and above-diagonal (fully masked) — without
 data-dependent control flow.
 
 Correctness coverage runs on CPU via pallas TPU interpret mode
-(tests/test_block_attention.py); on-chip the lane dim wants head_dim a
-multiple of 128 (the flagship's is 128).
+(tests/test_block_attention.py) and, compiled by Mosaic, on the chip
+(chip_smoke.py); on-chip the lane dim wants head_dim a multiple of 128
+(the flagship's is 128).
 
 Ref for the role this plays: the reference's fused 16:1 packing kernels
 (gradient_compression-inl.h:40-139) are its example of hot-loop kernel
@@ -72,9 +73,9 @@ def _block_attn_ref(q, k, v, offs, causal: bool):
 def _kernel(offs_ref, q_ref, k_ref, v_ref, m_ref, l_ref, o_ref, *,
             scale: float, causal: bool, bq: int, Tk: int):
     iq = pl.program_id(2)
-    q = q_ref[0, :, 0, :]    # [bq, D]
-    kk = k_ref[0, :, 0, :]   # [Tk, D]
-    vv = v_ref[0, :, 0, :]
+    q = q_ref[...]    # [bq, D]
+    kk = k_ref[...]   # [Tk, D]
+    vv = v_ref[...]
     s = lax.dot_general(q, kk, (((1,), (1,)), ((), ())),
                         preferred_element_type=jnp.float32)
     s = s * jnp.float32(scale)
@@ -83,14 +84,13 @@ def _kernel(offs_ref, q_ref, k_ref, v_ref, m_ref, l_ref, o_ref, *,
                  + lax.broadcasted_iota(jnp.int32, (bq, Tk), 0))
         k_pos = offs_ref[1] + lax.broadcasted_iota(jnp.int32, (bq, Tk), 1)
         s = jnp.where(q_pos >= k_pos, s, jnp.float32(_NEG))
-    m = jnp.max(s, axis=1)
-    p = jnp.exp(s - m[:, None])
-    l = jnp.sum(p, axis=1)
-    o = lax.dot_general(p.astype(vv.dtype), vv, (((1,), (0,)), ((), ())),
-                        preferred_element_type=jnp.float32)
-    m_ref[0, :, 0] = m
-    l_ref[0, :, 0] = l
-    o_ref[0, :, 0, :] = o
+    m = jnp.max(s, axis=1, keepdims=True)   # [bq, 1]
+    p = jnp.exp(s - m)
+    m_ref[...] = m
+    l_ref[...] = jnp.sum(p, axis=1, keepdims=True)
+    o_ref[...] = lax.dot_general(
+        p.astype(vv.dtype), vv, (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)
 
 
 def _pick_bq(Tq: int) -> int:
@@ -123,31 +123,45 @@ def _flash_fwd_impl(q, k, v, offs, causal: bool):
     grid = (B, H, Tq // bq)
     kernel = functools.partial(
         _kernel, scale=1.0 / np.sqrt(D), causal=causal, bq=bq, Tk=Tk)
+    # Mosaic tiles the LAST TWO block dims ((8, 128) for f32), so the
+    # kernel blocks over [B, H, T, D]: (T, D) trail and the per-head
+    # block is a squeezed leading dim.  Blocking the caller's
+    # [B, T, H, D] layout directly would put a size-1 head block in the
+    # sublane dim, which the TPU lowering refuses.  The row statistics
+    # keep a trailing unit dim for the same reason.
+    qh, kh, vh = (x.swapaxes(1, 2) for x in (q, k, v))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=grid,
         # index_map gets the scalar-prefetch ref appended to grid indices
         in_specs=[
-            pl.BlockSpec((1, bq, 1, D), lambda b, h, i, offs: (b, i, h, 0)),
-            pl.BlockSpec((1, Tk, 1, D), lambda b, h, i, offs: (b, 0, h, 0)),
-            pl.BlockSpec((1, Tk, 1, D), lambda b, h, i, offs: (b, 0, h, 0)),
+            pl.BlockSpec((None, None, bq, D),
+                         lambda b, h, i, offs: (b, h, i, 0)),
+            pl.BlockSpec((None, None, Tk, D),
+                         lambda b, h, i, offs: (b, h, 0, 0)),
+            pl.BlockSpec((None, None, Tk, D),
+                         lambda b, h, i, offs: (b, h, 0, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, bq, 1), lambda b, h, i, offs: (b, i, h)),
-            pl.BlockSpec((1, bq, 1), lambda b, h, i, offs: (b, i, h)),
-            pl.BlockSpec((1, bq, 1, D), lambda b, h, i, offs: (b, i, h, 0)),
+            pl.BlockSpec((None, None, bq, 1),
+                         lambda b, h, i, offs: (b, h, i, 0)),
+            pl.BlockSpec((None, None, bq, 1),
+                         lambda b, h, i, offs: (b, h, i, 0)),
+            pl.BlockSpec((None, None, bq, D),
+                         lambda b, h, i, offs: (b, h, i, 0)),
         ],
     )
     m, l, o = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=[
-            jax.ShapeDtypeStruct((B, Tq, H), jnp.float32),
-            jax.ShapeDtypeStruct((B, Tq, H), jnp.float32),
-            jax.ShapeDtypeStruct((B, Tq, H, D), jnp.float32),
+            jax.ShapeDtypeStruct((B, H, Tq, 1), jnp.float32),
+            jax.ShapeDtypeStruct((B, H, Tq, 1), jnp.float32),
+            jax.ShapeDtypeStruct((B, H, Tq, D), jnp.float32),
         ],
-    )(offs, q, k, v)
-    return m, l, o
+    )(offs, qh, kh, vh)
+    return (m[..., 0].swapaxes(1, 2), l[..., 0].swapaxes(1, 2),
+            o.swapaxes(1, 2))
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4,))

@@ -21,8 +21,6 @@ All kernels operate on flat float32 arrays padded to a multiple of
 
 from __future__ import annotations
 
-import functools
-
 import jax
 import jax.numpy as jnp
 
@@ -58,8 +56,8 @@ def _quant_kernel(g_ref, r_ref, thr_ref, packed_ref, newr_ref):
 _QROWS = 128
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def _quantize_padded(g2d, r2d, thr, interpret=False):
+@jax.jit
+def _quantize_padded(g2d, r2d, thr):
     from jax.experimental import pallas as pl
 
     rows = g2d.shape[0]
@@ -80,16 +78,14 @@ def _quantize_padded(g2d, r2d, thr, interpret=False):
             jax.ShapeDtypeStruct((rows // 4, LANES), jnp.uint8),
             jax.ShapeDtypeStruct((rows, LANES), jnp.float32),
         ),
-        interpret=interpret,
     )(g2d, r2d, thr)
 
 
 def quantize_2bit_tpu(grad: jax.Array, residual: jax.Array,
-                      threshold: float = 0.5, interpret: bool = False):
+                      threshold: float = 0.5):
     """Residual-feedback 2-bit quantization on-chip.
 
     Returns (packed uint8 [ceil(n/4*LANES)*LANES...], new_residual [n]).
-    ``interpret=True`` runs the kernel in pallas interpret mode (CPU tests).
     """
     n = grad.shape[0]
     g = _pad_to(grad.astype(jnp.float32), _QROWS * LANES)
@@ -97,8 +93,7 @@ def quantize_2bit_tpu(grad: jax.Array, residual: jax.Array,
     rows = g.shape[0] // LANES
     thr = jnp.full((1, 1), threshold, jnp.float32)
     packed, newr = _quantize_padded(
-        g.reshape(rows, LANES), r.reshape(rows, LANES), thr,
-        interpret=interpret)
+        g.reshape(rows, LANES), r.reshape(rows, LANES), thr)
     return packed.reshape(-1), newr.reshape(-1)[:n]
 
 
@@ -116,8 +111,8 @@ def _dequant_kernel(packed_ref, thr_ref, out_ref):
     out_ref[3 * quarter:4 * quarter] = decode((b >> 6) & 3)
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def _dequantize_padded(p2d, thr, interpret=False):
+@jax.jit
+def _dequantize_padded(p2d, thr):
     from jax.experimental import pallas as pl
 
     rows = p2d.shape[0] * 4
@@ -131,16 +126,14 @@ def _dequantize_padded(p2d, thr, interpret=False):
         ],
         out_specs=pl.BlockSpec((_QROWS, LANES), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((rows, LANES), jnp.float32),
-        interpret=interpret,
     )(p2d, thr)
 
 
-def dequantize_2bit_tpu(packed: jax.Array, n: int, threshold: float = 0.5,
-                        interpret: bool = False) -> jax.Array:
+def dequantize_2bit_tpu(packed: jax.Array, n: int,
+                        threshold: float = 0.5) -> jax.Array:
     prows = packed.shape[0] // LANES
     thr = jnp.full((1, 1), threshold, jnp.float32)
-    out = _dequantize_padded(packed.reshape(prows, LANES), thr,
-                             interpret=interpret)
+    out = _dequantize_padded(packed.reshape(prows, LANES), thr)
     return out.reshape(-1)[:n]
 
 
@@ -151,8 +144,8 @@ def _dgc_kernel(v_ref, u_ref, g_ref, m_ref, vout_ref, uout_ref):
     uout_ref[:] = u_ref[:] + v
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def _dgc_padded(v2d, u2d, g2d, m, interpret=False):
+@jax.jit
+def _dgc_padded(v2d, u2d, g2d, m):
     from jax.experimental import pallas as pl
 
     rows = v2d.shape[0]
@@ -167,12 +160,11 @@ def _dgc_padded(v2d, u2d, g2d, m, interpret=False):
             jax.ShapeDtypeStruct((rows, LANES), jnp.float32),
             jax.ShapeDtypeStruct((rows, LANES), jnp.float32),
         ),
-        interpret=interpret,
     )(v2d, u2d, g2d, m)
 
 
 def dgc_update_tpu(velocity: jax.Array, accum: jax.Array, grad: jax.Array,
-                   momentum: float = 0.9, interpret: bool = False):
+                   momentum: float = 0.9):
     """Fused DGC momentum-correction update (v = m·v + g; u += v) on-chip
     (the BSC inner loop, ref: gradient_compression.cc:191-269)."""
     n = grad.shape[0]
@@ -182,5 +174,5 @@ def dgc_update_tpu(velocity: jax.Array, accum: jax.Array, grad: jax.Array,
     rows = v.shape[0] // LANES
     m = jnp.full((1, 1), momentum, jnp.float32)
     vo, uo = _dgc_padded(v.reshape(rows, LANES), u.reshape(rows, LANES),
-                         g.reshape(rows, LANES), m, interpret=interpret)
+                         g.reshape(rows, LANES), m)
     return vo.reshape(-1)[:n], uo.reshape(-1)[:n]

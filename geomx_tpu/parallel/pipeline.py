@@ -18,9 +18,7 @@ from typing import Callable, Optional
 
 import jax
 import jax.numpy as jnp
-from jax import lax
-
-from geomx_tpu.compat import shard_map
+from jax import lax, shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 

@@ -30,8 +30,7 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from geomx_tpu.compat import shard_map
+from jax.sharding import Mesh
 
 BLOCK = 256  # quantization block (VPU-lane friendly; per-block scale)
 
@@ -147,44 +146,24 @@ def quantized_psum_mean_ef(x: jnp.ndarray, residual: jnp.ndarray,
 
 
 def make_party_step_quantized(grad_fn: Callable, mesh: Mesh) -> Callable:
-    """Drop-in for :func:`geomx_tpu.parallel.dp.make_party_step` that
-    reduces gradients with :func:`quantized_psum_mean` instead of the
-    fp32 all-reduce GSPMD would insert.  ``grad_fn(params, x, y) ->
-    (loss, acc, grads)``; loss/acc are mean-reduced exactly (scalars
-    are free), gradients ride the int8 wire."""
-    axis = mesh.axis_names[0]
-    n_dev = mesh.shape[axis]
-    repl = NamedSharding(mesh, P())
-    batch_sh = NamedSharding(mesh, P(axis))
+    """:func:`geomx_tpu.parallel.dp.make_party_step` with the gradients
+    reduced by :func:`quantized_psum_mean` instead of the fp32
+    all-reduce.  ``grad_fn(params, x, y) -> (loss, acc, grads)``;
+    loss/acc are mean-reduced exactly (scalars are free), gradients
+    ride the int8 wire."""
+    from geomx_tpu.parallel.dp import make_party_step
 
-    def local(params, x, y):
-        loss, acc, grads = grad_fn(params, x, y)
-        loss = jax.lax.pmean(loss, axis)
-        acc = jax.lax.pmean(acc, axis)
+    def reduce_grads(grads, axis, n_dev):
         flat, treedef = jax.tree_util.tree_flatten(grads)
-        sizes = [np.prod(g.shape) for g in flat]
+        sizes = [int(np.prod(g.shape)) for g in flat]
         cat = jnp.concatenate([g.reshape(-1).astype(jnp.float32)
                                for g in flat])
         red = quantized_psum_mean(cat, axis, n_dev)
         out = []
         off = 0
         for g, sz in zip(flat, sizes):
-            out.append(red[off:off + int(sz)].reshape(g.shape))
-            off += int(sz)
-        return loss, acc, jax.tree_util.tree_unflatten(treedef, out)
+            out.append(red[off:off + sz].reshape(g.shape))
+            off += sz
+        return jax.tree_util.tree_unflatten(treedef, out)
 
-    smapped = shard_map(
-        local, mesh=mesh,
-        in_specs=(P(), P(axis), P(axis)),
-        out_specs=(P(), P(), P()),
-        check_vma=False,
-    )
-    jitted = jax.jit(smapped)
-
-    def step(params, x, y):
-        params = jax.device_put(params, repl)
-        x = jax.device_put(jnp.asarray(x), batch_sh)
-        y = jax.device_put(jnp.asarray(y), batch_sh)
-        return jitted(params, x, y)
-
-    return step
+    return make_party_step(grad_fn, mesh, reduce_grads=reduce_grads)

@@ -19,8 +19,6 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-
-from geomx_tpu.compat import axis_size as _axis_size
 import numpy as np
 from jax import lax
 
@@ -76,7 +74,7 @@ def ring_attention(
     128; off-chip use TPU interpret mode).
     """
     if axis_size is None:
-        axis_size = _axis_size(axis_name)
+        axis_size = lax.axis_size(axis_name)
     my = lax.axis_index(axis_name)
     B, T, H, D = q.shape
     neg = jnp.float32(-1e30)

@@ -19,8 +19,6 @@ from __future__ import annotations
 import jax
 from jax import lax
 
-from geomx_tpu.compat import axis_size as _axis_size
-
 from geomx_tpu.parallel.ring_attention import (
     dense_attention, fast_dense_attention)
 
@@ -39,7 +37,7 @@ def ulysses_attention(
     sequence laid out contiguously by sp rank (same contract as
     ring_attention).  Returns ``[B, T_local, H, D]`` in q.dtype.
     """
-    P = _axis_size(axis_name)
+    P = lax.axis_size(axis_name)
     H = q.shape[2]
     if H % P != 0:
         raise ValueError(

@@ -15,6 +15,7 @@
 # Env: BASE_PORT (9500), STEPS (40)
 set -euo pipefail
 cd "$(dirname "$0")/.."
+export JAX_PLATFORMS=cpu  # several role processes share this host; a chip belongs to one (see run_cluster.sh)
 
 BASE_PORT="${BASE_PORT:-9500}"
 STEPS="${STEPS:-100}"

@@ -8,6 +8,11 @@
 # Env:   PARTIES (2), WORKERS (2), GSERVERS (1), BASE_PORT (9300), STEPS (6)
 set -euo pipefail
 cd "$(dirname "$0")/.."
+# CPU acceptance matrix: 2+2P+PW role processes share this host, and a
+# chip belongs to one process — on a TPU host the first role to touch
+# jax would take it and the rest would fail.  The chip path is one
+# process per host (Simulation; docs/deployment.md).
+export JAX_PLATFORMS=cpu
 
 PARTIES="${PARTIES:-2}"
 WORKERS="${WORKERS:-2}"

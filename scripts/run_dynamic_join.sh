@@ -17,6 +17,7 @@ case "$MODE" in
 esac
 HERE="$(cd "$(dirname "$0")" && pwd)"
 cd "$HERE/.."
+export JAX_PLATFORMS=cpu  # several role processes share this host; a chip belongs to one (see run_cluster.sh)
 BASE_PORT="${BASE_PORT:-9400}"
 STEPS="${STEPS:-8}"
 # the joiner's rounds must be a PREFIX of the cluster's (it folds into

@@ -19,6 +19,7 @@
 # Env: BASE_PORT (9600), STEPS (120)
 set -euo pipefail
 cd "$(dirname "$0")/.."
+export JAX_PLATFORMS=cpu  # several role processes share this host; a chip belongs to one (see run_cluster.sh)
 
 BASE_PORT="${BASE_PORT:-9600}"
 STEPS="${STEPS:-120}"

@@ -7,6 +7,7 @@
 # Env: BASE_PORT (9400), STEPS (25), CKPT_DIR (tmp)
 set -euo pipefail
 cd "$(dirname "$0")/.."
+export JAX_PLATFORMS=cpu  # several role processes share this host; a chip belongs to one (see run_cluster.sh)
 
 BASE_PORT="${BASE_PORT:-9400}"
 STEPS="${STEPS:-25}"

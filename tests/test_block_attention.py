@@ -1,4 +1,4 @@
-"""Interpret-mode coverage for the pallas flash block kernel (advisor r3).
+"""Interpret-mode coverage for the pallas flash block kernel.
 
 ``ops/block_attention.flash_block_attention`` is the ring-attention
 ``fast="flash"`` production path (reachable via ``make_apply`` with
@@ -18,8 +18,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from geomx_tpu.compat import shard_map
-from geomx_tpu.compat import force_tpu_interpret_mode
+from jax import shard_map
+from jax.experimental.pallas.tpu import force_tpu_interpret_mode
 from jax.sharding import PartitionSpec as P
 
 from geomx_tpu.ops.block_attention import (
@@ -123,7 +123,15 @@ def test_ring_attention_flash_matches_dense():
 
 def test_ring_attention_flash_grads_match_dense():
     """End-to-end train-step path: grads of a scalar loss through the
-    sharded flash ring vs the dense reference."""
+    sharded flash ring vs the dense reference.
+
+    The grad is jitted, as every train step is.  Un-jitted, ``jax.grad``
+    dispatches the backward's eager ops from the main thread while the
+    forward's interpreter callbacks are still in flight on the virtual
+    devices' threads, and the TPU interpreter (which itself dispatches
+    jax ops from inside those callbacks) deadlocks on jax 0.9.0.  The
+    same grads are checked uninterpreted on four real chips by
+    ``chip_smoke.py``."""
     mesh = make_mesh({"sp": 4})
     T = 4 * TQ
     rng = np.random.default_rng(4)
@@ -137,9 +145,9 @@ def test_ring_attention_flash_grads_match_dense():
         check_vma=False,
     )
     with force_tpu_interpret_mode():
-        gf = jax.tree_util.tree_map(np.asarray, jax.grad(
+        gf = jax.tree_util.tree_map(np.asarray, jax.jit(jax.grad(
             lambda a, b, c: jnp.sum(ring(a, b, c) ** 2),
-            argnums=(0, 1, 2))(q, k, v))
+            argnums=(0, 1, 2)))(q, k, v))
     gr = jax.grad(
         lambda a, b, c: jnp.sum(dense_attention(a, b, c, causal=True) ** 2),
         argnums=(0, 1, 2))(q, k, v)

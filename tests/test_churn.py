@@ -210,7 +210,9 @@ def test_two_parties_fold_in_same_global_round(lightweight):
         assert _wait_for(lambda: gs.num_contributors == 1, 10)
         assert gs.party_folds == 2
         assert sim.recovery_monitor.preempt_folds == 1
-        assert sim.recovery_monitor.party_folds == 1  # only the crash
+        # only the crash; the monitor counts its fold once the global
+        # tier has ACKed the RPC, i.e. just after gs.party_folds moved
+        assert _wait_for(lambda: sim.recovery_monitor.party_folds == 1, 10)
 
         # the noticed party's host is reclaimed; replacements come up
         sim.kill_local_server(1)

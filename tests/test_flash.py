@@ -1,12 +1,12 @@
-"""Flash-attention correctness without the chip (VERDICT r2 item 2).
+"""Flash-attention correctness without the chip.
 
 ``attn_impl="flash"`` (models/transformer.py::_single_device_attention)
-is the MFU bench's headline path but is real-TPU-only at lowering time;
-these tests run the very same code under pallas **TPU interpret mode**
-on CPU, so a broken kernel or a wrong layout swap can never again reach
-the bench untested.  Tolerances: the interpret-mode kernel computes in
-fp32, so fwd is compared tightly; bwd goes through the kernel's custom
-VJP (the path the train step uses).
+lowers only for a TPU; these tests run the very same code under pallas
+**TPU interpret mode** on CPU, so a broken kernel or a wrong layout swap
+is caught before a chip run.  ``chip_smoke.py`` compiles the kernel
+uninterpreted at the flagship geometry.  Tolerances: the interpret-mode
+kernel computes in fp32, so fwd is compared tightly; bwd goes through
+the kernel's custom VJP (the path the train step uses).
 """
 
 import jax
@@ -14,7 +14,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from geomx_tpu.compat import force_tpu_interpret_mode
+from jax.experimental.pallas.tpu import force_tpu_interpret_mode
 
 from geomx_tpu.models.transformer import (
     TransformerConfig, _single_device_attention,
@@ -31,21 +31,6 @@ def _qkv(dtype=jnp.float32, seed=0):
     return tuple(jax.random.normal(k, (B, T, H, D), dtype) for k in ks)
 
 
-# jax 0.4.x's bundled flash_attention op is broken under pallas
-# interpret mode (its _load_discharge_rule trips on int indices:
-# "AttributeError: 'int' object has no attribute 'shape'" inside
-# jax/_src/pallas/primitives.py) — an upstream bug in the interpreter,
-# red at seed, not in this repo's kernel wiring.  xfail(strict=False):
-# the mark self-heals into XPASS visibility when a jax upgrade fixes
-# the discharge rule, instead of hiding a then-working path.
-_UPSTREAM_FLASH_INTERPRET = pytest.mark.xfail(
-    reason="upstream jax 0.4.x pallas interpret-mode bug: "
-           "_load_discharge_rule AttributeError on int indices "
-           "(bundled flash_attention op; red at seed)",
-    raises=AttributeError, strict=False)
-
-
-@_UPSTREAM_FLASH_INTERPRET
 def test_flash_forward_matches_dense_interpret():
     cfg = TransformerConfig(attn_impl="flash")
     q, k, v = _qkv()
@@ -55,7 +40,6 @@ def test_flash_forward_matches_dense_interpret():
     np.testing.assert_allclose(o, r, rtol=1e-4, atol=1e-4)
 
 
-@_UPSTREAM_FLASH_INTERPRET
 def test_flash_backward_matches_dense_interpret():
     """The custom-VJP backward — the path every train step exercises."""
     cfg = TransformerConfig(attn_impl="flash")
@@ -77,7 +61,6 @@ def test_flash_backward_matches_dense_interpret():
             err_msg=f"grad wrt {name}")
 
 
-@_UPSTREAM_FLASH_INTERPRET
 def test_flash_bf16_within_tolerance_interpret():
     """bf16 inputs — the dtype the MFU bench actually times."""
     cfg = TransformerConfig(attn_impl="flash")
@@ -90,19 +73,16 @@ def test_flash_bf16_within_tolerance_interpret():
     assert np.max(np.abs(o - r)) < 5e-2
 
 
-def test_bench_flash_gate_degrades_cleanly_off_chip():
-    """bench.py's pre-timing exactness gate must never crash the child:
-    off-chip (no interpret context) flash fails to lower and the gate
-    falls back to attn_impl='fast' with a FAILED note."""
+def test_bench_flash_gate_fails_off_chip():
+    """bench.py's pre-timing exactness gate has no fallback: off-chip
+    (no interpret context) flash cannot lower, and the gate raises
+    instead of quietly timing ``attn_impl='fast'`` under flash's name."""
     import sys
     from pathlib import Path
     sys.path.insert(0, str(Path(__file__).resolve().parent.parent))
     from bench import _flash_exactness_check
 
-    impl, status = _flash_exactness_check("flash")
-    assert impl in ("flash", "fast")
-    if impl == "fast":
-        assert "FAILED" in status
+    with pytest.raises(ValueError, match="interpret mode"):
+        _flash_exactness_check("flash")
     # non-flash configs skip the gate untouched
-    impl2, status2 = _flash_exactness_check("fast")
-    assert impl2 == "fast" and "skipped" in status2
+    assert "skipped" in _flash_exactness_check("fast")

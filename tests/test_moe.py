@@ -81,8 +81,7 @@ def test_flops_independent_of_expert_count():
         x, router, we1, we2 = _mats(G, S, D, F, E, seed=1)
         f = jax.jit(lambda x: moe_ffn_topk(
             x, router, we1, we2, k=2, capacity_factor=1.0)[0])
-        from geomx_tpu.compat import cost_analysis
-        return cost_analysis(f.lower(x).compile())["flops"]
+        return f.lower(x).compile().cost_analysis()["flops"]
 
     f4, f16 = flops(4), flops(16)
     assert f16 / f4 < 1.3, (f4, f16)

@@ -12,7 +12,7 @@ import pytest
 from geomx_tpu.parallel import make_mesh
 from geomx_tpu.parallel.quantized_allreduce import (
     BLOCK, make_party_step_quantized, quantized_psum_mean)
-from geomx_tpu.compat import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 
