@@ -281,13 +281,15 @@ def resolve_codec_device(config) -> bool:
     return True
 
 
-def make_merge_backend(config) -> MergeBackend:
+def make_merge_backend(config, tracer=None) -> MergeBackend:
     """Construct the resolved backend.  When ``jax`` was resolved —
     explicitly, or by ``auto`` on a live accelerator — a
     :class:`JaxBackend` that cannot be built raises: the numpy backend
-    is a reference tests choose, not a fallback that hides the device."""
+    is a reference tests choose, not a fallback that hides the device.
+    ``tracer`` is the building server's: the device backend's spans
+    (``be.*`` / ``opt.step``) are recorded under that node."""
     if resolve_merge_backend(config) == "jax":
         from geomx_tpu.kvstore.jax_backend import JaxBackend
 
-        return JaxBackend(config)
+        return JaxBackend(config, tracer=tracer)
     return NumpyBackend(config)

@@ -317,6 +317,12 @@ class WorkerKVStore:
         return self._tracer.round(round_idx,
                                   self.config.trace_sample_every)
 
+    def trace_span(self, name: str, **args):
+        """A span of this worker's node under the open round (no-op
+        outside a sampled ``trace_round``): how the training loop marks
+        its own work at the slice edge, ``edge.d2h`` / ``edge.scale``."""
+        return self._tracer.span(name, **args)
+
     # ---- public API ---------------------------------------------------------
     def init(self, tid: int, value: np.ndarray, barrier: bool = False,
              overwrite: bool = False):
@@ -578,7 +584,7 @@ class WorkerKVStore:
         if num_merge > 1:
             body_out["num_merge"] = int(num_merge)
         fields = {"body": body_out} if body_out else {}
-        with self._tracer.span("worker.push"):
+        with self._tracer.span("worker.push", key=tid, nbytes=flat.nbytes):
             ts = self.worker.zpush(self._encode(tid, flat, priority),
                                    cmd=Cmd.DEFAULT, priority=priority,
                                    **fields)
@@ -642,11 +648,11 @@ class WorkerKVStore:
         def decode(kvs):
             # runs on the response-delivery thread under the response's
             # trace context — the decode span closes the round's chain
-            with self._tracer.span("worker.pull_decode"):
+            with self._tracer.span("worker.pull_decode", key=tid, of=kvs):
                 out = self._decode(tid, kvs)
             cb(tid, out)
 
-        with self._tracer.span("worker.pull"):
+        with self._tracer.span("worker.pull", key=tid, nbytes=4 * size):
             ts = self.worker.zpull(
                 keys, cb=decode,
                 cmd=Cmd.DEFAULT, priority=priority, after_ts=after,

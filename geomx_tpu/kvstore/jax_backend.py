@@ -65,6 +65,7 @@ import numpy as np
 
 from geomx_tpu.kvstore.backend import (MergeBackend, _accumulate_kernel,
                                        _adopt_or_copy)
+from geomx_tpu.trace.recorder import _NULL_SPAN, get_tracer
 
 # below this many elements the mesh collective loses to a plain add
 # (dispatch + cross-device assembly dominate); overridable so the CPU
@@ -108,18 +109,57 @@ class _DeviceAccum:
         return total.tobytes()
 
 
+class _Timed:
+    """One clock pair for a site that feeds an operator gauge
+    (``merge_device_ms`` / ``opt_device_ms``) and, in a sampled round,
+    is a tracer span: the span's own duration is billed when there is
+    one, the site's clock when the tracer hands back ``_NULL_SPAN``.
+    Host clock around asynchronous dispatch and staging — NOT device
+    time (the profiler's ``XLA Modules`` line has that)."""
+
+    __slots__ = ("_be", "_gauge", "_span", "_t0")
+
+    def __init__(self, be: "JaxBackend", gauge: str, span):
+        self._be = be
+        self._gauge = gauge
+        self._span = span
+
+    def __enter__(self):
+        if self._span is _NULL_SPAN:
+            self._t0 = time.perf_counter()
+        else:
+            self._span.__enter__()
+        return self
+
+    def __exit__(self, *exc):
+        span = self._span
+        if span is _NULL_SPAN:
+            ms = (time.perf_counter() - self._t0) * 1e3
+        else:
+            span.__exit__(*exc)
+            ms = span.dur_us * 1e-3
+        be = self._be
+        with be._mu:
+            setattr(be, self._gauge, getattr(be, self._gauge) + ms)
+        return False
+
+
 class JaxBackend(MergeBackend):
     name = "jax"
     # a device stream serializes dispatch; more lanes than this only
     # contend on the dispatch lock without overlapping device work
     max_lanes = 4
 
-    def __init__(self, config=None):
+    def __init__(self, config=None, tracer=None):
         import jax  # deliberate: constructing this backend IS the opt-in
         import jax.numpy as jnp
 
         self._jax = jax
         self._jnp = jnp
+        # the backend has no node of its own: the server that builds it
+        # hands it its Tracer, and the spans below (be.* / opt.step)
+        # land under that server's handler spans
+        self._tr = tracer if tracer is not None else get_tracer("backend")
         self._devices = list(jax.devices())
         self._threads = int(getattr(config, "server_merge_threads", 0)
                             or 0)
@@ -132,19 +172,32 @@ class JaxBackend(MergeBackend):
         self._platform = self._devices[0].platform
         # donated-argument accumulate: XLA writes the sum back into the
         # accumulator's buffer — the device analog of acc += v
-        self._add = jax.jit(lambda a, b: a + b, donate_argnums=(0,))
+        # (every jitted server program is a NAMED function: the
+        # profiler's ``XLA Modules`` line calls it ``jit_<name>``, and
+        # the benchmark tells merge from optimizer from codec by it)
+        def geomx_merge_add(a, b):
+            return a + b
+
+        self._add = jax.jit(geomx_merge_add, donate_argnums=(0,))
+
         # scale takes the factor as an f32 ARRAY argument: a python
         # float would be baked into the jaxpr and retrace per distinct
         # HFA renormalization value
-        self._scale = jax.jit(lambda a, s: a * s, donate_argnums=(0,))
+        def geomx_merge_scale(a, s):
+            return a * s
+
+        self._scale = jax.jit(geomx_merge_scale, donate_argnums=(0,))
+
         # gradient-hygiene screen: one fused device reduction to a
         # scalar — |x| <= m subsumes the finiteness check (NaN/inf
         # compare False), so both modes are a single pass and the only
         # host traffic is the bool
-        self._screen = jax.jit(
-            lambda x, m: jnp.where(m > np.float32(0),
-                                   (jnp.abs(x) <= m).all(),
-                                   jnp.isfinite(x).all()))
+        def geomx_screen(x, m):
+            return jnp.where(m > np.float32(0),
+                             (jnp.abs(x) <= m).all(),
+                             jnp.isfinite(x).all())
+
+        self._screen = jax.jit(geomx_screen)
         self._mesh_cache: Dict[int, object] = {}
         self._reducers: Dict[tuple, object] = {}
         # per-key error-feedback residual for the quantized collective:
@@ -190,13 +243,11 @@ class JaxBackend(MergeBackend):
         # the donation contract is honored trivially here: the wire
         # buffer is consumed by the single staged H2D copy and never
         # aliased or mutated afterwards
-        t0 = time.perf_counter()
         spread = (len(self._devices) > 1
                   and len(v) >= _MESH_MIN_ELEMS)
-        acc = _DeviceAccum(self._stage(v, self._devices[0]), len(v),
-                           spread, key=key)
-        self._bill(t0)
-        return acc
+        with self._timed("be.h2d", "merge_device_ms", key, v.nbytes):
+            part = self._stage(v, self._devices[0])
+        return _DeviceAccum(part, len(v), spread, key=key)
 
     def accumulate(self, acc, v: np.ndarray):
         if isinstance(acc, np.ndarray):
@@ -206,22 +257,27 @@ class JaxBackend(MergeBackend):
                                  np.ascontiguousarray(v, np.float32),
                                  self._threads)
             return acc
-        t0 = time.perf_counter()
-        if not acc.spread:
-            staged = self._stage(v, self._devices[0])
-            acc.parts[0] = self._add(acc.parts[0], staged)
-        else:
-            # round-robin device slots: contribution i lands on device
-            # i % n, pre-reduced per slot in arrival order; the round
-            # close psums ACROSS the slots
-            slot = acc.count % len(self._devices)
+        # round-robin device slots under a mesh: contribution i lands
+        # on device i % n, pre-reduced per slot in arrival order; the
+        # round close psums ACROSS the slots
+        slot = acc.count % len(self._devices) if acc.spread else 0
+        with self._timed("be.h2d", "merge_device_ms", acc.key, v.nbytes):
             staged = self._stage(v, self._devices[slot])
-            if slot < len(acc.parts):
+        if slot < len(acc.parts):
+            with self._timed("be.add", "merge_device_ms", acc.key,
+                             v.nbytes):
                 acc.parts[slot] = self._add(acc.parts[slot], staged)
-            else:
-                acc.parts.append(staged)
+                if self._platform == "cpu":
+                    # the CPU client stages an aligned host buffer by
+                    # ALIASING it, and runs the add some time later: a
+                    # sender that reused its buffer after the ack
+                    # changed the merge, by the luck of malloc.  Here
+                    # the add has read the buffer before the push is
+                    # acked; an accelerator copies, and stays async
+                    acc.parts[slot].block_until_ready()
+        else:
+            acc.parts.append(staged)
         acc.count += 1
-        self._bill(t0)
         return acc
 
     # ---- round close --------------------------------------------------------
@@ -229,25 +285,25 @@ class JaxBackend(MergeBackend):
         if isinstance(acc, np.ndarray):
             np.multiply(acc, s, out=acc)
             return acc
-        t0 = time.perf_counter()
-        part = self._reduced(acc)
-        acc.parts = [self._scale(part, np.float32(s))]
-        self._bill(t0)
+        with self._timed("be.scale", "merge_device_ms", acc.key,
+                         4 * acc.elems):
+            part = self._reduced(acc)
+            acc.parts = [self._scale(part, np.float32(s))]
         return acc
 
     def materialize(self, acc) -> np.ndarray:
         if isinstance(acc, np.ndarray):
             return acc
-        t0 = time.perf_counter()
-        host = np.asarray(self._reduced(acc))  # block + one D2H
-        with self._mu:
-            self.d2h_bytes += host.nbytes
-        if not host.flags.writeable:
-            # the CPU jax backend hands out a read-only view of the
-            # device buffer; the server OWNS the materialized round
-            # (optimizer builds the update in it — donated contract)
-            host = host.copy()
-        self._bill(t0)
+        with self._timed("be.d2h", "merge_device_ms", acc.key,
+                         4 * acc.elems):
+            host = np.asarray(self._reduced(acc))  # block + one D2H
+            with self._mu:
+                self.d2h_bytes += host.nbytes
+            if not host.flags.writeable:
+                # the CPU jax backend hands out a read-only view of the
+                # device buffer; the server OWNS the materialized round
+                # (optimizer builds the update in it — donated contract)
+                host = host.copy()
         return host
 
     def _reduced(self, acc: "_DeviceAccum"):
@@ -285,7 +341,7 @@ class JaxBackend(MergeBackend):
             from geomx_tpu.parallel.quantized_allreduce import (
                 quantized_psum_mean_ef)
 
-            def body(x, r):  # [1, elems] + residual per device slot
+            def geomx_mesh_reduce(x, r):  # [1, elems] + residual per slot
                 out, r_new = quantized_psum_mean_ef(x[0], r[0], "party", k)
                 # quantized mean * k = the party SUM the round-close
                 # consumers expect; the residual is already in that
@@ -293,26 +349,29 @@ class JaxBackend(MergeBackend):
                 return (out * np.float32(k))[None], r_new[None]
 
             red = jax.jit(shard_map(
-                body, mesh=mesh, in_specs=(P("party"), P("party")),
+                geomx_mesh_reduce, mesh=mesh,
+                in_specs=(P("party"), P("party")),
                 out_specs=(P("party"), P("party")), check_vma=False))
         elif self._quantized:
             from geomx_tpu.parallel.quantized_allreduce import (
                 quantized_psum_mean)
 
-            def body(x):  # [1, elems] per device
+            def geomx_mesh_reduce(x):  # [1, elems] per device
                 # quantized mean * k = the party SUM the round-close
                 # consumers expect (the global optimizer divides by
                 # num_contributors itself)
                 return (quantized_psum_mean(x[0], "party", k)
                         * np.float32(k))[None]
 
-            red = jax.jit(shard_map(body, mesh=mesh, in_specs=P("party"),
+            red = jax.jit(shard_map(geomx_mesh_reduce, mesh=mesh,
+                                    in_specs=P("party"),
                                     out_specs=P("party"), check_vma=False))
         else:
-            def body(x):
+            def geomx_mesh_reduce(x):
                 return jax.lax.psum(x, "party")
 
-            red = jax.jit(shard_map(body, mesh=mesh, in_specs=P("party"),
+            red = jax.jit(shard_map(geomx_mesh_reduce, mesh=mesh,
+                                    in_specs=P("party"),
                                     out_specs=P("party"), check_vma=False))
         with self._mu:
             self._reducers[key] = red
@@ -348,22 +407,25 @@ class JaxBackend(MergeBackend):
         from jax.sharding import NamedSharding, PartitionSpec as P
 
         k = len(parts)
-        mesh = self._submesh(k)
-        sharding = NamedSharding(mesh, P("party"))
-        global_arr = self._jax.make_array_from_single_device_arrays(
-            (k, elems), sharding,
-            [p.reshape(1, elems) for p in parts])
-        ef = self._ef and key is not None
-        if ef:
-            r = self._residual_for(key, k, elems)
-            out, r_new = self._reducer(k, elems, True)(global_arr, r)
-            self._residuals[key] = (k, r_new)
-        else:
-            out = self._reducer(k, elems, False)(global_arr)
-        # out is [k, elems] with equal rows; commit row 0 to device 0 so
-        # downstream single-device consumers (the jitted optimizer
-        # update, the donated scale) see one device, not the mesh
-        return self._jax.device_put(out[0], self._devices[0])
+        # a plain span: the site that closes the round (be.scale,
+        # be.d2h, opt.step) holds the gauge's clock around it
+        with self._tr.span("be.reduce", key=key, nbytes=4 * elems * k):
+            mesh = self._submesh(k)
+            sharding = NamedSharding(mesh, P("party"))
+            global_arr = self._jax.make_array_from_single_device_arrays(
+                (k, elems), sharding,
+                [p.reshape(1, elems) for p in parts])
+            ef = self._ef and key is not None
+            if ef:
+                r = self._residual_for(key, k, elems)
+                out, r_new = self._reducer(k, elems, True)(global_arr, r)
+                self._residuals[key] = (k, r_new)
+            else:
+                out = self._reducer(k, elems, False)(global_arr)
+            # out is [k, elems] with equal rows; commit row 0 to device 0
+            # so downstream single-device consumers (the jitted optimizer
+            # update, the donated scale) see one device, not the mesh
+            return self._jax.device_put(out[0], self._devices[0])
 
     def screen_finite(self, v: np.ndarray, mag_max: float = 0.0) -> bool:
         """Device screen: the jitted fused reduction ships one scalar
@@ -404,15 +466,11 @@ class JaxBackend(MergeBackend):
         return cls(self, spec)
 
     # ---- observability ------------------------------------------------------
-    def _bill(self, t0: float) -> None:
-        dt = (time.perf_counter() - t0) * 1e3
-        with self._mu:
-            self.merge_device_ms += dt
-
-    def _bill_opt(self, t0: float) -> None:
-        dt = (time.perf_counter() - t0) * 1e3
-        with self._mu:
-            self.opt_device_ms += dt
+    def _timed(self, name: str, gauge: str, key, nbytes: int) -> _Timed:
+        """Span ``name`` (in a sampled round) and ``gauge`` (always)
+        from one clock pair."""
+        return _Timed(self, gauge,
+                      self._tr.span(name, key=key, nbytes=nbytes))
 
     def _bill_d2h(self, nbytes: int) -> None:
         with self._mu:
@@ -449,12 +507,13 @@ class DeviceWeight:
     (deleted) buffer under it would be a use-after-free on accelerator
     backends."""
 
-    __slots__ = ("ref", "_be", "_host")
+    __slots__ = ("ref", "_be", "_host", "key")
 
-    def __init__(self, be: "JaxBackend", ref):
+    def __init__(self, be: "JaxBackend", ref, key=None):
         self.ref = ref
         self._be = be
         self._host: Optional[np.ndarray] = None
+        self.key = key  # for the be.d2h span of host()
 
     @property
     def nbytes(self) -> int:  # store_bytes accounting without a D2H
@@ -465,7 +524,9 @@ class DeviceWeight:
 
     def host(self) -> np.ndarray:
         if self._host is None:
-            h = np.asarray(self.ref)  # one D2H (zero-copy view on cpu)
+            with self._be._tr.span("be.d2h", key=self.key,
+                                   nbytes=self.ref.nbytes):
+                h = np.asarray(self.ref)  # one D2H (zero-copy view on cpu)
             self._be._bill_d2h(h.nbytes)
             self._host = h
         return self._host
@@ -511,22 +572,21 @@ class DeviceOptimizer:
         a host ndarray on the key's first device round (adopted with
         one H2D); ``accum`` is the merge accumulator (device handle, or
         a host array when a row-sparse scatter seeded the round)."""
-        t0 = time.perf_counter()
-        w = self._weight_ref(raw_w)
-        g = self._grad_ref(accum)
-        new = self._update(k, w, g, float(scale))
-        self._be._bill_opt(t0)
-        return DeviceWeight(self._be, new)
+        with self._be._timed("opt.step", "opt_device_ms", k, raw_w.nbytes):
+            w = self._weight_ref(raw_w)
+            g = self._grad_ref(accum)
+            new = self._update(k, w, g, float(scale))
+        return DeviceWeight(self._be, new, key=k)
 
     def add_delta(self, raw_w, accum) -> DeviceWeight:
         """HFA milestone-delta close: ``weight + accum`` on device (no
         optimizer state involved — the delta is pre-divided)."""
-        t0 = time.perf_counter()
-        w = self._weight_ref(raw_w)
-        g = self._grad_ref(accum)
-        new = w + g  # NOT the donated add: w must stay alive (aliases)
-        self._be._bill_opt(t0)
-        return DeviceWeight(self._be, new)
+        key = getattr(raw_w, "key", None)  # a DeviceWeight knows its key
+        with self._be._timed("opt.step", "opt_device_ms", key, raw_w.nbytes):
+            w = self._weight_ref(raw_w)
+            g = self._grad_ref(accum)
+            new = w + g  # NOT the donated add: w must stay alive (aliases)
+        return DeviceWeight(self._be, new, key=key)
 
     def _weight_ref(self, raw):
         if isinstance(raw, DeviceWeight):
@@ -603,23 +663,25 @@ class DeviceSgd(DeviceOptimizer):
         if self.momentum == 0.0 and self.wd == 0.0:
             # numpy Sgd.update_scaled's fast path: new_w = g·c + w with
             # c = f32(-(lr·scale)) — two passes, grad donated
-            self._upd = jax.jit(lambda g, w, c: g * c + w,
-                                donate_argnums=(0,))
+            def geomx_sgd_plain(g, w, c):
+                return g * c + w
+
+            self._upd = jax.jit(geomx_sgd_plain, donate_argnums=(0,))
         elif self.momentum == 0.0:
-            def f(w, g, scale, lr, wd):
+            def geomx_sgd(w, g, scale, lr, wd):
                 g = g * scale
                 g = g + wd * w
                 return w - lr * g
 
-            self._upd = jax.jit(f, donate_argnums=(1,))
+            self._upd = jax.jit(geomx_sgd, donate_argnums=(1,))
         else:
-            def f(w, mom, g, scale, lr, wd, momentum):
+            def geomx_sgd(w, mom, g, scale, lr, wd, momentum):
                 g = g * scale
                 g = g + wd * w
                 mom = momentum * mom - lr * g
                 return w + mom, mom
 
-            self._upd = jax.jit(f, donate_argnums=(1, 2))
+            self._upd = jax.jit(geomx_sgd, donate_argnums=(1, 2))
 
     def _update(self, k, w, g, scale):
         if self.momentum == 0.0 and self.wd == 0.0:
@@ -644,13 +706,13 @@ class DeviceNag(DeviceOptimizer):
         super().__init__(be, spec)
         self.momentum = float(spec.get("momentum", 0.9))
 
-        def f(w, mom, g, scale, lr, wd, momentum):
+        def geomx_nag(w, mom, g, scale, lr, wd, momentum):
             g = g * scale
             g = g + wd * w
             mom = momentum * mom + g
             return w - lr * (g + momentum * mom), mom
 
-        self._upd = self._jax.jit(f, donate_argnums=(1, 2))
+        self._upd = self._jax.jit(geomx_nag, donate_argnums=(1, 2))
 
     def _update(self, k, w, g, scale):
         st = self._st.get(k)
@@ -673,8 +735,8 @@ class DeviceAdam(DeviceOptimizer):
         self.eps = float(spec.get("eps", 1e-8))
         jnp = self._jnp
 
-        def f(w, m, v, g, scale, b1, one_b1, b2, one_b2, corr1, corr2,
-              lr, eps, wd):
+        def geomx_adam(w, m, v, g, scale, b1, one_b1, b2, one_b2, corr1,
+                       corr2, lr, eps, wd):
             g = g * scale
             g = g + wd * w
             m = b1 * m + one_b1 * g
@@ -683,7 +745,7 @@ class DeviceAdam(DeviceOptimizer):
             vhat = v / corr2
             return w - lr * mhat / (jnp.sqrt(vhat) + eps), m, v
 
-        self._upd = self._jax.jit(f, donate_argnums=(1, 2, 3))
+        self._upd = self._jax.jit(geomx_adam, donate_argnums=(1, 2, 3))
 
     def _update(self, k, w, g, scale):
         st = self._st.get(k)
@@ -742,20 +804,25 @@ class CodecStage:
         jax, jnp = be._jax, be._jnp
         self._jax, self._jnp = jax, jnp
         # decode kernels (receiver side; shape/length-cached by jit)
-        self._dec_f16 = jax.jit(lambda p: p.astype(jnp.float32))
+        def geomx_fp16_dec(p):
+            return p.astype(jnp.float32)
 
+        self._dec_f16 = jax.jit(geomx_fp16_dec)
+
+        # ``_scatter`` (and DeviceBscCodec's ``enc``) keep their names:
+        # the benchmark's codec_dev_ms_per_step matches on them
         def _scatter(vals, idx, n):
             return jnp.zeros(n, jnp.float32).at[idx].set(vals)
 
         self._dec_bsc = jax.jit(_scatter, static_argnums=(2,))
 
-        def _unpack2bit(b, t, n):
+        def geomx_2bit_dec(b, t, n):
             q = jnp.stack([b & 3, (b >> 2) & 3, (b >> 4) & 3,
                            (b >> 6) & 3], axis=1).reshape(-1)[:n]
             z = jnp.zeros((), jnp.float32)
             return jnp.where(q == 1, t, jnp.where(q == 2, -t, z))
 
-        self._dec_2bit = jax.jit(_unpack2bit, static_argnums=(2,))
+        self._dec_2bit = jax.jit(geomx_2bit_dec, static_argnums=(2,))
 
     # ---- residency helpers (server-side seam) -------------------------------
     def is_device(self, v) -> bool:
@@ -779,7 +846,8 @@ class CodecStage:
         """Full-tensor D2H for the fallback event paths (degraded-round
         absorb, adaptive raw stash) — billed to ``codec_host_bytes`` so
         the steady-state "host copies == 0" contract stays auditable."""
-        host = np.asarray(v)
+        with self._be._tr.span("be.d2h", nbytes=v.nbytes):
+            host = np.asarray(v)
         with self._be._mu:
             self._be.codec_host_bytes += host.nbytes
         return host
@@ -802,7 +870,8 @@ class CodecStage:
         THE single D2H of the device encode path (compressed bytes only,
         billed to ``codec_d2h_bytes``).  The returned view keeps the
         device buffer alive; senders ship it donated and never mutate."""
-        host = np.asarray(payload)
+        with self._be._tr.span("be.d2h", nbytes=payload.nbytes):
+            host = np.asarray(payload)
         with self._be._mu:
             self._be.codec_d2h_bytes += host.nbytes
         return host
@@ -907,8 +976,12 @@ class DeviceFp16Codec(DeviceCodec):
 
     def __init__(self, stage):
         super().__init__(stage)
-        self._enc = self._jax.jit(
-            lambda x: x.astype(self._jnp.float16))
+        jnp = self._jnp
+
+        def geomx_fp16_enc(x):
+            return x.astype(jnp.float16)
+
+        self._enc = self._jax.jit(geomx_fp16_enc)
 
     def compress(self, key, arr):
         t0 = time.perf_counter()
@@ -934,7 +1007,7 @@ class DeviceTwoBitCodec(DeviceCodec):
         self._residual: Dict[int, object] = {}
         jnp = self._jnp
 
-        def enc(r, g, t):
+        def geomx_2bit_enc(r, g, t):
             r = r + g
             pos = r > t
             neg = r < -t
@@ -949,7 +1022,7 @@ class DeviceTwoBitCodec(DeviceCodec):
                       | (qp[:, 3] << 6))
             return packed.astype(jnp.uint8), r
 
-        self._enc = self._jax.jit(enc, donate_argnums=(0,))
+        self._enc = self._jax.jit(geomx_2bit_enc, donate_argnums=(0,))
 
     def compress(self, key, arr):
         t0 = time.perf_counter()

@@ -50,22 +50,34 @@ from geomx_tpu.trace import context as _tctx
 from geomx_tpu.transport.message import Control, Domain, Message
 
 
-def _ctx_bound(fn):
+def _ctx_bound(fn, lane_tracer=None, key=None):
     """Carry the calling (handler) thread's trace context onto a merge
     lane: a sampled round's merge spans — and the WAN push-up messages
     the lane sends at round completion — must stay children of the
     inbound push, or sharding would sever every cross-node chain.
-    Free when tracing is off (returns ``fn`` itself)."""
+    Free when tracing is off (returns ``fn`` itself).
+
+    ``lane_tracer`` is the server's tracer where its lanes are THREADS
+    (``ShardExecutor.inline`` false; None where they run inline, the
+    reactor default): the bound item then runs under a ``lane`` span
+    that carries its own wait, submit to start, as ``queued_us``."""
     if not _tctx.ACTIVE:
         return fn
     ctx = _tctx.current()
     if ctx is None:
         return fn
+    submitted = time.monotonic()
 
     def bound():
         prev = _tctx.swap(ctx)
         try:
-            fn()
+            if lane_tracer is None:
+                fn()
+            else:
+                with lane_tracer.span(
+                        "lane", key=key,
+                        queued_us=(time.monotonic() - submitted) * 1e6):
+                    fn()
         finally:
             _tctx.restore(prev)
 
@@ -372,7 +384,12 @@ class LocalServer:
         # numpy = the host reference path, jax = staged device merge;
         # deterministic forces numpy).  The lanes themselves are built
         # per-backend — a device backend caps how many can usefully run.
-        self._backend = make_merge_backend(self.config)
+        from geomx_tpu.trace.recorder import get_tracer
+        from geomx_tpu.utils import get_profiler
+
+        self._prof = get_profiler(str(postoffice.node))
+        self._tr = get_tracer(str(postoffice.node))
+        self._backend = make_merge_backend(self.config, tracer=self._tr)
         # device-resident WAN codec stage (ISSUE 20): non-None iff the
         # jax backend is active and codec_device resolves on — encode
         # then reads the device merge accumulator directly and the only
@@ -380,13 +397,10 @@ class LocalServer:
         self._codec_stage = self._backend.make_codec_stage(self.config)
         self._mu, self._shards = make_merge_lanes(
             self.config, postoffice.node, self._backend)
+        # the ``lane`` span of _ctx_bound: only where lanes are threads
+        self._lane_tr = None if self._shards.inline else self._tr
         self._ctr_mu = threading.Lock()  # leaf lock for shared counters
         #                                  bumped from parallel lanes
-        from geomx_tpu.trace.recorder import get_tracer
-        from geomx_tpu.utils import get_profiler
-
-        self._prof = get_profiler(str(postoffice.node))
-        self._tr = get_tracer(str(postoffice.node))
         # flight recorder (obs/flight.py): fence/fold/round events +
         # this server's merge-pressure sources; None when disabled
         self._flight = postoffice.flight
@@ -538,12 +552,14 @@ class LocalServer:
             # the tracer span nests inside the profiler span: same
             # buffer, but the tracer one carries the causal ids and is
             # gated on the round's sampling, not on profiler.running
-            with prof.span("local.push"), self._tr.span("local.push"):
+            with prof.span("local.push"), \
+                    self._tr.span("local.push", of=msg):
                 self._handle_push(msg, kvs)
             if prof.running:
                 prof.count("push_bytes", float(msg.nbytes))
         elif msg.pull:
-            with prof.span("local.pull"), self._tr.span("local.pull"):
+            with prof.span("local.pull"), \
+                    self._tr.span("local.pull", of=msg):
                 self._handle_pull(msg, kvs)
 
     def _handle_init(self, msg: Message, kvs: KVPairs):
@@ -1525,7 +1541,8 @@ class LocalServer:
                 self._push_merged(msg, kvs, bundles)
 
         for k, v in slices:
-            self._shards.submit(k, _ctx_bound(lambda k=k, v=v: merge_one(k, v)))
+            self._shards.submit(k, _ctx_bound(
+                lambda k=k, v=v: merge_one(k, v), self._lane_tr, k))
 
     def _push_merged(self, msg: Message, kvs: KVPairs,
                      bundles: List[dict]):
@@ -1689,7 +1706,7 @@ class LocalServer:
             if bundle is not None:
                 self._dispatch_rounds([bundle])
 
-        self._shards.submit(key, _ctx_bound(merge_rs))
+        self._shards.submit(key, _ctx_bound(merge_rs, self._lane_tr, key))
 
     def _on_inter_ts_delivery(self, msg: Message, kvs: KVPairs):
         """Updated weights arrived via the WAN overlay instead of a pull
@@ -1714,6 +1731,16 @@ class LocalServer:
                                         Cmd.TS_AUTOPULL)
 
     def _take_completed_locked(self, k: int) -> dict:
+        """:meth:`_take_round_locked` under the ``local.close`` span:
+        the close of one key's round on this tier, from the last
+        contribution counted to the detached (scaled, materialized)
+        bundle; the push-up that follows is ``codec.encode`` and the
+        van's sends under the same ``local.push``."""
+        with self._tr.span("local.close", key=k,
+                           contributors=self._keys[k].count):
+            return self._take_round_locked(k)
+
+    def _take_round_locked(self, k: int) -> dict:
         """Detach key ``k``'s completed round (caller holds stripe(k);
         completion was just decided).  Bumps the round counter, applies
         the HFA convex renormalization — accum = Σ w_i/n_i with
@@ -2021,7 +2048,9 @@ class LocalServer:
                        if isinstance(self.push_codec, MpqSelector)
                        else self.push_codec)) for k, v in kvs.slices()]
         pool = codec_pool(self.config) if len(sel) > 1 else None
-        with self._tr.span("codec.encode"):
+        # (the pool's threads carry no trace context: the span stays at
+        # this level, around the futures)
+        with self._tr.span("codec.encode", of=kvs):
             if pool is None:
                 enc = [(k, c.name, c.compress(k, v)) for k, v, c in sel]
             else:
@@ -2319,7 +2348,7 @@ class LocalServer:
         tags = kvs.tags or {}
         pv = kvs.pv or {}
         wv = kvs.wv or {}
-        with self._tr.span("local.pull_down"):
+        with self._tr.span("local.pull_down", of=kvs):
             live = []
             for k, v in kvs.slices():
                 with self._mu.stripe(k):
@@ -2797,7 +2826,10 @@ class GlobalServer:
         # folds, failover fences, replication snapshots and policy
         # swaps — their atomicity against the data path is unchanged.
         # Lanes are built per merge backend (kvstore/backend.py).
-        self._backend = make_merge_backend(self.config)
+        from geomx_tpu.trace.recorder import get_tracer
+
+        self._tr = get_tracer(str(postoffice.node))
+        self._backend = make_merge_backend(self.config, tracer=self._tr)
         # device-resident WAN codec stage (ISSUE 20): compressed pushes
         # decode through jitted kernels straight into device arrays the
         # merge lanes seed without re-staging (zero full-tensor host
@@ -2805,6 +2837,8 @@ class GlobalServer:
         self._codec_stage = self._backend.make_codec_stage(self.config)
         self._mu, self._shards = make_merge_lanes(
             self.config, f"g{postoffice.node}", self._backend)
+        # the ``lane`` span of _ctx_bound: only where lanes are threads
+        self._lane_tr = None if self._shards.inline else self._tr
         self._ack_mu = threading.Lock()  # leaf lock: a parked push's
         #                                  remaining-keys set is shared
         #                                  across stripes
@@ -2893,11 +2927,9 @@ class GlobalServer:
         self._since_ckpt = 0
         self._ckpt_busy = False
         self._ckpt_pending = False
-        from geomx_tpu.trace.recorder import get_tracer
         from geomx_tpu.utils import get_profiler
 
         self._prof = get_profiler(str(postoffice.node))
-        self._tr = get_tracer(str(postoffice.node))
         # flight recorder (obs/flight.py): fence/promotion/round events
         # + this shard's merge-pressure sources; None when disabled
         self._flight = postoffice.flight
@@ -3095,7 +3127,7 @@ class GlobalServer:
             prof.count("push_bytes", float(msg.nbytes))
         span_name = ("global.init" if msg.cmd == Cmd.INIT
                      else "global.push" if msg.push else "global.pull")
-        with prof.span(span_name), self._tr.span(span_name):
+        with prof.span(span_name), self._tr.span(span_name, of=msg):
             self._handle_inner(msg, kvs, server)
 
     def _handle_inner(self, msg: Message, kvs: Optional[KVPairs],
@@ -3342,14 +3374,14 @@ class GlobalServer:
             # then jitted kernels land each gradient as a device array
             # the merge lanes seed with no re-staging.  Device dispatch
             # serializes anyway, so the host codec pool buys nothing.
-            with self._tr.span("codec.decode"):
+            with self._tr.span("codec.decode", of=msg):
                 vs = [self._codec_stage.decode(msg.compr, k, p, ln, thr)
                       for (k, p), ln in zip(pairs, lens)]
                 vals = vs[0] if len(vs) == 1 else self._codec_stage.concat(vs)
             return KVPairs(np.array([k for k, _ in pairs], dtype=np.int64),
                            vals, np.array(lens, dtype=np.int64))
         pool = codec_pool(self.config) if len(pairs) > 1 else None
-        with self._tr.span("codec.decode"):
+        with self._tr.span("codec.decode", of=msg):
             if pool is None:
                 vs = [decompress_payload(msg.compr, k, p, ln, thr,
                                          bank=self._decoders)
@@ -3466,11 +3498,23 @@ class GlobalServer:
                                    dissem_ok)
 
         for k, v in slices:
-            self._shards.submit(k, _ctx_bound(lambda k=k, v=v: merge_one(k, v)))
+            self._shards.submit(k, _ctx_bound(
+                lambda k=k, v=v: merge_one(k, v), self._lane_tr, k))
 
     def _complete_key_locked(self, k: int, hfa_delta: bool,
                              to_ack: List[tuple],
                              reparks: List[Message]) -> None:
+        """:meth:`_close_key_locked` under the ``global.close`` span:
+        the close of one key's round on this tier, from the last
+        contribution counted to the parked pulls' responses handed to
+        the van."""
+        with self._tr.span("global.close", key=k,
+                           contributors=self._keys[k].count):
+            self._close_key_locked(k, hfa_delta, to_ack, reparks)
+
+    def _close_key_locked(self, k: int, hfa_delta: bool,
+                          to_ack: List[tuple],
+                          reparks: List[Message]) -> None:
         """One completed key's update (caller holds stripe(k) or the
         all-stripes barrier): optimizer (or additive HFA delta), parked
         push ack collection, parked pull serving.  Appends (request,
@@ -3502,7 +3546,8 @@ class GlobalServer:
             st.parked_pushes.clear()
             st.deferred.clear()
             return
-        with self._tr.span("global.opt"):
+        with self._tr.span("global.opt", key=k,
+                           nbytes=getattr(st.accum, "nbytes", None)):
             dev = self._dev_opt
             if dev is not None:
                 # device-resident round close: the accumulator never
@@ -3903,7 +3948,7 @@ class GlobalServer:
         # cache and rng are shared across keys — a leaf lock (taken
         # under a stripe or the barrier, never the reverse) keeps them
         # coherent now that pull serving runs outside the big lock
-        with self._tr.span("codec.encode"), self._pc_mu:
+        with self._tr.span("codec.encode", of=req), self._pc_mu:
             self._respond_pull_compressed_inner(req, typ, size_bound)
 
     def _respond_pull_compressed_inner(self, req: Message, typ,

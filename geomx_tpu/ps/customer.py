@@ -13,6 +13,7 @@ from __future__ import annotations
 
 import queue
 import threading
+import time
 from typing import Callable, Dict, Optional
 
 from geomx_tpu.ps.postoffice import Postoffice
@@ -61,6 +62,7 @@ class Customer:
             else None
         )
         self._threads = []
+        self._tracer = None  # fetched by the first sampled message
         # lightweight-party mode (transport/reactor.py): handler threads
         # become serial channels on the shared reactor pool — identical
         # per-customer FIFO order (and the same split pull lane as a
@@ -183,10 +185,26 @@ class Customer:
         merge→push-up→pull-down chain) become children of the inbound
         message, which is what connects one round's spans across nodes.
         Callers gate on ``ACTIVE and msg.trace_id`` FIRST so untraced
-        messages pay one attribute read, not an extra frame."""
+        messages pay one attribute read, not an extra frame.
+
+        The ``handle`` span is the boundary of every server and worker
+        customer: how long the handler ran and, as ``queued_us``, how
+        long the message waited from ``Van.send`` to this line (in-proc
+        delivery only: the stamp does not cross the wire)."""
+        queued_us = ((time.monotonic() - msg.sent_mono) * 1e6
+                     if msg.sent_mono else None)
+        tr = self._tracer
+        if tr is None:
+            from geomx_tpu.trace.recorder import get_tracer
+
+            tr = self._tracer = get_tracer(str(self.postoffice.node))
+        op = (("push_pull" if msg.pull else "push") if msg.push
+              else "pull" if msg.pull else "ctrl")
         prev = _tctx.swap(_tctx.TraceContext(msg.trace_id, msg.span_id))
         try:
-            self._handler(msg)
+            with tr.span("handle", of=msg, cmd=int(msg.cmd), op=op,
+                         request=int(msg.request), queued_us=queued_us):
+                self._handler(msg)
         finally:
             _tctx.restore(prev)
 

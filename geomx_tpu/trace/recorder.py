@@ -14,6 +14,13 @@ Timestamps: events carry the profiler-relative ``ts`` (so a per-node
 ``args`` — the collector merges on the monotonic clock, corrected by the
 per-node offset estimated from heartbeat RTTs.
 
+On the profiler's clock: every recorded span also enters a
+``jax.profiler.TraceAnnotation`` named ``geomx:<node>:<span name>`` with
+the causal ids and the site's arguments (``key``, ``nbytes``,
+``queued_us``) as its keyword arguments, so that under a live profiler
+session the spans sit on the host plane of the device's own trace.  With
+no session the annotation is one no-op call.
+
 Overhead: ``span()`` / ``round()`` return the shared ``_NULL_SPAN``
 whenever tracing is inactive or the current thread carries no sampled
 context — no allocation, no branch beyond the gate, nothing stamped.
@@ -44,31 +51,75 @@ class _NullSpan:
 
 _NULL_SPAN = _NullSpan()
 
+# the span's name on the profiler's timeline: ``geomx:<node>:<name>``
+# (NOT the benchmark's ``bench:``, which names the workers' phases)
+ANNOTATION_PREFIX = "geomx:"
+_annotate = None
+
+
+def _annotation(name: str, args: dict):
+    """A ``jax.profiler.TraceAnnotation``: records only while a profiler
+    session is live.  jax is imported by the first sampled span, never
+    by a process that traces nothing."""
+    global _annotate
+    if _annotate is None:
+        from jax.profiler import TraceAnnotation
+
+        _annotate = TraceAnnotation
+    return _annotate(name, **args)
+
+
+def _carried(of) -> dict:
+    """``key`` and ``nbytes`` of the ``Message`` or ``KVPairs`` a site
+    works on, read on the sampled path only (a site hands over the
+    object, which costs nothing when tracing is off)."""
+    keys = of.keys
+    out = {}
+    if keys is not None and len(keys):
+        out["key"] = int(keys[0])
+    nbytes = getattr(of, "nbytes", None)  # a Message's wire size
+    if nbytes is None and of.vals is not None:
+        nbytes = of.vals.nbytes
+    if nbytes is not None:
+        out["nbytes"] = int(nbytes)
+    return out
+
 
 class _Span:
-    __slots__ = ("_tr", "name", "cat", "_enter_ctx", "_prev", "span_id",
-                 "parent", "trace_id", "_t0", "_t0_mono")
+    __slots__ = ("_tr", "name", "cat", "_prev", "span_id", "parent",
+                 "trace_id", "args", "_ann", "_t0", "dur_us")
 
     def __init__(self, tracer: "Tracer", name: str, cat: str,
-                 trace_id: int, parent: int):
+                 trace_id: int, parent: int, of=None, args=None):
         self._tr = tracer
         self.name = name
         self.cat = cat
         self.trace_id = trace_id
         self.parent = parent
         self.span_id = _ctx.new_span_id()
+        self.args = {k: v for k, v in args.items() if v is not None} \
+            if args else {}
+        if of is not None:
+            self.args.update(_carried(of))
+        self.dur_us = 0.0
 
     def __enter__(self):
         self._prev = _ctx.swap(_ctx.TraceContext(self.trace_id, self.span_id))
-        self._t0 = time.perf_counter()
-        self._t0_mono = time.monotonic()
+        self._ann = _annotation(
+            self._tr.annotation_name(self.name),
+            dict(self.args, trace_id=self.trace_id, span=self.span_id,
+                 parent=self.parent))
+        self._ann.__enter__()
+        # one clock for the start and the duration (CLOCK_MONOTONIC)
+        self._t0 = time.monotonic()
         return self
 
     def __exit__(self, *exc):
-        dur_us = (time.perf_counter() - self._t0) * 1e6
+        self.dur_us = (time.monotonic() - self._t0) * 1e6
+        self._ann.__exit__(*exc)
         _ctx.restore(self._prev)
-        self._tr._record(self.name, self.cat, dur_us, self.trace_id,
-                         self.span_id, self.parent, self._t0_mono)
+        self._tr._record(self.name, self.cat, self.dur_us, self.trace_id,
+                         self.span_id, self.parent, self._t0, **self.args)
         return False
 
 
@@ -85,17 +136,29 @@ class Tracer:
         self.batch_events = 256
         self.dropped_events = 0
         self._cap = 100_000
+        self._names: Dict[str, str] = {}
+
+    def annotation_name(self, name: str) -> str:
+        full = self._names.get(name)
+        if full is None:
+            full = self._names[name] = (
+                f"{ANNOTATION_PREFIX}{self.node}:{name}")
+        return full
 
     # ---- recording ----------------------------------------------------------
-    def span(self, name: str, cat: str = "trace"):
+    def span(self, name: str, cat: str = "trace", of=None, **args):
         """Timed child span of the thread's current context (no-op when
-        tracing is off or the context is unsampled)."""
+        tracing is off or the context is unsampled).  ``args`` are what
+        the site carries (``key``, ``nbytes``, ``queued_us``): plain
+        values already at hand, because the call evaluates them with
+        tracing off too; ``of`` is a ``Message`` or ``KVPairs`` whose
+        first key and bytes are read only when the span is recorded."""
         if not _ctx.ACTIVE:
             return _NULL_SPAN
         cur = _ctx.current()
         if cur is None:
             return _NULL_SPAN
-        return _Span(self, name, cat, cur.trace_id, cur.span_id)
+        return _Span(self, name, cat, cur.trace_id, cur.span_id, of, args)
 
     def round(self, round_idx: int, sample_every: int):
         """Root span of one sampled round: every node derives the same
@@ -120,8 +183,12 @@ class Tracer:
             cur = _ctx.current()
             if cur is not None:
                 trace_id, parent = cur.trace_id, cur.span_id
-        self._record(name, "event", 0.0, trace_id,
-                     span or _ctx.new_span_id(), parent,
+        span = span or _ctx.new_span_id()
+        with _annotation(self.annotation_name(name),
+                         dict(extra, trace_id=trace_id, span=span,
+                              parent=parent)):
+            pass
+        self._record(name, "event", 0.0, trace_id, span, parent,
                      time.monotonic(), **extra)
 
     def _record(self, name: str, cat: str, dur_us: float, trace_id: int,

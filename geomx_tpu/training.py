@@ -392,6 +392,18 @@ class Trainer:
         return metric.get()
 
 
+def _edge_to_host(kv: WorkerKVStore, tid: int, g,
+                  scale: float) -> np.ndarray:
+    """One gradient leaf across the slice edge, ``np.asarray(g) * scale``
+    as two statements so that a sampled round shows the copy off the
+    device (``edge.d2h``) apart from the host multiply (``edge.scale``)."""
+    with kv.trace_span("edge.d2h", key=tid,
+                       nbytes=getattr(g, "nbytes", None)):
+        host = np.asarray(g)
+    with kv.trace_span("edge.scale", key=tid, nbytes=host.nbytes):
+        return host * scale
+
+
 def run_worker(
     kv: WorkerKVStore,
     params,
@@ -456,7 +468,7 @@ def run_worker(
                 if kv.ts_push is not None:
                     # TS push direction: worker-to-worker merge tree; the
                     # elected holder pushes the merged set for the party
-                    kv.ts_merge_push({tid: np.asarray(g) * scale
+                    kv.ts_merge_push({tid: _edge_to_host(kv, tid, g, scale)
                                       for tid, g in enumerate(g_leaves)})
                     for tid in range(len(leaves)):
                         kv.pull(tid,
@@ -465,12 +477,13 @@ def run_worker(
                 elif kv.config.enable_p3:
                     # P3: sliced push+pull, values ride the response
                     for tid, g in enumerate(g_leaves):
-                        kv.push_pull(tid, np.asarray(g) * scale,
+                        kv.push_pull(tid, _edge_to_host(kv, tid, g, scale),
                                      lambda t, arr: buf.__setitem__(t, arr),
                                      priority=-tid)
                 else:
                     for tid, g in enumerate(g_leaves):
-                        kv.push(tid, np.asarray(g) * scale, priority=-tid)
+                        kv.push(tid, _edge_to_host(kv, tid, g, scale),
+                                priority=-tid)
                     for tid in range(len(leaves)):
                         kv.pull(tid,
                                 lambda t, arr: buf.__setitem__(t, arr),

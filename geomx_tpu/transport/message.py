@@ -253,6 +253,13 @@ class Message:
     span_id: int = 0
     parent_span_id: int = 0
     sampled: bool = False
+    # time.monotonic() at Van.send, for sampled messages only: what the
+    # receiving Customer's ``handle`` span reports as ``queued_us``.
+    # NOT on the wire: in-proc the object itself is delivered; over TCP
+    # it arrives as 0 and the collector's clock-corrected wan.send /
+    # wan.recv pairing stays the source.
+    sent_mono: float = dataclasses.field(default=0.0, repr=False,
+                                         compare=False)
 
     _nbytes_cache: Optional[int] = dataclasses.field(
         default=None, repr=False, compare=False

@@ -759,8 +759,12 @@ class Van:
                     msg.trace_id = ctx.trace_id
                     msg.parent_span_id = ctx.span_id
                     msg.sampled = True
-            if msg.trace_id > 0 and msg.span_id == 0:
-                msg.span_id = _tctx.new_span_id()
+            if msg.trace_id > 0:
+                if msg.span_id == 0:
+                    msg.span_id = _tctx.new_span_id()
+                # the receiver's ``handle`` span reads it: send to the
+                # handler's first line, the van's own queue included
+                msg.sent_mono = time.monotonic()
         if self._use_send_thread and msg.control is Control.EMPTY:
             # negative: PriorityQueue pops smallest first, we want highest first
             self._pq.put((-msg.priority, next(self._pq_tie), msg))

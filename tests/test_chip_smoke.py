@@ -86,7 +86,7 @@ def test_cache_dir_defaults_to_fixed_checkout_path(monkeypatch,
 def test_merge_backend_raises_when_jax_backend_cannot_be_built(monkeypatch):
     from geomx_tpu.kvstore import backend, jax_backend
 
-    def broken(self, config=None):
+    def broken(self, config=None, tracer=None):
         raise RuntimeError("device backend failed to initialize")
 
     monkeypatch.setattr(jax_backend.JaxBackend, "__init__", broken)

@@ -251,6 +251,10 @@ def test_failover_visible_in_cluster_state_and_round_stall_alert():
         # promotion lands; the console shows it within one collection
         # interval of the next sweep
         assert _wait_for(lambda: not sb1.is_standby), "promotion stalled"
+        # (the state is collected on the next sweep: asserting at once
+        # lost that race about one run in twelve)
+        assert _wait_for(lambda: sim.cluster_state()["shards"][1]["holder"]
+                         == "standby_global:1"), "console never showed it"
         st = sim.cluster_state()
         assert st["shards"][1]["holder"] == "standby_global:1"
         assert st["shards"][1]["term"] == 1

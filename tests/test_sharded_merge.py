@@ -180,8 +180,13 @@ def test_pull_not_blocked_behind_other_keys_merge():
     single-lock half lives in test_robustness.py.  lightweight=False:
     lightweight mode runs merge lanes inline with server_shards forced
     to 1 — the sharded configuration under test doesn't exist there."""
+    # 3 stripes, not 4: a tensor's ps key is ``tensor_id << 20``
+    # (kvstore/keys.py CHUNK_SPACE) and a stripe is ``key % n``, so under
+    # any power-of-two count EVERY key shares stripe 0 and this test was
+    # a race between the pull and the wedged lane for that one stripe
+    # (lost under load).  2**20 % 3 == 1: keys 0 and 1 differ here.
     cfg = Config(topology=Topology(num_parties=1, workers_per_party=2),
-                 server_shards=4)
+                 server_shards=3)
     sim = Simulation(cfg, lightweight=False)
     try:
         ws = sim.all_workers()
@@ -190,6 +195,8 @@ def test_pull_not_blocked_behind_other_keys_merge():
             w.init(0, np.zeros(64, np.float32))
             w.init(1, np.zeros(64, np.float32))
         ls = sim.local_servers[0]
+        k0, k1 = (ws[0].plan.parts(t, 64)[0].ps_key for t in (0, 1))
+        assert ls._mu.stripe(k0) is not ls._mu.stripe(k1)
         block = threading.Event()
         from geomx_tpu.native import bindings as nb
         orig = nb.accumulate

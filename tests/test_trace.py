@@ -207,6 +207,45 @@ def test_retarget_replay_keeps_original_trace_id():
     fabric.shutdown()
 
 
+def _backend_sites_get_the_null_span():
+    """Every site ISSUE 26 added to the jax merge backend and the merge
+    lanes, with ``context.ACTIVE`` false: ``_NULL_SPAN`` each, and the
+    operator gauges still fed from the site's own clock."""
+    from geomx_tpu.kvstore.jax_backend import JaxBackend
+    from geomx_tpu.kvstore.server import _ctx_bound
+
+    class Spy(Tracer):
+        def __init__(self):
+            super().__init__("spy-node")
+            self.seen = []
+
+        def span(self, name, *a, **kw):
+            got = super().span(name, *a, **kw)
+            self.seen.append((name, got))
+            return got
+
+    spy = Spy()
+    cfg = Config(topology=Topology(), merge_backend="jax")
+    be = JaxBackend(cfg, tracer=spy)
+    small = np.ones(64, np.float32)      # folded on one device
+    be.materialize(be.accumulate(be.seed(small, False, key=2), small))
+    v = np.ones(1 << 16, np.float32)     # one slot a device, reduced
+    acc = be.accumulate(be.seed(v, donated=False, key=3), v)
+    acc = be.scale(acc, 0.5)
+    opt = be.make_device_optimizer({"type": "sgd", "lr": 0.1})
+    opt.step(3, np.zeros(1 << 16, np.float32), acc, 1.0).host()
+    stage = be.make_codec_stage(cfg)
+    stage._wire(stage.make_push_codec({"type": "fp16"})._enc(
+        stage._ensure_device(v)))
+    assert {n for n, _ in spy.seen} == {
+        "be.h2d", "be.add", "be.scale", "be.reduce", "be.d2h", "opt.step"}
+    assert all(got is _NULL_SPAN for _, got in spy.seen)
+    st = be.stats()
+    assert st["merge_device_ms"] > 0 and st["opt_device_ms"] > 0
+    fn = lambda: None  # noqa: E731
+    assert _ctx_bound(fn, spy, 1) is fn
+
+
 def test_disabled_tracing_no_per_message_work():
     """Tier-1 overhead guard (satellite): with tracing off, spans are
     gated BEFORE construction (the factory returns one shared no-op
@@ -222,8 +261,13 @@ def test_disabled_tracing_no_per_message_work():
         assert tr.span("local.push") is _NULL_SPAN
         assert tr.span("anything") is tr.span("else")
         assert tr.round(0, 0) is _NULL_SPAN
+        # ...whatever the site carries (ISSUE 26: key / nbytes / the
+        # message itself, read only when a span is recorded)
+        assert tr.span("be.add", key=1, nbytes=2) is _NULL_SPAN
+        assert tr.span("handle", of=Message(), queued_us=None) is _NULL_SPAN
         tr.instant("evict.worker")  # gated: records nothing
         assert tr.pending() == 0
+        _backend_sites_get_the_null_span()
 
         topo = Topology(num_parties=1, workers_per_party=1)
         fabric = InProcFabric()
@@ -233,6 +277,7 @@ def test_disabled_tracing_no_per_message_work():
             msg = Message(recipient=topo.server(0), domain=Domain.LOCAL,
                           control=Control.HEARTBEAT)
             po.van.send(msg)
+            assert msg.sent_mono == 0  # no queue stamp either
             assert msg.trace_id == 0
             assert msg.span_id == 0
             assert msg.parent_span_id == 0
