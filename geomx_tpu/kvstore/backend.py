@@ -90,10 +90,26 @@ class MergeBackend:
         raise NotImplementedError
 
     def materialize(self, acc) -> np.ndarray:
-        """The accumulator as a host f32 ndarray the server owns (the
-        identity on the numpy path — NO copy; a device sync + one D2H
-        on an accelerator path)."""
+        """The accumulator as a host f32 ndarray that nothing but the
+        caller sees (the identity on the numpy path — NO copy; a device
+        sync + one D2H on an accelerator path).  It MAY BE READ-ONLY: a
+        D2H result comes frozen and is handed on as it is — the
+        codebase's own immutability promise, which every reader (WAN
+        pack, host codecs, the raw stash, HFA's local apply) takes
+        without a copy.  A consumer that BUILDS IN the round takes its
+        copy at the point of mutation, through the servers'
+        copy-on-write gate (``kvstore.server._mutable_round``), which
+        reports it to :meth:`count_cow`.  Returns only when every
+        staged push of the round has been read off its sender's
+        buffer: the local tier's round close calls it with no stripe
+        held, and before the push that completed the round is acked
+        (docs/merge-backends.md "Round close")."""
         raise NotImplementedError
+
+    def count_cow(self, nbytes: int) -> None:
+        """A consumer copied a frozen materialized round to write into
+        it (``cow_bytes`` where the backend keeps counters; the numpy
+        path never hands out a frozen round)."""
 
     def stats(self) -> dict:
         """Observability: merged into the server's QUERY_STATS body."""

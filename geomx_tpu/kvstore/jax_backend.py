@@ -208,6 +208,10 @@ class JaxBackend(MergeBackend):
         self._mu = threading.Lock()  # counters + caches (leaf lock)
         self.h2d_bytes = 0
         self.d2h_bytes = 0
+        # bytes of materialized rounds a consumer copied because it had
+        # to write into a frozen one (the second copy of a round close:
+        # 0 wherever the round is only read)
+        self.cow_bytes = 0
         self.merge_device_ms = 0.0
         self.opt_device_ms = 0.0
         # codec-stage counters (ISSUE 20): wall spent in jitted codec
@@ -299,12 +303,20 @@ class JaxBackend(MergeBackend):
             host = np.asarray(self._reduced(acc))  # block + one D2H
             with self._mu:
                 self.d2h_bytes += host.nbytes
-            if not host.flags.writeable:
-                # the CPU jax backend hands out a read-only view of the
-                # device buffer; the server OWNS the materialized round
-                # (optimizer builds the update in it — donated contract)
+            if self._platform == "cpu":
+                # jax hands out a READ-ONLY array on every platform.  On
+                # an accelerator it is a fresh host buffer nothing else
+                # sees, and goes on frozen: whoever builds in the round
+                # copies at that point (count_cow).  On the CPU client
+                # it is a VIEW of the device buffer, which may itself
+                # alias the sender's non-donated payload (see
+                # accumulate): this copy is the isolation copy
                 host = host.copy()
         return host
+
+    def count_cow(self, nbytes: int) -> None:
+        with self._mu:
+            self.cow_bytes += int(nbytes)
 
     def _reduced(self, acc: "_DeviceAccum"):
         if len(acc.parts) == 1:
@@ -490,7 +502,8 @@ class JaxBackend(MergeBackend):
                     "codec_d2h_bytes": self.codec_d2h_bytes,
                     "codec_host_bytes": self.codec_host_bytes,
                     "h2d_bytes": self.h2d_bytes,
-                    "d2h_bytes": self.d2h_bytes}
+                    "d2h_bytes": self.d2h_bytes,
+                    "cow_bytes": self.cow_bytes}
 
 
 class DeviceWeight:

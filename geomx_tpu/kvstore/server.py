@@ -192,6 +192,47 @@ def _mutable(arr: np.ndarray) -> np.ndarray:
     return arr if arr.flags.writeable else arr.copy()
 
 
+def _mutable_round(backend, host: np.ndarray) -> np.ndarray:
+    """:func:`_mutable` for a materialized round.
+
+    ``MergeBackend.materialize`` hands a D2H result on frozen, as it
+    came: most consumers only read it.  The ones that BUILD IN the
+    round (the host optimizer's donated update, the row-sparse scatter)
+    take their copy here, at the point of mutation, and the backend
+    counts it (``cow_bytes``)."""
+    own = _mutable(host)
+    if own is not host:
+        backend.count_cow(own.nbytes)
+    return own
+
+
+def _merge_stats(node, backend) -> dict:
+    """``backend.stats()`` for a server's QUERY_STATS body, with the
+    jax path's time and byte counters mirrored to the registry for the
+    status console (absent under the numpy backend)."""
+    out = backend.stats()
+    ms = out.get("merge_device_ms")
+    if ms is not None:
+        from geomx_tpu.utils.metrics import system_gauge
+
+        system_gauge(f"{node}.merge_device_ms").set(ms)
+        system_gauge(f"{node}.h2d_bytes").set(out.get("h2d_bytes") or 0)
+        # device->host traffic + optimizer-stage time: the
+        # steady-state zero-D2H contract is audited on these
+        system_gauge(f"{node}.d2h_bytes").set(out.get("d2h_bytes") or 0)
+        # the round close's second copy: 0 while rounds are only read
+        system_gauge(f"{node}.cow_bytes").set(out.get("cow_bytes") or 0)
+        system_gauge(f"{node}.opt_device_ms").set(
+            out.get("opt_device_ms") or 0)
+        # codec stage (ISSUE 20): kernel time + wire-ready compressed
+        # D2H — host_copy auditing rides the same stats
+        system_gauge(f"{node}.codec_device_ms").set(
+            out.get("codec_device_ms") or 0)
+        system_gauge(f"{node}.codec_d2h_bytes").set(
+            out.get("codec_d2h_bytes") or 0)
+    return out
+
+
 class _KeyState:
     """Per-ps-key aggregation state on the local server."""
 
@@ -1532,6 +1573,9 @@ class LocalServer:
                     # decide→retake window a parallel lane could
                     # otherwise merge the next round's gradient into
                     bundle = self._take_completed_locked(k)
+            if bundle is not None:
+                # stripe released; still before the ack below
+                self._materialize_round(bundle)
             with done_mu:
                 if bundle is not None:
                     bundles.append(bundle)
@@ -1693,13 +1737,17 @@ class LocalServer:
                 else:
                     # a dense push may have seeded this key on a device
                     # backend; the scatter-add is host-side by design
-                    st.accum = self._backend.materialize(st.accum)
+                    st.accum = _mutable_round(
+                        self._backend,
+                        self._backend.materialize(st.accum))
                 np.add.at(st.accum.reshape(-1, cols), row_ids, rows)
                 st.count += 1
                 st.row_sparse = True
                 if (st.count >= (st.expected or self.num_workers)
                         and not st.completing):
                     bundle = self._take_completed_locked(key)
+            if bundle is not None:
+                self._materialize_round(bundle)
             err = getattr(msg, "_gx_poisoned", None)
             self._recent.mark_done(msg, err)
             self.server.response(msg, body=err)
@@ -1732,10 +1780,11 @@ class LocalServer:
 
     def _take_completed_locked(self, k: int) -> dict:
         """:meth:`_take_round_locked` under the ``local.close`` span:
-        the close of one key's round on this tier, from the last
-        contribution counted to the detached (scaled, materialized)
-        bundle; the push-up that follows is ``codec.encode`` and the
-        van's sends under the same ``local.push``."""
+        what the close of one key's round holds the stripe for, from
+        the last contribution counted to the detached (scaled)
+        accumulator.  :meth:`_materialize_round` (``be.d2h``) and the
+        push-up (``codec.encode``, the van's sends) follow with no
+        stripe held, under the same ``local.push``."""
         with self._tr.span("local.close", key=k,
                            contributors=self._keys[k].count):
             return self._take_round_locked(k)
@@ -1748,7 +1797,10 @@ class LocalServer:
         completed the round short): dividing by Σ 1/n_i keeps the
         result a weighted MEAN of weight vectors, never
         scale-inflated/shrunk — resets the per-round state, and returns
-        the round bundle :meth:`_dispatch_rounds` ships."""
+        the round bundle with the accumulator DETACHED, not yet a
+        value: the stripe is held to decide and detach, never to wait
+        for the device or to copy the model
+        (:meth:`_materialize_round` does that, stripe released)."""
         st = self._keys[k]
         st.round += 1
         gated = self.hfa_enabled and st.round % self.hfa_k2 != 0
@@ -1774,9 +1826,8 @@ class LocalServer:
                        and self.ts_push_inter is None
                        and not self._adaptive and not self._degraded
                        and not isinstance(st.accum, np.ndarray))
-        v = (self._codec_stage.round_value(st.accum) if keep_device
-             else self._backend.materialize(st.accum))
-        bundle = {"k": k, "v": v, "gated": gated, "rs": st.row_sparse}
+        bundle = {"k": k, "acc": st.accum, "keep_device": keep_device,
+                  "gated": gated, "rs": st.row_sparse}
         st.hfa_inv = 0.0
         st.accum = None
         st.count = 0
@@ -1785,6 +1836,24 @@ class LocalServer:
         st.contributors = set()
         st.in_flight += 1  # round launched; finish decrements
         st.row_sparse = False  # describes this round only
+        return bundle
+
+    def _materialize_round(self, bundle: dict) -> dict:
+        """The detached accumulator becomes the value
+        :meth:`_dispatch_rounds` ships: the device codec's handle, or
+        the wait for the device and the round's one D2H (``be.d2h``).
+        No stripe is needed (the accumulator is this thread's alone
+        since the detach), so none is held on the hot path: the key's
+        own pulls stay parked on ``in_flight``, every other key's
+        pulls and pull-downs go on meanwhile.  Runs on the thread that
+        detached the round and BEFORE the completing push is acked: a
+        worker's push aliases its caller's buffer until the ack and the
+        staged H2D reads that buffer asynchronously — this blocking D2H
+        is what retires the alias."""
+        acc = bundle.pop("acc")
+        bundle["v"] = (self._codec_stage.round_value(acc)
+                       if bundle.pop("keep_device")
+                       else self._backend.materialize(acc))
         return bundle
 
     def _dispatch_rounds(self, bundles: List[dict]):
@@ -1833,7 +1902,8 @@ class LocalServer:
         membership-fold path (caller holds the all-stripes barrier, so
         the per-key takes below just re-enter their stripes)."""
         self._dispatch_rounds(
-            [self._take_completed_locked(k) for k in sorted(keys)])
+            [self._materialize_round(self._take_completed_locked(k))
+             for k in sorted(keys)])
 
     def _apply_local(self, kvs: KVPairs):
         """HFA off-round: the merged push is already the party-mean weight
@@ -2679,30 +2749,8 @@ class LocalServer:
             # merge backend observability (kvstore/backend.py):
             # merge_backend name + the jax path's merge_device_ms /
             # h2d_bytes, mirrored to the registry for the status console
-            **self._merge_stats(),
+            **_merge_stats(self.po.node, self._backend),
         }
-
-    def _merge_stats(self) -> dict:
-        out = self._backend.stats()
-        ms, h2d = out.get("merge_device_ms"), out.get("h2d_bytes")
-        if ms is not None:
-            from geomx_tpu.utils.metrics import system_gauge
-
-            system_gauge(f"{self.po.node}.merge_device_ms").set(ms)
-            system_gauge(f"{self.po.node}.h2d_bytes").set(h2d or 0)
-            # device->host traffic + optimizer-stage time: the
-            # steady-state zero-D2H contract is audited on these
-            system_gauge(f"{self.po.node}.d2h_bytes").set(
-                out.get("d2h_bytes") or 0)
-            system_gauge(f"{self.po.node}.opt_device_ms").set(
-                out.get("opt_device_ms") or 0)
-            # codec stage (ISSUE 20): encode kernel time + wire-ready
-            # compressed D2H — host_copy auditing rides the same stats
-            system_gauge(f"{self.po.node}.codec_device_ms").set(
-                out.get("codec_device_ms") or 0)
-            system_gauge(f"{self.po.node}.codec_d2h_bytes").set(
-                out.get("codec_d2h_bytes") or 0)
-        return out
 
     def leave_global(self, timeout: float = 30.0) -> dict:
         """Gracefully withdraw this PARTY from the global tier (VERDICT
@@ -3574,9 +3622,11 @@ class GlobalServer:
                 else:
                     # accum is donated: update_scaled may build the new
                     # weights in it, skipping the /num temporary and the
-                    # result allocation (big-tensor hot path)
+                    # result allocation (big-tensor hot path) — so a
+                    # frozen D2H result is copied here, and only here
                     new_w = self.optimizer.update_scaled(
-                        k, self.store[k], accum,
+                        k, self.store[k],
+                        _mutable_round(self._backend, accum),
                         1.0 / self.num_contributors)
             with self._wv_mu:
                 self.store[k] = new_w
@@ -4759,31 +4809,9 @@ class GlobalServer:
             # restart discrimination (see LocalServer.stats)
             "uptime_s": self.po.uptime_s(),
             "boot": van.boot,
-            # merge backend observability (see LocalServer._merge_stats)
-            **self._merge_stats(),
+            # merge backend observability (see _merge_stats)
+            **_merge_stats(self.po.node, self._backend),
         }
-
-    def _merge_stats(self) -> dict:
-        out = self._backend.stats()
-        ms, h2d = out.get("merge_device_ms"), out.get("h2d_bytes")
-        if ms is not None:
-            from geomx_tpu.utils.metrics import system_gauge
-
-            system_gauge(f"{self.po.node}.merge_device_ms").set(ms)
-            system_gauge(f"{self.po.node}.h2d_bytes").set(h2d or 0)
-            # device->host traffic + optimizer-stage time: the
-            # steady-state zero-D2H contract is audited on these
-            system_gauge(f"{self.po.node}.d2h_bytes").set(
-                out.get("d2h_bytes") or 0)
-            system_gauge(f"{self.po.node}.opt_device_ms").set(
-                out.get("opt_device_ms") or 0)
-            # codec stage (ISSUE 20): decode kernel time + wire-ready
-            # compressed D2H — host_copy auditing rides the same stats
-            system_gauge(f"{self.po.node}.codec_device_ms").set(
-                out.get("codec_device_ms") or 0)
-            system_gauge(f"{self.po.node}.codec_d2h_bytes").set(
-                out.get("codec_d2h_bytes") or 0)
-        return out
 
     def stop(self):
         if self._repl is not None:

@@ -253,3 +253,244 @@ def test_deterministic_suite_unaffected():
     w_b, be_b, _ = _train_rounds(merge_backend="numpy", deterministic=True)
     assert be_a == be_b == "numpy"
     assert w_a.tobytes() == w_b.tobytes()
+
+
+# ---- the round close: one D2H, handed on frozen (ISSUE 27) -------------------
+
+class _FreshFrozenPart:
+    """What an accelerator's device array is to ``np.asarray``: every
+    D2H lands in a fresh, read-only host buffer (jax freezes it on
+    every platform; only the CPU client hands out a view instead)."""
+
+    def __init__(self, v):
+        self._v = np.array(v, np.float32)
+        self.handed_out = []
+
+    def __array__(self, dtype=None, copy=None):
+        out = self._v.copy()
+        out.flags.writeable = False
+        self.handed_out.append(out)
+        return out
+
+
+def _as_accelerator(be):
+    """Steer a jax backend down its accelerator branch on the CPU
+    client: what it decides by is the platform it observed."""
+    be._platform = "tpu"
+    return be
+
+
+def _materialize_fresh_frozen():
+    from geomx_tpu.kvstore.jax_backend import _DeviceAccum
+
+    be = _as_accelerator(_jax_backend())
+    part = _FreshFrozenPart(np.arange(1024))
+    out = be.materialize(_DeviceAccum(part, 1024, False, key=7))
+    # the D2H result itself, frozen: no second copy of the round
+    assert len(part.handed_out) == 1 and out is part.handed_out[0]
+    assert not out.flags.writeable
+    st = be.stats()
+    assert (st["d2h_bytes"], st["cow_bytes"]) == (out.nbytes, 0)
+    # a consumer that builds in the round copies once, and is counted
+    from geomx_tpu.kvstore.server import _mutable_round
+
+    own = _mutable_round(be, out)
+    assert own is not out and own.flags.writeable
+    own += 1.0  # (writing into ``out`` would raise: numpy enforces it)
+    np.testing.assert_array_equal(out, np.arange(1024, dtype=np.float32))
+    assert be.stats()["cow_bytes"] == out.nbytes
+    assert _mutable_round(be, own) is own
+    assert be.stats()["cow_bytes"] == out.nbytes
+
+
+def _materialize_cpu_view_isolated():
+    # on the CPU client device_put aliases an aligned host buffer and
+    # np.asarray is a view of it: the materialized round could be the
+    # sender's own (non-donated) memory, so the isolation copy stays
+    be = _jax_backend()
+    assert be._platform == "cpu"
+    v = np.arange(4096, dtype=np.float32)
+    out = be.materialize(be.seed(v, donated=False))
+    assert out.flags.writeable and not np.shares_memory(out, v)
+    v[:] = -1.0
+    np.testing.assert_array_equal(out, np.arange(4096, dtype=np.float32))
+    assert be.stats()["cow_bytes"] == 0  # isolation is not a COW
+    # a host-seeded (row-sparse) round passes through, as ever
+    host = np.ones(8, np.float32)
+    assert be.materialize(host) is host
+
+
+def _local_server_forwards_frozen():
+    # the local tier only READS the round (WAN pack, push-up): it goes
+    # up frozen, as it came off the device, and nothing is copied
+    sim = Simulation(Config(
+        topology=Topology(num_parties=1, workers_per_party=1),
+        merge_backend="jax"))
+    try:
+        ls = sim.local_servers[0]
+        _as_accelerator(ls._backend)
+        sent = []
+        zpush = ls.up.zpush
+        ls.up.zpush = lambda kvs, **kw: (sent.append(kvs.vals),
+                                         zpush(kvs, **kw))[1]
+        w = sim.all_workers()[0]
+        w.init(0, np.zeros(2048, np.float32))
+        w.set_optimizer({"type": "sgd", "lr": 1.0})
+        sent.clear()  # the init went up too
+        for r in range(3):
+            w.push(0, np.full(2048, float(r + 1), np.float32))
+            w.wait_all()
+            np.testing.assert_array_equal(
+                w.pull_sync(0),
+                np.full(2048, -sum(range(1, r + 2)), np.float32))
+        assert len(sent) == 3
+        assert not any(v.flags.writeable for v in sent)
+        st = ls.stats()
+        assert st["cow_bytes"] == 0
+        assert st["d2h_bytes"] == st["h2d_bytes"] == 3 * 2048 * 4
+    finally:
+        sim.shutdown()
+
+
+@pytest.mark.parametrize("case", [
+    _materialize_fresh_frozen, _materialize_cpu_view_isolated,
+    _local_server_forwards_frozen], ids=lambda f: f.__name__.strip("_"))
+def test_materialize_hands_on_one_copy(case):
+    case()
+
+
+def _host_opt_rounds(opt, frozen, **cfg_kw):
+    """Three two-party rounds closed by the HOST optimizer at the
+    global tier; returns (weights, the global server's stats)."""
+    sim = Simulation(Config(
+        topology=Topology(num_parties=2, workers_per_party=1), **cfg_kw))
+    try:
+        gs = sim.global_servers[0]
+        if frozen:
+            _as_accelerator(gs._backend)
+        ws = sim.all_workers()
+        for w in ws:
+            w.init(0, np.zeros(2048, np.float32))
+        ws[0].set_optimizer(opt)
+        rng = np.random.default_rng(5)
+        for _ in range(3):
+            for w in ws:
+                w.push(0, rng.integers(-8, 8, 2048).astype(np.float32))
+            for w in ws:
+                w.pull_sync(0)
+                w.wait_all()
+        return np.array(ws[0].pull_sync(0)), gs.stats()
+    finally:
+        sim.shutdown()
+
+
+@pytest.mark.parametrize("frozen", [True, False],
+                         ids=["frozen-d2h", "cpu-copy"])
+@pytest.mark.parametrize("opt", [
+    {"type": "sgd", "lr": 0.1},          # builds new_w IN the round
+    {"type": "dcasgd", "lr": 0.1},       # scales the round in place
+    {"type": "adam", "lr": 0.1},
+], ids=lambda o: o["type"])
+def test_host_optimizer_close_copies_on_write(opt, frozen):
+    """The global tier's host-optimizer close (device stage off, or an
+    optimizer it does not cover) is the consumer that WRITES into the
+    materialized round (``update_scaled``: accum is donated).  A frozen
+    D2H result is copied there, once, and counted; the trajectory is
+    the numpy backend's bit for bit, and no frozen round is written
+    into (numpy would raise "assignment destination is read-only")."""
+    w_np, _ = _host_opt_rounds(opt, False, merge_backend="numpy")
+    w_jx, st = _host_opt_rounds(opt, frozen, merge_backend="jax",
+                                merge_opt_device=False)
+    assert st["merge_backend"] == "jax" and st["opt_device"] == ""
+    assert w_np.tobytes() == w_jx.tobytes()
+    assert st["d2h_bytes"] == 3 * 2048 * 4
+    assert st["cow_bytes"] == (st["d2h_bytes"] if frozen else 0)
+
+
+@pytest.mark.parametrize("frozen", [True, False],
+                         ids=["frozen-d2h", "cpu-copy"])
+def test_row_sparse_scatter_into_device_round_copies_on_write(frozen):
+    """The other writer: a row-sparse push meeting a round that a dense
+    push seeded on the device scatters (``np.add.at``) into the
+    materialized round, through the same gate."""
+    from geomx_tpu.kvstore.server import _mutable_round
+
+    be = _jax_backend()
+    if frozen:
+        _as_accelerator(be)
+    dense = np.arange(64, dtype=np.float32)
+    host = _mutable_round(be, be.materialize(be.seed(dense, True)))
+    np.add.at(host.reshape(-1, 8), [1, 1], np.ones((2, 8), np.float32))
+    want = dense.copy().reshape(-1, 8)
+    want[1] += 2.0
+    np.testing.assert_array_equal(host, want.ravel())
+    assert be.stats()["cow_bytes"] == (host.nbytes if frozen else 0)
+
+
+def test_payload_overwritten_after_ack_does_not_reach_global_tier():
+    """The aliasing contract (``WorkerKVStore.push``): a non-donated
+    payload aliases the caller's buffer until the ack, never after.
+    The round close's D2H runs outside the server's lock but BEFORE the
+    ack, and on the CPU client its copy is what cuts the alias chain
+    (payload -> staged device buffer -> np.asarray view -> the global
+    tier's staged buffer): a buffer reused right after the ack must not
+    change the round the global tier merges."""
+    sim = Simulation(Config(
+        topology=Topology(num_parties=2, workers_per_party=1),
+        merge_backend="jax"))
+    try:
+        ws = sim.all_workers()
+        for w in ws:
+            w.init(0, np.zeros(4096, np.float32))
+        ws[0].set_optimizer({"type": "sgd", "lr": 1.0})
+        for r in range(3):
+            buf = np.full(4096, 3.0, np.float32)
+            ws[0].push(0, buf)
+            ws[0].wait_all()          # acked: the buffer is ours again
+            buf[:] = 1e6              # ... while party 1's round is open
+            ws[1].push(0, np.full(4096, 5.0, np.float32))
+            ws[1].wait_all()
+            for w in ws:
+                np.testing.assert_array_equal(
+                    w.pull_sync(0),
+                    np.full(4096, -4.0 * (r + 1), np.float32))  # mean of 3, 5
+    finally:
+        sim.shutdown()
+
+
+def test_membership_fold_that_closes_a_round_still_ships_it():
+    """The fold path (a leave lowers the target under the all-stripes
+    barrier and the open round completes without the leaver) detaches
+    AND materializes under the barrier, as before: the round is
+    shipped, not lost between the two halves of the close."""
+    sim = Simulation(Config(
+        topology=Topology(num_parties=1, workers_per_party=2),
+        merge_backend="jax"))
+    try:
+        ws = sim.all_workers()
+        for w in ws:
+            w.init(0, np.zeros(256, np.float32))
+        ws[0].set_optimizer({"type": "sgd", "lr": 1.0})
+        w3 = sim.add_worker(0)
+        w3.init(0, np.zeros(256, np.float32))
+        g = np.ones(256, np.float32)
+        for w in ws + [w3]:
+            w.push(0, g)
+        for w in ws + [w3]:
+            np.testing.assert_array_equal(w.pull_sync(0), -3.0 * g)
+            w.wait_all()
+        ls = sim.local_servers[0]
+        d2h = ls.stats()["d2h_bytes"]
+        ws[0].push(0, g)
+        ws[1].push(0, g)              # 2 of 3: the round stalls ...
+        for w in ws:
+            w.wait_all()
+        assert w3.leave_party()["num_workers"] == 2   # ... and folds
+        for w in ws:
+            np.testing.assert_array_equal(w.pull_sync(0), -5.0 * g)
+        st = ls.stats()
+        assert st["d2h_bytes"] == d2h + g.nbytes and st["cow_bytes"] == 0
+        assert all(s.accum is None and s.in_flight == 0
+                   for s in ls._keys.values())
+    finally:
+        sim.shutdown()

@@ -235,3 +235,120 @@ def test_deterministic_mode_forces_single_shard():
     assert resolve_server_shards(cfg) == 1
     cfg2 = Config(topology=Topology(), server_shards=6)
     assert resolve_server_shards(cfg2) == 6
+
+
+# ---- the round close off the server's lock (ISSUE 27) ------------------------
+
+def test_round_close_d2h_holds_no_stripe():
+    """The stripe is held to DECIDE and DETACH a round, never to wait
+    for the device or to copy the model.  Under the reactor default a
+    server has ONE stripe: wedge ``materialize`` (the round close's
+    D2H) of key B and everything else that takes the lock must go on
+    — a worker's pull of key A, a pull-down of key A — while key B's
+    own pull stays parked behind its round (``in_flight``, set at the
+    detach), and is served with the new weights once the round is
+    through."""
+    sim = Simulation(Config(
+        topology=Topology(num_parties=1, workers_per_party=1),
+        merge_backend="jax"))
+    try:
+        w = sim.all_workers()[0]
+        w.set_optimizer({"type": "sgd", "lr": 1.0})
+        for t in (0, 1):
+            w.init(t, np.zeros(64, np.float32))
+        ls = sim.local_servers[0]
+        assert ls._mu.n == 1, "the reactor default is one lock a server"
+        ka, kb = (w.plan.parts(t, 64)[0].ps_key for t in (0, 1))
+        wedged, release = threading.Event(), threading.Event()
+        materialize = ls._backend.materialize
+
+        def wedge(acc):
+            if acc.key == kb:
+                wedged.set()
+                assert release.wait(20)
+            return materialize(acc)
+
+        ls._backend.materialize = wedge
+        try:
+            w.push(1, np.ones(64, np.float32))   # one worker: closes B
+            assert wedged.wait(5)
+            # B's round is detached and launched, its stripe free
+            st = ls._keys[kb]
+            assert st.accum is None and st.in_flight == 1
+            t0 = time.monotonic()
+            np.testing.assert_array_equal(w.pull_sync(0),
+                                          np.zeros(64, np.float32))
+            fresh = np.full(64, 7.0, np.float32)
+            down = threading.Thread(target=ls._on_pull_down, args=(
+                KVPairs(np.array([ka], np.int64), fresh,
+                        np.array([64], np.int64)),))
+            down.start()
+            down.join(5)
+            assert not down.is_alive(), "pull-down waited for the D2H"
+            np.testing.assert_array_equal(w.pull_sync(0), fresh)
+            assert time.monotonic() - t0 < 5.0
+            # B's own pull parks: fresher weights are owed to it (sent
+            # past the client, which holds a tensor's pull back until
+            # its push is acked — and that ack follows the D2H)
+            got = []
+            ts = w.worker.zpull([kb], cb=got.append, cmd=Cmd.DEFAULT)
+            deadline = time.monotonic() + 5
+            while not st.parked_pulls and time.monotonic() < deadline:
+                time.sleep(0.01)
+            assert st.parked_pulls and not got, "served a stale B"
+        finally:
+            release.set()
+        w.worker.wait(ts)
+        w.wait_all()
+        np.testing.assert_array_equal(got[0].vals,
+                                      -np.ones(64, np.float32))
+    finally:
+        sim.shutdown()
+
+
+def test_pushes_up_keep_per_key_order_across_lanes():
+    """With merge lanes as threads (three stripes, three keys) each
+    lane detaches, materializes and ships its key's rounds in turn, so
+    a key's rounds reach the global tier in the order they closed
+    whatever the D2H of each took — 20 back-to-back rounds, the D2H
+    jittered."""
+    cfg = Config(topology=Topology(num_parties=1, workers_per_party=1),
+                 server_shards=3, merge_backend="jax")
+    sim = Simulation(cfg, lightweight=False)   # lanes are threads
+    try:
+        w = sim.all_workers()[0]
+        w.set_optimizer({"type": "sgd", "lr": 1.0})
+        for t in range(3):
+            w.init(t, np.zeros(64, np.float32))
+        ls = sim.local_servers[0]
+        keys = [w.plan.parts(t, 64)[0].ps_key for t in range(3)]
+        assert len({id(ls._mu.stripe(k)) for k in keys}) == 3
+        rng = np.random.default_rng(0)
+        naps = {k: iter(rng.uniform(0, 0.01, 20)) for k in keys}
+        materialize = ls._backend.materialize
+
+        def jittered(acc):
+            time.sleep(next(naps[acc.key]))
+            return materialize(acc)
+
+        ls._backend.materialize = jittered
+        shipped = {k: [] for k in keys}
+        push_up = ls._push_up
+
+        def logged(kvs, **kw):
+            for k, v in kvs.slices():
+                shipped[int(k)].append(float(v[0]))
+            return push_up(kvs, **kw)
+
+        ls._push_up = logged
+        for r in range(20):
+            for t in range(3):
+                w.push(t, np.full(64, float(r + 1), np.float32))
+        w.wait_all()
+        for t in range(3):
+            np.testing.assert_array_equal(
+                w.pull_sync(t), np.full(64, -210.0, np.float32))
+        for k in keys:
+            assert shipped[k] == [float(r + 1) for r in range(20)], k
+    finally:
+        sim.shutdown()
