@@ -1,9 +1,13 @@
-"""Operations and bytes the algorithm needs, from shapes alone.
+"""The flagship's counts: parameters, and the operations and bytes the
+algorithm needs, from shapes alone.
 
 Kept with the benchmark so that no PR that claims a gain can change the
 yardstick.  ``bench.py``'s ``_transformer_train_flops_per_step`` has the
 same idea but counts the masked half of causal attention and the
-embedding gather; this copy counts neither (see ``PERF.md``).
+embedding gather; this copy counts neither (see ``PERF.md``).  Every
+function takes ``cfg``, the configuration's keys as ``obs["model"]``
+holds them; a ``trace_kernel`` reader file names a kernel function here
+by ``fn`` and calls it as ``fn(cfg, batch) -> (FLOPs, bytes)``.
 """
 
 from __future__ import annotations
@@ -67,14 +71,3 @@ def flash_bwd_dq(cfg: dict, batch: int):
     """S = QK^T again, dP = dO V^T, dQ = dS K; reads q, k, v, dO, l, m,
     di, writes dq."""
     return _flash(cfg, batch, matmuls=3, tiles=5, stats=3)
-
-
-KERNEL_FNS = {"flash_fwd": flash_fwd, "flash_bwd_dkv": flash_bwd_dkv,
-              "flash_bwd_dq": flash_bwd_dq}
-
-
-def least_seconds(flops: float, nbytes: float, peaks: dict):
-    """The roofline's least time for a call, and which bound sets it."""
-    t_flops = flops / peaks["bf16_flops"]
-    t_bytes = nbytes / peaks["hbm_bytes_per_s"]
-    return max(t_flops, t_bytes), ("flops" if t_flops >= t_bytes else "bytes")

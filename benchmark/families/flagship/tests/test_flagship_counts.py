@@ -1,8 +1,14 @@
-"""The FLOP and byte functions against hand counts for the flagship."""
+"""The flagship's counts against hand counts, and the roofline's
+arithmetic on them."""
+
+from pathlib import Path
 
 import pytest
 
-from benchmark.lib import flops
+from benchmark.lib import family, roofline
+
+ROOT = Path(__file__).resolve().parents[4]
+flops = family.load(ROOT, ["benchmark"], "flagship").counts
 
 L4 = {"vocab": 8192, "d_model": 2048, "n_heads": 16, "n_layers": 4,
       "d_ff": 8192, "max_seq": 2048}
@@ -45,7 +51,7 @@ def test_flash_kernel_counts():
 
 def test_roofline_says_which_bound():
     peaks = {"bf16_flops": 197e12, "hbm_bytes_per_s": 819e9}
-    t, bound = flops.least_seconds(*flops.flash_fwd(L4, 4), peaks)
+    t, bound = roofline.least_seconds(*flops.flash_fwd(L4, 4), peaks)
     assert bound == "flops" and t == pytest.approx(68_753_031_168 / 197e12)
-    t, bound = flops.least_seconds(1e6, 819e9, peaks)
+    t, bound = roofline.least_seconds(1e6, 819e9, peaks)
     assert bound == "bytes" and t == pytest.approx(1.0)

@@ -7,10 +7,13 @@ gives sizes, topology and layout; the traffic mix gives the cluster's
 ``Config`` fields (sync mode, codec parameters), the ``Trainer``'s
 arguments (optimizer, compression, HFA), an optional ``FaultPolicy``
 (modeled WAN), the data stream, the warm-up and the ``correct`` rule;
-each per-layer metric is a file read by a reader of its ``kind``.  From
-the program the harness takes only the system under test, the
-``measure=`` hook of ``Trainer.fit``, ``JaxBackend.stats()`` and
-``Simulation.wan_bytes()``.
+each per-layer metric is a file read by a reader of its ``kind``; the
+model is the configuration's ``family``, a directory of files
+(``lib/family.py``): the system's entry, the plain reference, the counts
+and the keys it needs.  From the program the harness takes only the
+system under test, the ``measure=`` hook of ``Trainer.fit``,
+``JaxBackend.stats()``, ``Simulation.wan_bytes()`` and, in a traced run,
+the spans of its tracer (``Config.trace_sample_every``).
 
 ``--trace 1`` runs the profiler over a window of the mix's
 ``trace_steps`` and prints the per-layer metrics.  ``--trace 0`` prints
@@ -18,9 +21,10 @@ the end-to-end ones over the whole window; where one of them is read
 from the device trace (``chip_ms_per_step``), the profiler covers the
 window's first ``trace_steps`` and is stopped at the gate's mark.
 
-What is NOT data, and needs an edit here: a new ``layout.kind`` (how
+What is NOT files, and needs an edit here: a new ``layout.kind`` (how
 parties map onto chips), a worker loop that ``Trainer.fit`` does not
-reach (``run_worker_overlapped``), and a new ``correct.mode``.
+reach (``run_worker_overlapped``), and a new ``correct.mode``.  No cell
+needs another of either, and code that no cell runs is not measured.
 """
 
 from __future__ import annotations
@@ -37,16 +41,18 @@ from pathlib import Path
 
 import numpy as np
 
-from . import data, flops, readers, reference, trace as tr, validate
+from . import data, family, readers, spans, trace as tr, validate
 from .gate import StepGate
 
 # a hung barrier is a run that never exits: past this every thread's
 # stack is dumped and the process dies (the driver allows a compiling
 # run 1200 s)
 DEADLINE_S = 1150
+# the contract's most for a list of ``breakdown``; idle_by_span's last
+# row is "no span open"
+BREAKDOWN_ROWS = 10
 COUNTER_KEYS = ("h2d_bytes", "d2h_bytes", "codec_d2h_bytes",
                 "codec_host_bytes")
-SIZE_KEYS = ("vocab", "d_model", "n_heads", "n_layers", "d_ff", "max_seq")
 
 
 class BenchmarkError(SystemExit):
@@ -83,7 +89,7 @@ def load_cell(root: Path, name: str) -> dict:
             raise BenchmarkError(f"per-layer metric {m['name']!r} has no "
                                  "reader file")
         layer.append({**json.loads(f.read_text()), "name": m["name"]})
-    return {"cell": cell,
+    return {"cell": cell, "paths": paths,
             "config": json.loads((root / entry["file"]).read_text()),
             "traffic": json.loads(tfile.read_text()),
             "end_to_end": list(filter(reported, manifest["end_to_end"])),
@@ -177,8 +183,9 @@ def _profiler_options():
 def compare_losses(losses, ref, rule: dict, warmup: int):
     """The traffic mix's ``correct`` rule on the system's losses (every
     step of the run) against the reference's (the warm-up steps):
-    (what is wrong, the steps that are)."""
-    failures, bad = [], set()
+    (what is wrong, the steps that are, each number compared beside its
+    limit or limits)."""
+    failures, bad, compared = [], set(), {}
     tol = rule["loss_tol"]
     if rule["mode"] == "match_reference":
         agree = range(warmup)
@@ -193,6 +200,8 @@ def compare_losses(losses, ref, rule: dict, warmup: int):
         lo = ref[warmup - 1] - rule["band_margin"]
         hi = (ref[0] - rule["band_min_share_of_reference_fall"] * fall
               + rule["band_margin"])
+        compared[f"loss_step{warmup - 1}_in_band"] = [
+            float(losses[warmup - 1]), lo, hi]
         if not lo <= losses[warmup - 1] <= hi:
             bad.add(warmup - 1)
             failures.append(f"step {warmup - 1}: loss "
@@ -201,16 +210,19 @@ def compare_losses(losses, ref, rule: dict, warmup: int):
     else:
         raise BenchmarkError(f"unknown correct.mode {rule['mode']!r}")
     for k in agree:
+        compared[f"loss_gap_step{k}"] = [float(abs(losses[k] - ref[k])), tol]
         if not abs(losses[k] - ref[k]) <= tol:
             bad.add(k)
             failures.append(f"step {k}: loss {losses[k]:.5f} against the "
                             f"reference's {ref[k]:.5f}, over {tol}")
-    if rule["require_falling"] and not losses[-1] < losses[0]:
+    if rule["require_falling"]:
         # fresh batches every step: over K steps the batch-to-batch noise
         # can hide the fall, over the whole run it cannot
-        failures.append(f"loss did not fall over the run: first "
-                        f"{losses[0]:.5f}, last {losses[-1]:.5f}")
-    return failures, bad
+        compared["loss_fall_over_run"] = [float(losses[0] - losses[-1]), 0.0]
+        if not losses[-1] < losses[0]:
+            failures.append(f"loss did not fall over the run: first "
+                            f"{losses[0]:.5f}, last {losses[-1]:.5f}")
+    return failures, bad, compared
 
 
 def chip_ms_per_step(busy: dict, steps: int) -> float:
@@ -220,30 +232,46 @@ def chip_ms_per_step(busy: dict, steps: int) -> float:
 
 
 def reduce_trace(trace_dir: str, obs: dict, on_chip: bool,
-                 breakdown: bool = True) -> dict:
+                 traced: bool = True) -> dict:
     """Load the run's trace into ``obs`` for the readers; returns what the
     result line carries from it (``device`` additions, ``breakdown``,
     every chip's idle share).  Off the chip there is no device plane and
-    nothing is reduced."""
+    nothing of the device is reduced.  ``traced`` is the ``--trace 1``
+    run: only there are the breakdowns worked out and the program's
+    ``geomx:`` spans loaded (its tracer is on in that run alone), the
+    window's edges with them, off the chip too."""
     trace = tr.load(trace_dir)
     planes = tr.chips(trace)
-    if not planes:
-        if on_chip:
-            raise BenchmarkError("the trace has no /device:TPU:<n> plane: "
-                                 "no operation ran on a chip")
-        return {}
-    t0, t1 = tr.window(trace)
-    busy = {p: tr.busy_seconds(tr.device_ops(trace, p), t0, t1)
-            for p in planes}
-    obs.update(trace=trace, t0=t0, t1=t1, busy=busy)
-    seen = {"device": {"busy_s": sum(busy.values()) / len(planes),
-                       "window_s": t1 - t0},
-            "idle_pct_per_chip": {p: 100.0 * (1 - b / (t1 - t0))
-                                  for p, b in busy.items()}}
-    if breakdown:
-        # over every chip: no entry depends on which chip was busiest
-        seen["breakdown"] = {"device_ops": tr.top_device_ops(trace, t0, t1),
-                             "idle_gaps": tr.idle_gaps(trace, t0, t1)}
+    if not planes and on_chip:
+        raise BenchmarkError("the trace has no /device:TPU:<n> plane: "
+                             "no operation ran on a chip")
+    t0, t1 = obs["t0"], obs["t1"] = tr.window(trace)
+    seen: dict = {}
+    if planes:
+        busy = {p: tr.busy_seconds(tr.device_ops(trace, p), t0, t1)
+                for p in planes}
+        obs.update(trace=trace, busy=busy)
+        seen = {"device": {"busy_s": sum(busy.values()) / len(planes),
+                           "window_s": t1 - t0},
+                "idle_pct_per_chip": {p: 100.0 * (1 - b / (t1 - t0))
+                                      for p, b in busy.items()}}
+        if traced:
+            # over every chip: no entry depends on which chip was busiest
+            seen["breakdown"] = {
+                "device_ops": tr.top_device_ops(trace, t0, t1),
+                "idle_gaps": tr.idle_gaps(trace, t0, t1)}
+    found = spans.load(trace_dir) if traced else []
+    if found:
+        obs["spans"] = found
+        every_op = [e for p in planes for e in tr.device_ops(trace, p)]
+        seen.setdefault("breakdown", {}).update(
+            host_spans=spans.host_spans(found, t0, t1, obs["steps"],
+                                        n=BREAKDOWN_ROWS),
+            idle_by_span=spans.idle_by_span(
+                found, tr.busy_intervals(every_op, t0, t1), t0, t1,
+                n=BREAKDOWN_ROWS - 1))
+        seen["spans_in_window_per_step"] = sum(
+            t0 <= s.start <= t1 for s in found) / obs["steps"]
     return seen
 
 
@@ -259,7 +287,6 @@ def run_cell(root: Path, name: str, seed: int, seconds: float, trace: bool,
     layout = config["layout"]
 
     import jax
-    import jax.numpy as jnp
 
     devices = jax.devices()
     dev = devices[0]
@@ -296,29 +323,31 @@ def run_cell(root: Path, name: str, seed: int, seconds: float, trace: bool,
 
     from geomx_tpu.core.config import Config, Topology
     from geomx_tpu.kvstore import Simulation
-    from geomx_tpu.models.transformer import (TransformerConfig, init_params,
-                                              make_lm_grad_fn)
     from geomx_tpu.parallel.dp import make_party_step, party_meshes
     from geomx_tpu.training import Trainer
     from geomx_tpu.transport.van import FaultPolicy
 
-    model = {k: config[k] for k in SIZE_KEYS}
-    attn_impl, compute_dtype = config["attn_impl"], config["compute_dtype"]
+    # the model is the configuration's family: its files, found by name
+    fam = family.load(root, spec["paths"], config["family"])
+    model = {k: config[k] for k in (*family.MODEL_KEYS, *fam.needs["keys"])}
+    compute_dtype = config["compute_dtype"]
     cluster = dict(traffic.get("config", {}))
+    if trace:
+        # the program's tracer, whose spans the program_span readers and
+        # the host breakdowns read: on in the traced run alone
+        cluster["trace_sample_every"] = 1
     if rehearse:
         tiny = json.loads(
             (Path(__file__).parent / "rehearsal.json").read_text())
-        model.update(tiny["model"])
-        attn_impl, compute_dtype = tiny["attn_impl"], tiny["compute_dtype"]
+        model.update(fam.needs["rehearsal"])
+        compute_dtype = tiny["compute_dtype"]
         cluster.update(tiny["config"])
         traffic["trainer"]["optimizer"]["lr"] = tiny["lr"]
-    mcfg = TransformerConfig(**model, attn_impl=attn_impl,
-                             compute_dtype=jnp.dtype(compute_dtype))
+    init, grad_fn = fam.system.build(model, compute_dtype)
     parties = config["topology"]["parties"]
     per_party = config["topology"]["workers_per_party"]
     # worker i is worker i % per_party of party i // per_party
     workers = [(p, w) for p in range(parties) for w in range(per_party)]
-    grad_fn = make_lm_grad_fn(mcfg)
     if layout["kind"] == "party_dp_mesh":
         per = layout["chips_per_party"]
         meshes = party_meshes(parties, devices=devices[:parties * per])
@@ -335,8 +364,12 @@ def run_cell(root: Path, name: str, seed: int, seconds: float, trace: bool,
 
     # weights on the device in one jitted call from the seed, handed to
     # the workers as host arrays: that is what Trainer / kv.init take
-    params = jax.tree_util.tree_map(np.asarray, jax.jit(
-        lambda key: init_params(mcfg, key))(jax.random.PRNGKey(seed)))
+    params = jax.tree_util.tree_map(
+        np.asarray, jax.jit(init)(jax.random.PRNGKey(seed)))
+    # jax keeps a jitted function's program loaded for as long as the
+    # function lives: the initialiser's would stay on the chip for the
+    # whole run (6.6 MB of peak_hbm_GB at the flagship's sizes)
+    del init
     pool = data.batch_pool(traffic["data"], seed, len(workers), batch, seq,
                            model["vocab"])
     warmup = int(traffic["warmup_steps"])
@@ -371,6 +404,10 @@ def run_cell(root: Path, name: str, seed: int, seconds: float, trace: bool,
 
     def on_open():
         edges["open"] = _snapshot(sim, compiles)
+        # every worker between steps: weights, the gradients held, the
+        # servers' state, no activations
+        edges["resident"] = [(d.memory_stats() or {}).get("bytes_in_use", 0)
+                             for d in devices]
         if profile:
             with jax.profiler.TraceAnnotation(
                     tr.SPAN_PREFIX + tr.WINDOW_OPEN):
@@ -459,14 +496,17 @@ def run_cell(root: Path, name: str, seed: int, seconds: float, trace: bool,
            "counters": {k: closed["counters"][k] - opened["counters"][k]
                         for k in closed["counters"]},
            "compiles": closed["compiles"] - opened["compiles"],
-           "model": model, "chips": len(devices),
+           "model": model, "counts": fam.counts, "chips": len(devices),
            "batch_per_chip": batch_per_chip, "peaks": peaks,
-           "trace": None, "t0": None, "t1": None, "busy": None}
+           "trace": None, "t0": None, "t1": None, "busy": None,
+           "spans": None}
     say(f"window: {steps} steps in {window_s:.3f}s, "
         f"{values['tokens_per_s']:.1f} tokens/s, WAN "
         f"{values['wan_MB_per_step']:.3f} MB/step, peak "
-        f"{values['peak_hbm_GB']:.3f} GB, compiles in window "
-        f"{obs['compiles']}")
+        f"{values['peak_hbm_GB']:.3f} GB (resident at the window's "
+        f"opening, per chip: "
+        + " ".join(f"{b / 1e9:.3f}" for b in edges["resident"])
+        + f"), compiles in window {obs['compiles']}")
 
     # ---- correct: the system's checks, then the plain reference --------
     losses = np.mean([out["losses"][i] for i in range(len(workers))], axis=0)
@@ -482,6 +522,7 @@ def run_cell(root: Path, name: str, seed: int, seconds: float, trace: bool,
         if st["codec_host_bytes"] != 0:
             failures.append(f"{node}: codec_host_bytes "
                             f"{st['codec_host_bytes']}, not 0")
+    compared = {"compiles_in_window": [obs["compiles"], 0]}
     if obs["compiles"]:
         failures.append(f"{obs['compiles']} compilation(s) inside the window")
     rule = traffic["correct"]
@@ -494,11 +535,12 @@ def run_cell(root: Path, name: str, seed: int, seconds: float, trace: bool,
             failures.append("the workers' parameters differ after the "
                             "window")
     # every party's model up and down the WAN once a step, uncompressed
-    dense_mb = 2 * parties * 4 * flops.n_params(model) / 1e6
+    dense_mb = 2 * parties * 4 * fam.counts.n_params(model) / 1e6
     if "wan_dense_share" in rule:
         # a codec's bytes lie between a floor (sending nothing is not a
         # faster codec) and a ceiling (it must compress)
         lo, hi = (share * dense_mb for share in rule["wan_dense_share"])
+        compared["wan_MB_per_step"] = [values["wan_MB_per_step"], lo, hi]
         if not lo < values["wan_MB_per_step"] < hi:
             failures.append(f"WAN {values['wan_MB_per_step']:.2f} MB/step "
                             f"is not between {lo:.2f} and {hi:.2f} (shares "
@@ -520,18 +562,17 @@ def run_cell(root: Path, name: str, seed: int, seconds: float, trace: bool,
         f"{(d.memory_stats() or {}).get('bytes_in_use', 0) / 1e9:.2f}"
         for d in devices))
     t_ref = time.perf_counter()
-    ref = reference.train(
+    ref = fam.reference.train(
         params, [pool[k].reshape(-1, seq) for k in range(warmup)],
         lr=traffic["trainer"]["optimizer"]["lr"], device=devices[0])
     say("loss, system   : " + " ".join(f"{x:.5f}" for x in losses[:warmup])
         + f"   (last of the window {losses[-1]:.5f})")
     say("loss, reference: " + " ".join(f"{x:.5f}" for x in ref)
         + f"   ({time.perf_counter() - t_ref:.1f}s)")
-    more, bad = compare_losses(losses, ref, rule, warmup)
+    more, bad, numbers = compare_losses(losses, ref, rule, warmup)
     failures += more
     bad_steps |= bad
-    for f in failures:
-        say("NOT CORRECT: " + f)
+    compared.update(numbers)
 
     # ---- per-layer metrics (the traced run) ----------------------------
     device = {"platform": dev.platform, "kind": dev.device_kind,
@@ -540,7 +581,7 @@ def run_cell(root: Path, name: str, seed: int, seconds: float, trace: bool,
               "failed": len(bad_steps)}
     if profile:
         seen = reduce_trace(trace_dir.name, obs, on_chip=not rehearse,
-                            breakdown=trace)
+                            traced=trace)
         device.update(seen.pop("device", {}))
         result.update(seen)
         if obs["busy"]:
@@ -569,9 +610,17 @@ def run_cell(root: Path, name: str, seed: int, seconds: float, trace: bool,
         cell=name, seed=seed, steps=steps, window_s=window_s,
         tokens_per_step=tokens_per_step, traced_steps=profiling["steps"],
         mark_pause_s=gate.paused_s,
+        resident_GB_at_open=[b / 1e9 for b in edges["resident"]],
         losses=[float(x) for x in losses[:warmup]] + [float(losses[-1])],
         reference_losses=[float(x) for x in ref],
         step_s=step_s,
         compiles_total=compiles.compiles, cache_hits=compiles.cache_hits,
-        failures=failures)
+        failures=failures, compared=compared)
+    # the last lines on standard error: what is wrong, and each number
+    # compared beside its limit or limits (the result line ends in them)
+    for f in failures:
+        say("NOT CORRECT: " + f)
+    say("compared: " + "; ".join(
+        f"{k} {v[0]:.6g} against " + " to ".join(f"{x:.6g}" for x in v[1:])
+        for k, v in compared.items()))
     return result

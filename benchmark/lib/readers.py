@@ -14,10 +14,14 @@ PR 25), and then one metric is read from different chips.
 ``tokens_per_step``, ``phases`` {name: [seconds, ...]} over workers and
 window steps, ``counters`` {key: delta over the window, summed over
 servers}, ``compiles`` (in the window), ``model`` (the configuration's
-sizes), ``chips``, ``batch_per_chip``, ``peaks`` (this device kind's
-row), ``trace`` (a trace dict or None), ``t0``/``t1`` (the window on the
-profiler's clock), ``busy`` {chip: seconds an operation ran in the
-window}.
+own keys, as its family reads them) and ``counts`` (the family's
+``counts.py``: ``n_params``, ``train_flops_per_token`` and the kernel
+functions a ``trace_kernel`` file names by ``fn``, each taking
+``model``), ``chips``, ``batch_per_chip``, ``peaks`` (this device
+kind's row), ``trace`` (a trace dict or None), ``t0``/``t1`` (the window
+on the profiler's clock), ``busy`` {chip: seconds an operation ran in
+the window}, ``spans`` (the program's ``geomx:`` spans of a traced run,
+``lib/spans.py``, or None).
 """
 
 from __future__ import annotations
@@ -26,7 +30,7 @@ import re
 
 import numpy as np
 
-from . import flops, trace as tr
+from . import roofline, spans, trace as tr
 
 COMBINE = {"sum": sum, "max": max}
 
@@ -82,9 +86,9 @@ def trace_kernel(spec, obs):
         if not evs:
             raise tr.PatternMatchedNothing(
                 f"no XLA op on any chip matches {k['pattern']!r}")
-        fl, by = flops.KERNEL_FNS[k["fn"]](obs["model"],
-                                           obs["batch_per_chip"])
-        least += len(evs) * flops.least_seconds(fl, by, obs["peaks"])[0]
+        fl, by = getattr(obs["counts"], k["fn"])(obs["model"],
+                                                 obs["batch_per_chip"])
+        least += len(evs) * roofline.least_seconds(fl, by, obs["peaks"])[0]
         took += sum(e.dur for e in evs)
     return 100.0 * least / took
 
@@ -100,8 +104,21 @@ def _mfu_pct(spec, obs):
     if obs["peaks"] is None:
         return None
     tokens_per_s = obs["steps"] * obs["tokens_per_step"] / obs["window_s"]
-    return (100.0 * tokens_per_s * flops.train_flops_per_token(obs["model"])
+    return (100.0 * tokens_per_s
+            * obs["counts"].train_flops_per_token(obs["model"])
             / (obs["chips"] * obs["peaks"]["bf16_flops"]))
+
+
+def _step_mfu_pct(spec, obs):
+    """The FLOPs the window's steps require over what the chips could
+    have done in the seconds an operation ran on them: the whole step's
+    share of the peak in device time, which bounds every kernel's."""
+    if obs["trace"] is None or obs["peaks"] is None:
+        return None
+    required = (obs["steps"] * obs["tokens_per_step"]
+                * obs["counts"].train_flops_per_token(obs["model"]))
+    return 100.0 * required / (sum(obs["busy"].values())
+                               * obs["peaks"]["bf16_flops"])
 
 
 def _compiles_in_window(spec, obs):
@@ -109,6 +126,7 @@ def _compiles_in_window(spec, obs):
 
 
 DERIVED = {"device_idle_pct": _device_idle_pct, "mfu_pct": _mfu_pct,
+           "step_mfu_pct": _step_mfu_pct,
            "compiles_in_window": _compiles_in_window}
 
 
@@ -118,7 +136,7 @@ def derived(spec, obs):
 
 KINDS = {"measure_phase": measure_phase, "stats_counter": stats_counter,
          "trace_module": trace_module, "trace_kernel": trace_kernel,
-         "derived": derived}
+         "derived": derived, "program_span": spans.program_span}
 
 
 def read(spec: dict, obs: dict):

@@ -13,7 +13,7 @@ import json
 import re
 from pathlib import Path
 
-from . import readers
+from . import family, readers
 
 NAME_RE = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 UNIT_RE = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
@@ -35,16 +35,29 @@ MAX_BOUND = 0.1
 # an expansion factor, or the number of experts per token
 WIDTH_RE = re.compile(
     r"(_dim|_rank)$|hidden|intermediate|latent|state_size|proj|expand|"
-    r"expansion|head_size|experts_per_tok|^d_model$|^d_ff$|^head_dim$|"
-    r"^moe_top_k$|^top_k$", re.IGNORECASE)
+    r"expansion|head_size|experts_per_tok|^head_dim$|^moe_top_k$|^top_k$",
+    re.IGNORECASE)
+# ... but a number of layers is a count whatever the layers are called:
+# num_hidden_layers is the key under which every catalog config gives
+# its depth, the one cut every configuration makes
+COUNT_RE = re.compile(r"^(num|n)_(\w+_)?(layers|blocks)$", re.IGNORECASE)
+
+
+def names_width(key: str, family_widths=()) -> bool:
+    """Whether ``reduced`` may not list ``key``: it names a width by the
+    contract's pattern and is not a count of layers, or it is one of the
+    keys its family calls a width (``needs.json``, ``widths``: names no
+    pattern could know)."""
+    return key in family_widths or (bool(WIDTH_RE.search(key))
+                                    and not COUNT_RE.match(key))
 
 
 # what the harness reads from a configuration's and a mix's file: a file
-# that lacks one fails here, not minutes into a run on the chip
-CONFIG_NEEDS = ("vocab", "d_model", "n_heads", "n_layers", "d_ff", "max_seq",
-                "attn_impl", "compute_dtype", "batch_per_chip_per_party",
-                "topology.parties", "topology.workers_per_party",
-                "layout.kind")
+# that lacks one fails here, not minutes into a run on the chip.  The
+# model's own keys are its family's (needs.json), beside these.
+CONFIG_NEEDS = ("family", *family.MODEL_KEYS, "compute_dtype",
+                "batch_per_chip_per_party", "topology.parties",
+                "topology.workers_per_party", "layout.kind")
 TRAFFIC_NEEDS = ("trainer.optimizer.lr", "data.order", "data.pool_steps",
                  "warmup_steps", "trace_steps", "correct.mode",
                  "correct.loss_tol", "correct.require_falling",
@@ -155,6 +168,22 @@ def _traffic(f: Path) -> list:
     return [f"lacks {k!r}" for k in lacks]
 
 
+def _family(errs, root, paths, where, f, body):
+    """Hold a configuration's family to its shape; returns its needs
+    (``needs.json``) where the family is sound, else None."""
+    fam = body.get("family") if isinstance(body, dict) else None
+    if fam is None or not _name(errs, where + " family", fam):
+        return None             # a file without the key is reported there
+    wrong = family.check(root, paths, fam)
+    errs += [f"{where}: {e}" for e in wrong]
+    if wrong:
+        return None
+    needs = family.needs(family.directory(root, paths, fam))
+    errs += [f"{where}: {f} lacks {k!r}, which its family {fam!r} needs"
+             for k in _missing(body, needs["keys"])]
+    return needs
+
+
 def metric_cells(metric: dict, cells) -> list:
     """The cells a metric is reported in: its ``workloads`` or all."""
     return list(metric.get("workloads", cells))
@@ -215,6 +244,7 @@ def check(root, manifest_name: str = "BENCHMARK.json") -> list:
     # ---- configs -------------------------------------------------------
     configs = _entries(errs, doc, "configs", 1, 24, CONFIG_KEYS)
     files = []
+    families: dict = {}          # configuration -> the family it names
     for c in configs:
         where = f"config {c['name']!r}"
         _name(errs, where + " name", c["name"])
@@ -240,10 +270,13 @@ def check(root, manifest_name: str = "BENCHMARK.json") -> list:
         if not isinstance(red, list) or len(red) > 16:
             errs.append(f"{where}: reduced must be a list of at most 16")
             red = []
+        needs = _family(errs, root, paths, where, f, body)
+        if needs is not None:
+            families[c["name"]] = body["family"]
         for k in red:
             if not _name(errs, where + " reduced", k):
                 continue
-            if WIDTH_RE.search(k):
+            if names_width(k, (needs or {}).get("widths", ())):
                 errs.append(f"{where}: reduced names the width {k!r}; no "
                             "width may change")
             if isinstance(body, dict) and k not in body:
@@ -378,6 +411,20 @@ def check(root, manifest_name: str = "BENCHMARK.json") -> list:
                 body.get("chips") not in readers.COMBINE:
             errs.append(f"{where}: {f.name} must say how the chips "
                         f"combine: chips = one of {sorted(readers.COMBINE)}")
+        elif body["kind"] == "trace_kernel":
+            # the operations and bytes are the family's of each cell
+            # that reports the metric
+            fams = {families[w["config"]] for w in cells
+                    if w["name"] in metric_cells(m, cell_names)
+                    and w["config"] in families}
+            for fam in sorted(fams):
+                have = family.kernel_fns(root, paths, fam)
+                for k in body.get("kernels", []):
+                    if k.get("fn") not in have:
+                        errs.append(
+                            f"{where}: {f.name} names the kernel function "
+                            f"{k.get('fn')!r}, which counts.py of the "
+                            f"family {fam!r} does not define")
     for w in cell_names:
         mine = [m["name"] for m in e2e if w in e2e_cells[m["name"]]]
         if "setup_s" not in mine:

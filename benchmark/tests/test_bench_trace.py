@@ -1,10 +1,15 @@
 """The trace reduction on a hand-built trace: busy union, idle share,
 module and kernel sums, the window, gap attribution."""
 
+from pathlib import Path
+
 import pytest
 
-from benchmark.lib import readers, trace as tr
+from benchmark.lib import family, readers, trace as tr
 from benchmark.lib.trace import Event
+
+FLAGSHIP = family.load(Path(__file__).resolve().parents[2], ["benchmark"],
+                       "flagship")
 
 CHIP0, CHIP1 = "/device:TPU:0", "/device:TPU:1"
 
@@ -118,6 +123,7 @@ def _obs(trace):
     return {"trace": trace, "t0": t0, "t1": t1, "busy": busy, "steps": 2,
             "batch_per_chip": 4, "peaks": {"bf16_flops": 197e12,
                                            "hbm_bytes_per_s": 819e9},
+            "counts": FLAGSHIP.counts,
             "model": {"vocab": 8192, "d_model": 2048, "n_heads": 16,
                       "n_layers": 4, "d_ff": 8192, "max_seq": 2048}}
 
@@ -141,6 +147,14 @@ def test_trace_readers():
     spec["kernels"][0]["pattern"] = "renamed_kernel"
     with pytest.raises(tr.PatternMatchedNothing):
         readers.read(spec, obs)
+    # the whole step against the peak while a chip was busy: 2 steps of
+    # 100 tokens at 1,409,335,296 FLOPs a token in 55 + 10 ms of device
+    # time on the two chips
+    obs["tokens_per_step"] = 100
+    assert readers.read({"kind": "derived", "fn": "step_mfu_pct"}, obs) \
+        == pytest.approx(100 * 2 * 100 * 1_409_335_296 / (0.065 * 197e12))
+    assert readers.read({"kind": "derived", "fn": "step_mfu_pct"},
+                        dict(obs, trace=None)) is None
 
 
 def _four_chip_trace(server_chip_busy: float):
@@ -257,11 +271,14 @@ def test_load_reads_a_recorded_profile(tmp_path):
 
 
 def test_reduce_trace_fills_the_line_and_refuses_an_idle_chip(monkeypatch):
-    from benchmark.lib import harness
+    from benchmark.lib import harness, spans
 
     monkeypatch.setattr(tr, "load", lambda _dir: _trace())
+    # a program without the tracer's annotations: no geomx: event
+    monkeypatch.setattr(spans, "load", lambda _dir: [])
     obs = {}
     seen = harness.reduce_trace("unused", obs, on_chip=True)
+    assert "host_spans" not in seen["breakdown"] and "spans" not in obs
     assert seen["device"]["window_s"] == pytest.approx(0.095)
     # averaged over the chips used: chip 0 busy 55 ms, chip 1 10 ms
     assert seen["device"]["busy_s"] == pytest.approx((0.055 + 0.010) / 2)
@@ -275,8 +292,11 @@ def test_reduce_trace_fills_the_line_and_refuses_an_idle_chip(monkeypatch):
     # breakdown is worked out there
     assert harness.chip_ms_per_step(obs["busy"], 5) == pytest.approx(
         1e3 * (0.055 + 0.010) / 2 / 5)
+    # ... and the program's spans are not even looked for
+    monkeypatch.setattr(spans, "load", lambda _dir: 1 / 0)
     assert "breakdown" not in harness.reduce_trace(
-        "unused", {}, on_chip=True, breakdown=False)
+        "unused", {}, on_chip=True, traced=False)
+    monkeypatch.setattr(spans, "load", lambda _dir: [])
     # a trace with no chip plane: nothing to reduce off the chip, an
     # error on it
     host_only = {k: v for k, v in _trace().items() if k.startswith("/host")}
@@ -284,6 +304,36 @@ def test_reduce_trace_fills_the_line_and_refuses_an_idle_chip(monkeypatch):
     assert harness.reduce_trace("unused", {}, on_chip=False) == {}
     with pytest.raises(SystemExit):
         harness.reduce_trace("unused", {}, on_chip=True)
+
+
+def test_reduce_trace_hands_the_readers_the_programs_spans(monkeypatch):
+    """The traced run: the ``geomx:`` spans go into ``obs`` and into two
+    more lists of the breakdown, of the contract's ten rows at most; off
+    the chip too, with the window from the host's marks."""
+    from benchmark.lib import harness, spans
+
+    ms = 1e-3
+    found = [spans.Span("server:0@p0", f"s{i}", "t1", (10 + 5 * i) * ms,
+                        4 * ms, {}) for i in range(14)]
+    found.append(spans.Span("server:0@p0", "before", "t1", 0.0, 2 * ms, {}))
+    monkeypatch.setattr(spans, "load", lambda _dir: found)
+    for trace, on_chip in ((_trace(), True), (
+            {k: v for k, v in _trace().items() if k.startswith("/host")},
+            False)):
+        monkeypatch.setattr(tr, "load", lambda _dir, t=trace: t)
+        obs = {"steps": 2}
+        seen = harness.reduce_trace("unused", obs, on_chip=on_chip)
+        assert obs["spans"] is found
+        assert (obs["t0"], obs["t1"]) == pytest.approx((0.005, 0.100))
+        assert seen["spans_in_window_per_step"] == 14 / 2
+        rows = seen["breakdown"]["host_spans"]
+        assert len(rows) == 10 and rows[0][1] == pytest.approx(0.004 / 2)
+        idle = seen["breakdown"]["idle_by_span"]
+        # off the chip every second is idle and every span is in it
+        assert len(idle) == (5 if on_chip else 10)
+        assert idle[-1][0] == spans.NO_SPAN
+        assert ("device_ops" in seen["breakdown"]) == on_chip
+        assert ("device" in seen) == on_chip
 
 
 # names read by hand from real traces on the v5e (PERF.md section 3)

@@ -114,6 +114,11 @@ BREAKS = {
                         "no width may change"),
     "a reduced key ending in _dim": (
         _set(["configs", 0, "reduced"], ["head_dim"]), "no width may"),
+    "a per-layer metric in no cell's list once every metric has one": (
+        lambda d: [m.__setitem__("workloads", m["workloads"][1:])
+                   for m in d["per_layer"]
+                   if "flagship-l4-1chip.fsa" in m["workloads"]],
+        "reports no per-layer metric"),
     "a reduced key the file lacks": (
         _set(["configs", 0, "reduced"], ["depth"]), "is not in"),
     "a command outside paths": (_set(["command"], ["python3", "bench.py"]),
@@ -162,6 +167,29 @@ FILE_BREAKS = {
     "a configuration without its batch": (
         "configs/flagship-l4-1chip.json",
         lambda d: d.pop("batch_per_chip_per_party"), "lacks"),
+    "a configuration that names no family": (
+        "configs/flagship-l4-1chip.json",
+        lambda d: d.pop("family"), "lacks 'family'"),
+    "a family that names nothing": (
+        "configs/flagship-l4-1chip.json",
+        lambda d: d.__setitem__("family", "nothing"),
+        "no directory <path>/families/nothing"),
+    "a family that is not a name": (
+        "configs/flagship-l4-dp2x2.json",
+        lambda d: d.__setitem__("family", "../lib"), "is not a name"),
+    "a configuration that lacks a key its family needs": (
+        "configs/flagship-l4-dp2x2.json",
+        lambda d: d.pop("d_ff"), "lacks 'd_ff', which its family"),
+    "a configuration without its attention": (
+        "configs/flagship-l4-1chip.json",
+        lambda d: d.pop("attn_impl"), "lacks 'attn_impl', which its family"),
+    "a family's needs without the rehearsal sizes": (
+        "families/flagship/needs.json",
+        lambda d: d.pop("rehearsal"), "needs.json: must hold"),
+    "a kernel function the family's counts do not define": (
+        "layer_metrics/attn_roofline_pct.json",
+        lambda d: d["kernels"][0].__setitem__("fn", "paged_fwd"),
+        "counts.py of the family 'flagship' does not define"),
     "a layout the harness does not know": (
         "configs/flagship-l4-dp2x2.json",
         lambda d: d["layout"].__setitem__("kind", "ring"), "layout kind"),
@@ -191,6 +219,81 @@ def test_a_broken_data_file_is_reported(checkout, what):
     f.write_text(json.dumps(body))
     errors = validate.check(checkout)
     assert any(expect in e for e in errors), (what, errors)
+
+
+FAMILY_BREAKS = {
+    "a family directory that lacks a part": (
+        lambda d: (d / "counts.py").unlink(), "lacks the part counts.py"),
+    "a family without its needs": (
+        lambda d: (d / "needs.json").unlink(), "lacks the part needs.json"),
+    "a reference that imports the program": (
+        lambda d: (d / "reference.py").write_text(
+            (d / "reference.py").read_text()
+            + "\n\ndef _borrowed():\n"
+              "    from geomx_tpu.models.transformer import make_apply\n"
+              "    return make_apply\n"),
+        "reference.py imports geomx_tpu"),
+    "a reference that imports the program by its name in a string": (
+        lambda d: (d / "reference.py").write_text(
+            (d / "reference.py").read_text()
+            + "\nimport importlib\n"
+              "_m = importlib.import_module('geomx_tpu.models')\n"),
+        "reference.py imports geomx_tpu"),
+    "counts that import the program": (
+        lambda d: (d / "counts.py").write_text(
+            "import geomx_tpu\n" + (d / "counts.py").read_text()),
+        "counts.py imports geomx_tpu"),
+    "a reference without train": (
+        lambda d: (d / "reference.py").write_text(
+            (d / "reference.py").read_text().replace("def train(",
+                                                     "def fit(")),
+        "reference.py does not define ['train']"),
+    "a system entry without build": (
+        lambda d: (d / "system.py").write_text("SIZE_KEYS = ()\n"),
+        "system.py does not define ['build']"),
+    "a part that does not parse": (
+        lambda d: (d / "counts.py").write_text("def n_params(:\n"),
+        "counts.py:"),
+}
+
+
+@pytest.mark.parametrize("what", sorted(FAMILY_BREAKS))
+def test_a_broken_family_is_reported(checkout, what):
+    fn, expect = FAMILY_BREAKS[what]
+    fn(checkout / "benchmark" / "families" / "flagship")
+    errors = validate.check(checkout)
+    assert any(expect in e for e in errors), (what, errors)
+    # once for each configuration that names the family
+    assert len([e for e in errors if expect in e]) == 2
+
+
+# what ``reduced`` may list (a count: depth, experts, vocabulary) and
+# what it may never (a width); num_hidden_layers is the key under which
+# every catalog config gives its depth
+COUNTS = ("num_hidden_layers", "n_layers", "n_routed_experts", "num_experts",
+          "vocab_size", "vocab", "num_layers", "num_nextn_predict_layers")
+WIDTHS = ("hidden_size", "moe_intermediate_size", "intermediate_size",
+          "kv_lora_rank", "q_lora_rank", "qk_rope_head_dim",
+          "num_experts_per_tok", "d_model", "d_ff", "head_dim",
+          "ffn_hidden_size", "v_head_dim", "ssm_state_size", "expand")
+
+
+@pytest.mark.parametrize("key", COUNTS + WIDTHS)
+def test_reduced_tells_a_count_from_a_width(checkout, key):
+    # d_model and d_ff are widths by their family's word (needs.json)
+    assert validate.names_width(key, ("d_model", "d_ff", "n_heads")) == (
+        key in WIDTHS)
+    assert not validate.names_width("d_ff") and not validate.names_width(
+        "n_heads")
+    # ... and through the whole check, with the key in the file
+    f = checkout / "benchmark" / "configs" / "flagship-l4-1chip.json"
+    body = json.loads(f.read_text())
+    body.update({key: 4, "reduced": [key], "source_values": {key: 8}})
+    f.write_text(json.dumps(body))
+    errors = _edit(checkout, _set(["configs", 0, "reduced"], [key]))
+    refused = [e for e in errors if "no width may change" in e]
+    assert bool(refused) == (key in WIDTHS), errors
+    assert [e for e in errors if e not in refused] == []
 
 
 def test_every_error_is_reported_not_the_first_alone(checkout):
@@ -248,6 +351,10 @@ def test_cell_config_traffic_and_metric_are_added_as_files(checkout):
                 "why": "fp16 on every tensor"})
         # tokens_per_s lists its cells; the new ones join them
         next(m for m in doc["end_to_end"] if m["name"] == "tokens_per_s")[
+            "workloads"] += ["flagship-l2.fp16", "flagship-l2.fsa"]
+        # ... and so does the one per-layer metric every cell reports
+        next(m for m in doc["per_layer"]
+             if m["name"] == "compiles_in_window")[
             "workloads"] += ["flagship-l2.fp16", "flagship-l2.fsa"]
         doc["per_layer"].append({
             "name": "grad_s_max", "unit": "s", "better": "lower",
