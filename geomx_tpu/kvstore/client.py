@@ -320,7 +320,8 @@ class WorkerKVStore:
     def trace_span(self, name: str, **args):
         """A span of this worker's node under the open round (no-op
         outside a sampled ``trace_round``): how the training loop marks
-        its own work at the slice edge, ``edge.d2h`` / ``edge.scale``."""
+        its own work at the slice edge, ``edge.d2h`` (and ``edge.scale``
+        where a scaling is left)."""
         return self._tracer.span(name, **args)
 
     # ---- public API ---------------------------------------------------------
@@ -572,8 +573,16 @@ class WorkerKVStore:
         the way into the in-proc fabric — no defensive copy is taken.
         The caller must not mutate ``grad`` until the push is acked
         (``wait(ts)`` / ``wait_all()``); reusing the buffer earlier
-        silently corrupts the in-flight push.  Servers copy on first
-        touch, so the alias never outlives the ack.
+        silently corrupts the in-flight push.  The numpy merge backend
+        copies on first touch, so there the alias never outlives the
+        ack.  The jax backend's first touch is an asynchronous H2D that
+        only the push which COMPLETES a round waits for before its ack:
+        a party of several workers gets its earlier acks with the copy
+        merely enqueued (jax holds a reference, so the buffer lives; a
+        write would still be a race, ROADMAP D10).  Hand over a buffer
+        that is not written again, as ``training.run_worker`` does (the
+        read-only copy off the device), or wait for the tensor's pull
+        before reusing it.
 
         ``num_merge > 1`` marks a pre-merged gradient carrying that many
         workers' contributions (TS push-direction: the elected holder

@@ -394,14 +394,28 @@ class Trainer:
 
 def _edge_to_host(kv: WorkerKVStore, tid: int, g,
                   scale: float) -> np.ndarray:
-    """One gradient leaf across the slice edge, ``np.asarray(g) * scale``
-    as two statements so that a sampled round shows the copy off the
-    device (``edge.d2h``) apart from the host multiply (``edge.scale``)."""
+    """One gradient leaf across the slice edge, in ONE pass: the copy
+    off the device (``edge.d2h``) and nothing after it.  A ``scale``
+    other than 1.0 (more than one worker a party) is applied on the
+    device before that copy (``edge.scale``: one elementwise program a
+    leaf), never as a second pass over the host copy; with ``scale ==
+    1.0`` no program is launched and no ``edge.scale`` span recorded.
+
+    The result is read-only and is the host value jax caches on the
+    array it was copied from: nothing writes to it again.  ``kv.push``
+    sends a view of it (the aliasing contract of
+    :meth:`WorkerKVStore.push`), and that view's ``base`` is what keeps
+    the buffer alive: until the ack through the in-flight message the
+    worker keeps for replay, and past it through the reference the
+    server's staged H2D holds until its transfer completes.  The caller
+    may drop ``g`` as soon as it has pushed."""
+    if scale != 1.0:
+        with kv.trace_span("edge.scale", key=tid,
+                           nbytes=getattr(g, "nbytes", None)):
+            g = g * scale
     with kv.trace_span("edge.d2h", key=tid,
                        nbytes=getattr(g, "nbytes", None)):
-        host = np.asarray(g)
-    with kv.trace_span("edge.scale", key=tid, nbytes=host.nbytes):
-        return host * scale
+        return np.asarray(g)
 
 
 def run_worker(
@@ -455,7 +469,11 @@ def run_worker(
         with kv.trace_round(step):
             with m.phase("grad"):
                 loss, acc, grads = grad_fn(params, x, y)
-                g_leaves, _ = jax.tree_util.tree_flatten(grads)
+                g_leaves = jax.tree_util.tree_leaves(grads)
+                # g_leaves alone holds the gradient from here on, so
+                # that the plain branch below can let each leaf's device
+                # buffer go as soon as its copy is off the chip
+                del grads
                 # block HERE so the phase split is honest: jax dispatch
                 # is async, and without this the whole backward pass
                 # would be billed to the push phase's first np.asarray
@@ -481,10 +499,15 @@ def run_worker(
                                      lambda t, arr: buf.__setitem__(t, arr),
                                      priority=-tid)
                 else:
-                    for tid, g in enumerate(g_leaves):
-                        kv.push(tid, _edge_to_host(kv, tid, g, scale),
-                                priority=-tid)
+                    # each tensor's pull right behind its push: the
+                    # client holds the pull back until that push is
+                    # acked (``after_ts``), so the early tensors come
+                    # back and are decoded on the response thread while
+                    # this thread still copies the later ones off the
+                    # device
                     for tid in range(len(leaves)):
+                        kv.push(tid, _edge_to_host(kv, tid, g_leaves.pop(0),
+                                                   scale), priority=-tid)
                         kv.pull(tid,
                                 lambda t, arr: buf.__setitem__(t, arr),
                                 priority=-tid)
