@@ -56,6 +56,7 @@ exported at a failover/handoff snapshot restores into either engine.
 
 from __future__ import annotations
 
+import functools
 import os
 import threading
 import time
@@ -800,8 +801,9 @@ class CodecStage:
 
     Wire frames are bit-identical to the numpy reference in both
     directions: fp16/2bit device ENCODERS emit byte-identical frames
-    for identical state; the BSC device encoder picks its support via
-    exact ``jax.lax.top_k`` (k = ratio·n) instead of the reference's
+    for identical state; the BSC device encoder picks the exact top-k
+    of the accumulated mass (k = ratio·n, by a counted threshold and a
+    compaction: :func:`_topk_support`) instead of the reference's
     sampled-threshold scan — a legal selection under the same
     ``[f32 values ‖ int32 indices bit-cast to f32]`` layout — and every
     DECODER (device or numpy) reconstructs any legal frame bitwise
@@ -822,8 +824,8 @@ class CodecStage:
 
         self._dec_f16 = jax.jit(geomx_fp16_dec)
 
-        # ``_scatter`` (and DeviceBscCodec's ``enc``) keep their names:
-        # the benchmark's codec_dev_ms_per_step matches on them
+        # ``_scatter`` (and the Bi-Sparse encoder's ``enc``) keep their
+        # names: the benchmark's codec_dev_ms_per_step matches on them
         def _scatter(vals, idx, n):
             return jnp.zeros(n, jnp.float32).at[idx].set(vals)
 
@@ -1054,14 +1056,133 @@ class DeviceTwoBitCodec(DeviceCodec):
                                   self.threshold)
 
 
+# elements a block of the support compaction holds: the (k, block) row
+# gather stays under the 8 bytes an element the pair sort took, and the
+# scatter over n / block entries costs what that gather does (v5e, PR 31)
+_SUPPORT_BLOCK = 256
+
+
+def _topk_support(u, k: int):
+    """The exact top-``k`` of ``|u|`` without a sort: ``(idx, mask)``,
+    ``idx`` the ``k`` selected positions ascending (int32), ``mask``
+    their indicator over ``u``.  The set is the one a stable descending
+    sort of ``|u|`` picks: ties at the k-th magnitude go to the lowest
+    index, NaN ranks above ``inf``.
+
+    ``|u|`` orders like its bits as int32, so the k-th largest comes from
+    a radix search, 4 bits a pass, by counting (reductions over ``n``
+    only; one loop body for the 8 passes).  The mask is compacted at the
+    scale of ``k`` and ``n / B``: block counts, their prefix sums, each
+    output slot's block and its rank there from two ``k``-long arrays
+    that mark where a block ends, one row gather of ``(k, B)`` mask bits
+    and the position of that rank in the row.  No sort, and no scatter or
+    element gather over ``n``: a TPU prices those at 7-11 ns an element.
+
+    What a run pays for beside the device's time is the number of
+    operations the program EXECUTES: every one is an event that a
+    profiler has to convert and write when it stops (150 an encode cost
+    the benchmark 7 s of set-up, 100 cost nothing: PR 32).  So whatever
+    is a scalar stays a scalar (the scalar core runs it inside the
+    neighbouring operation), and two prefix sums ride on one scan."""
+    import jax
+    import jax.numpy as jnp
+
+    i32 = jnp.int32
+    n, B = u.shape[0], _SUPPORT_BLOCK
+    nb = -(-n // B)
+    mag = jnp.abs(u)
+    bits = jnp.where(mag != mag, np.int32(0x7FFFFFFF),
+                     jax.lax.bitcast_convert_type(mag, i32))
+    bits = jnp.pad(bits, (0, nb * B - n), constant_values=-1).reshape(nb, B)
+
+    def radix_pass(i, carry):
+        # t: the k-th largest's bits above this pass's, zeros below;
+        # gt: how many are >= t + (16 << s).  XLA fuses the 15 counts
+        # into one pass over the tensor (0.13 ms for 16.7M elements on the
+        # v5e; a (15, nb, B) comparison summed over its last two axes
+        # takes 0.23, ONE reduce with 15 results 0.15: PR 32)
+        t, gt = carry
+        s = 28 - 4 * i
+        counts = []
+        for d in range(1, 16):
+            cand = t | (np.int32(d) << s)
+            count = jnp.sum(bits >= cand, dtype=i32)
+            # bit 31 is the sign: a candidate that sets it counts nothing
+            counts.append(jnp.where(cand < 0, 0, count) if d > 7 else count)
+        digit = sum((c >= k).astype(i32) for c in counts)
+        for c in counts:  # the largest count under k belongs to digit + 1
+            gt = jnp.maximum(gt, jnp.where(c < k, c, 0))
+        return t | (digit << s), gt
+
+    # t: the k-th largest; gt: how many are larger
+    t, gt = jax.lax.fori_loop(0, 8, radix_pass, (jnp.zeros((), i32),) * 2)
+
+    # everything above t, and the first k - gt of the ties by index
+    tie = bits == t
+    c_tie = jnp.sum(tie, axis=1, dtype=i32)
+    pre = jax.lax.cumsum(
+        jnp.stack([c_tie, jnp.sum(bits > t, axis=1, dtype=i32)]), axis=1)
+    p_tie = pre[0]
+    b_cut = jnp.sum(p_tie < k - gt, dtype=i32)     # block of the last tie
+    left = k - gt - p_tie[b_cut] + c_tie[b_cut]    # ties taken inside it
+    cut = b_cut * B + jnp.sum(jax.lax.cumsum(tie[b_cut].astype(i32)) < left,
+                              dtype=i32)
+    flat = jnp.arange(nb * B, dtype=i32).reshape(nb, B)
+    mask = (bits > t) | (tie & (flat <= cut))
+
+    # p[b]: selected up to and with block b, which is where it ends among
+    # the output's slots.  A 1 there counts the blocks before a slot, the
+    # block's size there sums to the slot its block starts at
+    p = pre[1] + jnp.minimum(p_tie, k - gt)
+    sizes = jnp.diff(p, prepend=0)
+    ends = jnp.zeros(k, i32)
+    at = jax.lax.cumsum(
+        jnp.stack([ends.at[p[:-1]].add(1, mode="drop"),
+                   ends.at[p[:-1]].add(sizes[:-1], mode="drop")]), axis=1)
+    blk, rank = at[0], jnp.arange(k, dtype=i32) - at[1]
+    # in-row prefix counts on the MXU (0/1 in bf16, f32 sums: exact)
+    upto = jnp.dot(mask[blk].astype(jnp.bfloat16),
+                   jnp.triu(jnp.ones((B, B), jnp.bfloat16)),
+                   preferred_element_type=jnp.float32)
+    pos = jnp.sum(upto.astype(i32) <= rank[:, None], axis=1, dtype=i32)
+    return blk * B + pos, mask.reshape(-1)[:n]
+
+
+@functools.lru_cache(maxsize=None)
+def _bsc_encoder():
+    """The Bi-Sparse encoder, jitted ONCE for the process: it is pure
+    (state and momentum are arguments, ``k`` static), so every
+    :class:`DeviceBscCodec` calls this one and a tensor length is
+    traced, lowered and fetched once however many servers encode it."""
+    import jax
+    import jax.numpy as jnp
+
+    def enc(v, u, g, m, k):
+        v = m * v + g
+        u = u + v
+        idx, mask = _topk_support(u, k)
+        vals = u[idx]
+        v = jnp.where(mask, np.float32(0.0), v)
+        u = jnp.where(mask, np.float32(0.0), u)
+        # the frame leaves the device as int32 WORDS: XLA:TPU lowers
+        # a float concatenate to maximum(pad, pad), which flushes the
+        # index bits (denormals as f32) next to the halves' seam
+        wire = jnp.concatenate([
+            jax.lax.bitcast_convert_type(vals, jnp.int32), idx])
+        return wire, v, u
+
+    return jax.jit(enc, static_argnums=(4,), donate_argnums=(0, 1))
+
+
 class DeviceBscCodec(DeviceCodec):
     """DGC-style Bi-Sparse push compressor on device: momentum velocity
     + accumulated mass exactly like :class:`BscCodec`, but the support
-    is picked by exact ``jax.lax.top_k`` over |accum| (k = ratio·n,
-    floor 1) instead of the sampled-threshold scan — no host RNG, no
-    full-array host pass, deterministic payload size.  The frame is the
-    same ``[f32 values ‖ int32 indices bit-cast to f32]`` layout, so
-    either family's decoder reconstructs it bitwise."""
+    is the exact top-k of |accum| (k = ratio·n, floor 1;
+    :func:`_topk_support`, indices ascending) instead of the
+    sampled-threshold scan — no host RNG, no full-array host pass,
+    deterministic payload size.  The frame is the same ``[f32 values ‖
+    int32 indices bit-cast to f32]`` layout, so either family's decoder
+    reconstructs it bitwise."""
 
     name = "bsc"
 
@@ -1072,24 +1193,7 @@ class DeviceBscCodec(DeviceCodec):
         self.momentum = float(momentum)
         self._velocity: Dict[int, object] = {}
         self._accum: Dict[int, object] = {}
-        jax, jnp = self._jax, self._jnp
-
-        def enc(v, u, g, m, k):
-            v = m * v + g
-            u = u + v
-            mag = jnp.abs(u)
-            _, idx = jax.lax.top_k(mag, k)
-            vals = u[idx]
-            v = v.at[idx].set(np.float32(0.0))
-            u = u.at[idx].set(np.float32(0.0))
-            wire = jnp.concatenate([
-                vals.astype(jnp.float32),
-                jax.lax.bitcast_convert_type(idx.astype(jnp.int32),
-                                             jnp.float32)])
-            return wire, v, u
-
-        self._enc = jax.jit(enc, static_argnums=(4,),
-                            donate_argnums=(0, 1))
+        self._enc = _bsc_encoder()
 
     def compress(self, key, arr):
         t0 = time.perf_counter()
@@ -1098,14 +1202,16 @@ class DeviceBscCodec(DeviceCodec):
         v = self._velocity.get(key)
         u = self._accum.get(key)
         if v is None or int(v.shape[0]) != n:
-            v = self._jnp.zeros(n, self._jnp.float32)
-            u = self._jnp.zeros(n, self._jnp.float32)
+            # placed as ``g`` is, which is how ``enc`` returns the state:
+            # a key's first encode runs the program every later one runs
+            v = self._jnp.zeros_like(g)
+            u = self._jnp.zeros_like(g)
         k = max(1, int(self.ratio * n))
         wire, v, u = self._enc(v, u, g, np.float32(self.momentum), k)
         self._velocity[key] = v
         self._accum[key] = u
         self._stage._bill(t0)
-        return self._stage._wire(wire)
+        return self._stage._wire(wire).view(np.float32)
 
     def decompress(self, key, payload, orig_len):
         return self._stage.decode("bsc", key, payload, orig_len)

@@ -5,7 +5,8 @@ Contracts pinned here:
 - CROSS-DECODE PARITY: the numpy codecs are the bit-compat wire
   reference.  fp16 and 2bit device ENCODERS emit byte-identical frames
   for identical state; the BSC device encoder may pick a different
-  (equally legal) support via exact top-k, but every legal frame —
+  (equally legal) support, the exact top-k (pinned against a sorting
+  oracle, ties, NaN and five rounds of state), but every legal frame —
   device- or numpy-encoded — reconstructs BITWISE identically under
   both families' decoders, f32 and f16-sourced, with integer-valued
   gradients surviving exactly where the codec is lossless on them;
@@ -152,6 +153,182 @@ def test_bsc_cross_decode_bitwise_both_directions():
         # integer-exact, so every transmitted value is a whole number
         assert np.all(vals == np.round(vals))
         np.testing.assert_array_equal(out_np[idx], vals)
+
+
+def _support_cases():
+    """(id, accumulated mass u, k[, the support where numpy's sort
+    cannot say]) — what the device encoder's selection must survive."""
+    rng = np.random.default_rng(31)
+
+    def normal(n):
+        return rng.standard_normal(n).astype(np.float32)
+
+    cases = [(f"n{n}", normal(n), max(1, int(0.01 * n)))
+             for n in (7, 2048, 4096, 100_003, 1_048_576)]
+    few = np.zeros(5000, np.float32)
+    few[rng.choice(5000, 20, replace=False)] = normal(20)
+    spread = normal(4096)
+    spread[[5, 9]] = np.inf, -np.inf
+    return cases + [
+        ("n_under_a_block", normal(200), 5),
+        ("k1", normal(4096), 1),
+        ("k_is_n", normal(300), 300),
+        ("all_zero", np.zeros(5000, np.float32), 50),
+        ("fewer_nonzeros_than_k", few, 50),
+        ("ties_at_the_threshold",
+         rng.integers(-3, 4, 100_003).astype(np.float32), 1000),
+        ("all_negative", -np.abs(normal(4096)) - 1, 40),
+        ("inf", spread, 40),
+        # what jax.lax.top_k did before PR 31, on the CPU and on the
+        # v5e: a NaN of either sign ranks above inf, lowest index first
+        ("nan", np.array([1, np.nan, 3, np.inf, -np.nan, 2, np.nan],
+                         np.float32), 3, [1, 4, 6]),
+        ("nan_then_inf", np.array([1, np.nan, 3, np.inf, -np.inf, 2],
+                                  np.float32), 2, [1, 3]),
+    ]
+
+
+@pytest.mark.parametrize("case", _support_cases(), ids=lambda c: c[0])
+def test_bsc_support_is_the_exact_topk(case):
+    """The device encoder selects without a sort (a counted threshold
+    and a compaction); the support must still be the one a stable
+    descending sort of |u| picks (which is ``lax.top_k``'s set), emitted
+    ascending, with the exact bits of the accumulated mass, and the
+    state zeroed exactly there."""
+    import jax
+    import jax.numpy as jnp
+
+    _, u, k, *pinned = case
+    n = len(u)
+    want = (np.asarray(pinned[0]) if pinned else
+            np.sort(np.argsort(-np.abs(u), kind="stable")[:k]))
+    _, topk = jax.lax.top_k(jnp.abs(jnp.asarray(u)), k)
+    np.testing.assert_array_equal(np.sort(np.asarray(topk)), want)
+    codec = _stage().make_push_codec(
+        {"type": "bsc", "ratio": (k + 0.5) / n, "momentum": 0.0})
+    frame = np.asarray(codec.compress(3, u.copy()))
+    assert frame.dtype == np.float32 and frame.shape == (2 * k,)
+    idx = frame[k:].view(np.int32)
+    np.testing.assert_array_equal(idx, want)  # unique, ascending, in range
+    assert frame[:k].tobytes() == u[idx].tobytes()
+    rest = u.copy()
+    rest[idx] = 0.0
+    for state in (codec._velocity[3], codec._accum[3]):
+        assert np.asarray(state).tobytes() == rest.tobytes()
+
+
+def test_bsc_five_rounds_match_the_sorting_encoder_bitwise():
+    """Velocity, accumulator and frame over five rounds at momentum 0.9
+    against a plain transcription of the encoder as it was before PR 31
+    (a full ``lax.top_k``, two scatters): the same state bit for bit and
+    the same index-value pairs, in whatever order."""
+    import jax
+    import jax.numpy as jnp
+
+    n, m = 20_000, np.float32(0.9)
+    k = max(1, int(0.01 * n))
+
+    @jax.jit
+    def sorting_enc(v, u, g):
+        v = m * v + g
+        u = u + v
+        _, idx = jax.lax.top_k(jnp.abs(u), k)
+        vals = u[idx]
+        return idx, vals, v.at[idx].set(0.0), u.at[idx].set(0.0)
+
+    codec = _stage().make_push_codec(
+        {"type": "bsc", "ratio": 0.01, "momentum": 0.9})
+    v = u = jnp.zeros(n, jnp.float32)
+    for r in range(5):
+        g = _grad(n, seed=40 + r)
+        idx, vals, v, u = sorting_enc(v, u, jnp.asarray(g))
+        frame = np.asarray(codec.compress(6, g))
+        order = np.argsort(np.asarray(idx))
+        assert (frame[k:].view(np.int32).tobytes()
+                == np.asarray(idx)[order].tobytes()), f"round {r}"
+        assert frame[:k].tobytes() == np.asarray(vals)[order].tobytes()
+        assert np.asarray(codec._velocity[6]).tobytes() == \
+            np.asarray(v).tobytes(), f"round {r}"
+        assert np.asarray(codec._accum[6]).tobytes() == \
+            np.asarray(u).tobytes(), f"round {r}"
+
+
+def test_bsc_frame_leaves_the_device_as_integer_words():
+    """XLA:TPU lowers a float32 concatenate to ``maximum(pad, pad)``,
+    which flushed the indices next to the seam of the two halves to 0
+    (they are denormals as float32; seen on the v5e in PR 31).  The
+    encoder therefore emits int32 words and the float32 view is taken
+    on the host; the bytes on the wire are the same, and every decoder
+    rebuilds the same dense tensor from them."""
+    import jax.numpy as jnp
+
+    stage = _stage()
+    codec = stage.make_push_codec(
+        {"type": "bsc", "ratio": 0.01, "momentum": 0.0})
+    n, k = 4096, 40
+    g = _grad(n, seed=2)
+    wire, _, _ = codec._enc(jnp.zeros(n), jnp.zeros(n), jnp.asarray(g),
+                            np.float32(0.0), k)
+    assert wire.dtype == jnp.int32 and wire.shape == (2 * k,)
+    frame = codec.compress(4, g)
+    assert frame.dtype == np.float32
+    assert frame.tobytes() == np.asarray(wire).tobytes()
+    dense = np.zeros(n, np.float32)
+    idx = np.asarray(wire)[k:]
+    dense[idx] = g[idx]
+    for out in (_host(stage.decode("bsc", 4, frame, n)),
+                _host(codec.decompress(4, frame, n)),
+                decompress_payload("bsc", 4, frame, n),
+                BscCodec(ratio=0.01).decompress(4, frame, n)):
+        assert out.tobytes() == dense.tobytes()
+
+
+def test_bsc_encoder_is_one_program_a_length_for_the_process():
+    """``enc`` is pure, so it is jitted once for the process: two codecs
+    (two local servers) share it, and a key's first encode runs the
+    program every later one runs (the zero state is placed like the
+    state a call returns).  Eight fetches a run were two lengths x two
+    servers x two placements (PR 31's set-up; ISSUE 32)."""
+    from geomx_tpu.kvstore import jax_backend
+
+    a = _stage().make_push_codec({"type": "bsc", "ratio": 0.01})
+    b = _stage().make_push_codec({"type": "bsc", "ratio": 0.01})
+    assert a._enc is b._enc is jax_backend._bsc_encoder()
+    # lengths no other test of this file encodes
+    before = a._enc._cache_size()
+    for n_th, n in enumerate((30_011, 70_003), start=1):
+        for r in range(3):
+            a.compress(n, _grad(n, seed=r))
+            assert a._enc._cache_size() == before + n_th, (n, r)
+        b.compress(n, _grad(n, seed=9))
+        assert a._enc._cache_size() == before + n_th
+
+
+def test_bsc_encoder_lowers_to_a_compact_module_named_enc():
+    """What a process pays on the host for each length: the radix search
+    is ONE loop body, so the module at the flagship's largest tensor is
+    523 StableHLO operations (PR 31's unrolled search: 1,425; the
+    ``lax.top_k`` encoder: 43).  ISSUE 32 asked for 400; the rest are
+    scalars, which cost the host 0.05 s a length and spare the device
+    an operation each (tests/test_tpu_compile.py counts those: they are
+    what set-up pays for).  ``jit_enc`` is the name the benchmark's
+    ``codec_dev_ms_per_step`` finds the program by."""
+    import re
+
+    import jax
+    import jax.numpy as jnp
+
+    from geomx_tpu.kvstore.jax_backend import _bsc_encoder
+
+    n = 2048 * 8192
+    vec = jax.ShapeDtypeStruct((n,), jnp.float32)
+    text = _bsc_encoder().lower(
+        vec, vec, vec, jax.ShapeDtypeStruct((), jnp.float32),
+        int(0.01 * n)).as_text()
+    ops = re.findall(r"\bstablehlo\.(\w+)", text)
+    assert len(ops) <= 600
+    assert "sort" not in ops and ops.count("while") == 1
+    assert re.match(r"module @jit_enc\b", text), text[:80]
 
 
 def test_mpq_selector_is_isinstance_compatible_and_splits():

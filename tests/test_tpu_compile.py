@@ -1,0 +1,90 @@
+"""Ahead-of-time compiles for the v5e, without a chip.
+
+libtpu is installed where the tests run, so the TPU's compiler can be
+asked what it makes of a device program at its real size: what the
+compiler refuses, what a program is called in a trace, and how much
+memory it takes beside its arguments.  Nothing runs, so nothing here is
+a time.  The topology is described inside a fixture (one process may
+hold libtpu; every xdist worker imports this file), and every such test
+lives in this one file."""
+
+import re
+
+import pytest
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    try:
+        from jax.experimental import topologies
+        from jax.sharding import SingleDeviceSharding
+
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no libtpu, or it is held by another process
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+# instructions that run inside a neighbour or not at all: no device event
+_NO_EVENT = {"parameter", "constant", "bitcast", "tuple", "get-tuple-element"}
+_SCALAR_CORE = {"or", "and", "add", "subtract", "multiply", "shift-left",
+                "compare", "select", "convert", "maximum", "minimum"}
+
+
+def _executed_operations(hlo: str, trips: int) -> int:
+    """How many device operations one call of a compiled module executes
+    (each is an event in a profiler's trace): the entry computation's
+    instructions, a ``while`` body's ``trips`` times; unfused scalar
+    arithmetic runs on the scalar core and leaves none."""
+    bodies, name = {}, None
+    for line in hlo.splitlines():
+        head = re.match(r"^(ENTRY )?%?([\w.\-]+) \(.*\{\s*$", line)
+        if head:
+            name = "ENTRY" if head.group(1) else head.group(2)
+            bodies[name] = []
+        elif line.startswith("}"):
+            name = None
+        elif name and " = " in line:
+            bodies[name].append(line.strip())
+
+    def count(lines) -> int:
+        total = 0
+        for line in lines:
+            rest = line.split(" = ", 1)[1]
+            op = re.findall(r"(?:^|[\s)])([a-z][a-z\-]*[a-z])\(", rest)[0]
+            if op == "while":
+                body = re.search(r"body=%?([\w.\-]+)", rest).group(1)
+                total += 1 + trips * count(bodies[body])
+            elif op not in _NO_EVENT and not (
+                    op in _SCALAR_CORE and re.match(r"\w+\[\]", rest)):
+                total += 1
+        return total
+
+    return count(bodies["ENTRY"])
+
+
+def test_bsc_encoder_for_the_v5e_has_no_sort_and_keeps_its_name(one_chip):
+    """The Bi-Sparse encoder at the flagship's largest tensor: the
+    benchmark's ``codec_dev_ms_per_step`` finds it by the module name
+    ``jit_enc``; a sort over n (what ``lax.top_k`` became, 38 ms a
+    tensor) must not come back; its temporaries stay under the 8 bytes
+    an element that sort's (value, index) pairs took, which is what
+    keeps ``peak_hbm_GB`` where it was; and a call executes few enough
+    operations that stopping a profiler's trace of six steps stays
+    cheap (52 encodes a step: at 147 a call the benchmark's set-up grew
+    by 7 s, at 98 by nothing; PR 32)."""
+    import jax
+    import jax.numpy as jnp
+
+    from geomx_tpu.kvstore.jax_backend import _bsc_encoder
+
+    n = 2048 * 8192
+    vec = jax.ShapeDtypeStruct((n,), jnp.float32, sharding=one_chip)
+    m = jax.ShapeDtypeStruct((), jnp.float32, sharding=one_chip)
+    compiled = _bsc_encoder().lower(vec, vec, vec, m, int(0.01 * n)).compile()
+    text = compiled.as_text()
+    assert re.match(r"HloModule jit_enc\b", text), text[:80]
+    assert not re.search(r"\bsort\(", text)
+    assert compiled.memory_analysis().temp_size_in_bytes <= 8 * n
+    assert _executed_operations(text, trips=8) <= 115
