@@ -8,8 +8,8 @@ failure (nothing here downgrades a failure to a note):
    cache in use; refuse to run unless jax found a TPU;
 2. compile every pallas kernel the package ships UNINTERPRETED and check
    it against its reference;
-3. train the flagship transformer at its full width (``bench.py``'s
-   ``MFU_CFG``; only depth is cut, to what the chip's memory holds)
+3. train the flagship transformer at its full width (the widths of
+   ``benchmark/configs/flagship-l4-1chip.json``; only depth is cut, to what the chip's memory holds)
    through the normal path — ``Simulation`` → ``Trainer.fit`` /
    ``run_worker`` → ``WorkerKVStore`` → ``LocalServer`` →
    ``GlobalServer`` on the jax merge backend — 2 parties x 1 worker,
@@ -54,7 +54,7 @@ class SmokeConfig:
     test hook (``tests/test_chip_smoke.py``) shrinks every size and sets
     ``interpret_kernels``."""
 
-    # bench.py MFU_CFG widths: d2048, 16 heads (head dim 128), ff8192,
+    # the benchmark's flagship widths: d2048, 16 heads (head dim 128), ff8192,
     # seq 2048, vocab 8192, bf16 compute, batch 4
     vocab: int = 8192
     d_model: int = 2048

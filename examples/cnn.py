@@ -31,7 +31,7 @@ from geomx_tpu.core.config import Config, Topology
 from geomx_tpu.data import ShardedIterator, synthetic_classification
 from geomx_tpu.kvstore import Simulation
 from geomx_tpu.models import MODEL_REGISTRY, create_model_state
-from geomx_tpu.training import run_worker, run_worker_hfa
+from geomx_tpu.training import ESync, Trainer, run_worker
 
 
 def main():
@@ -102,6 +102,7 @@ def main():
         compression=args.compression,
         bsc_ratio=args.bsc_ratio,
         use_hfa=args.hfa or args.esync,
+        hfa_k1=args.hfa_k1,
         hfa_k2=args.hfa_k2,
         enable_p3=args.p3,
         p3_slice_elems=50_000,
@@ -190,18 +191,10 @@ def main():
                       f"({time.time() - t0:.2f}s)", flush=True)
 
         outp: dict = {}
-        if args.esync:
-            from geomx_tpu.training import run_worker_esync
-
-            hist = run_worker_esync(kv, params, grad_fn, it, args.steps,
-                                    log_fn=log, params_out=outp)
-        elif args.hfa:
-            hist = run_worker_hfa(kv, params, grad_fn, it, args.steps,
-                                  k1=args.hfa_k1, log_fn=log,
-                                  params_out=outp)
-        else:
-            hist = run_worker(kv, params, grad_fn, it, args.steps,
-                              log_fn=log, params_out=outp)
+        hist = run_worker(
+            kv, params, grad_fn, it, args.steps, log_fn=log,
+            params_out=outp, schedule=Trainer.schedule_for(
+                kv, esync=ESync() if args.esync else None))
         if prefetch is not None:
             prefetch.close()
         with lock:

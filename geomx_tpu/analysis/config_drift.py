@@ -42,8 +42,7 @@ _ENV_NAME = re.compile(r"^GEOMX_[A-Z0-9_]+$")
 _DOC_ENV = re.compile(r"`(GEOMX_[A-Z0-9_]+)`")
 _ENV_TOKEN = re.compile(r"[\"'](GEOMX_[A-Z0-9_]+)[\"']")
 #: repo files outside the package whose env knobs the doc also catalogs
-_EXTRA_GLOBS = ("bench.py", "scripts/*.py", "scripts/*.sh",
-                "examples/*.py")
+_EXTRA_GLOBS = ("scripts/*.py", "scripts/*.sh", "examples/*.py")
 #: fields that are pure code-level plumbing, not operator knobs
 _INTERNAL_FIELDS = frozenset({"topology"})
 
@@ -118,8 +117,8 @@ class ConfigDrift(Checker):
                     f"env var {env} is read here but has no row in "
                     f"docs/{DOC_NAME} (env column)"))
         # stale-row check is read against ANY mention in the repo's
-        # tooling files too (bench.py / scripts / examples carry knobs
-        # the doc legitimately catalogs)
+        # tooling files too (scripts / examples carry knobs the doc
+        # legitimately catalogs)
         mentioned = set(env_reads)
         for pat in _EXTRA_GLOBS:
             for p in project.root.glob(pat):

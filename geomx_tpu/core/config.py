@@ -672,7 +672,7 @@ class Config:
     # (message heads, fences, barriers, membership/failover
     # transitions, round open/complete, sampled pressure readings) in
     # preallocated slots — no per-event allocation, <2% round-wall
-    # overhead (bench.py flight).  Rings dump to GEOMX_OBS_DIR on
+    # overhead by design, not measured on the chip.  Rings dump to GEOMX_OBS_DIR on
     # process exit/signal, on a HealthEngine alert transition
     # (Control.FLIGHT_DUMP broadcast — every node snapshots the same
     # incident window), and on operator request (python -m

@@ -219,8 +219,8 @@ class JaxBackend(MergeBackend):
         # kernels, wire-ready compressed bytes materialized (the ONLY
         # D2H the device codec path pays), and full-tensor bytes that
         # crossed the host boundary for codec work (the quantity the
-        # device path exists to eliminate — bench.py's host_copy_bytes;
-        # exactly 0 in steady state with device codecs on)
+        # device path exists to eliminate; exactly 0 in steady state
+        # with device codecs on)
         self.codec_device_ms = 0.0
         self.codec_d2h_bytes = 0
         self.codec_host_bytes = 0

@@ -135,9 +135,9 @@ class Replicator:
         self._lag = system_gauge(f"{gserver.po.node}.replication_lag_s")
         # per-SHARD twin of the per-node gauge: shard rank k is this
         # node's rank whether it is the plan primary (global_server:k)
-        # or its promoted standby (standby_global:k) — bench's shards
-        # sweep and the chaos soaks read the shard-keyed series so a
-        # failover doesn't break the metric's continuity
+        # or its promoted standby (standby_global:k) — the chaos soaks
+        # read the shard-keyed series so a failover doesn't break the
+        # metric's continuity
         self._shard_lag = system_gauge(
             f"global_shard{gserver.po.node.rank}.replication_lag_s")
         # baseline ship shortly after startup: a primary that dies before

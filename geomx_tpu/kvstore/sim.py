@@ -722,8 +722,7 @@ class Simulation:
                 "compression": d.compression}
 
     def process_threads(self) -> int:
-        """Live OS threads in this process right now — the scaling
-        reading ``bench.py --child parties`` records: O(nodes) under
+        """Live OS threads in this process right now: O(nodes) under
         the thread-per-endpoint harness, O(1) under lightweight mode."""
         import threading
 

@@ -496,7 +496,7 @@ def _worker_demo(po, kv, args, join_advertise=None):
 
     from geomx_tpu.data import ShardedIterator, synthetic_classification
     from geomx_tpu.models import create_cnn_state
-    from geomx_tpu.training import run_worker, run_worker_hfa
+    from geomx_tpu.training import Trainer, run_worker
 
     joining = join_advertise is not None or args.join
     x, y = synthetic_classification(n=512, shape=(12, 12, 1), seed=0)
@@ -541,16 +541,6 @@ def _worker_demo(po, kv, args, join_advertise=None):
 
         kv.worker.error_handler = _claim_poison_ack
 
-    def train(kv, params, it, steps, barrier_init):
-        # HFA servers average WEIGHTS — pushing gradients at them (the
-        # pre-r5 --hfa path) silently replaced the model with a mean
-        # gradient.  The HFA client loop is the only correct driver.
-        if args.hfa:
-            return run_worker_hfa(kv, params, grad_fn, it, steps,
-                                  k1=args.hfa_k1,
-                                  barrier_init=barrier_init)
-        return run_worker(kv, params, grad_fn, it, steps,
-                          barrier_init=barrier_init)
     if joining:
         info = kv.join_party(advertise=join_advertise)
         print(f"{po.node}: joined as rank {info['rank']} "
@@ -579,7 +569,12 @@ def _worker_demo(po, kv, args, join_advertise=None):
         # race, after it the mid-training failover path
         print(f"{po.node}: configured — training begins", flush=True)
     it = ShardedIterator(x, y, args.batch, widx, num_all)
-    hist = train(kv, params, it, args.steps, barrier_init=not joining)
+    # HFA servers average WEIGHTS — pushing gradients at them silently
+    # replaces the model with a mean gradient: the schedule follows the
+    # cluster's mode
+    hist = run_worker(kv, params, grad_fn, it, args.steps,
+                      barrier_init=not joining,
+                      schedule=Trainer.schedule_for(kv))
     if _drain_if_preempted(po, kv):
         return
     if joining:
@@ -617,7 +612,7 @@ def _worker_demo_lm(po, kv, args):
     if _drain_if_preempted(po, kv):
         return
     # steady tokens/s excludes the first step (jit compile + INIT
-    # broadcast dominate it; bench.py's lm child splits the same way)
+    # broadcast dominate it)
     if len(stamps) > 1:
         steady = (args.batch * cfg.max_seq * (len(stamps) - 1)
                   / max(stamps[-1] - stamps[0], 1e-9))
@@ -633,7 +628,7 @@ def _worker_demo_lm(po, kv, args):
 
 
 def _worker_demo_esync(po, kv, args):
-    """ESync acceptance workload: the esync client loop with optional
+    """ESync acceptance workload: the worker loop under ESync with optional
     injected per-step heterogeneity, printing the per-round (assigned
     steps, reach-server seconds) pairs the matrix asserts on."""
     import jax
@@ -641,7 +636,7 @@ def _worker_demo_esync(po, kv, args):
 
     from geomx_tpu.data import ShardedIterator, synthetic_classification
     from geomx_tpu.models import create_cnn_state
-    from geomx_tpu.training import run_worker_esync
+    from geomx_tpu.training import ESync, Trainer, run_worker
 
     x, y = synthetic_classification(n=2048, shape=(12, 12, 1), seed=0)
     _, params, grad_fn = create_cnn_state(
@@ -671,9 +666,9 @@ def _worker_demo_esync(po, kv, args):
     upd, _ = opt.update(g, opt.init(params), params)
     optax.apply_updates(params, upd)  # discarded — warmup only
     rounds_info: list = []
-    hist = run_worker_esync(kv, params, grad_fn, it, args.steps,
-                            optimizer=opt, barrier_init=True,
-                            max_local_steps=16, rounds_out=rounds_info)
+    hist = run_worker(kv, params, grad_fn, it, args.steps,
+                      schedule=Trainer.schedule_for(kv, esync=ESync(
+                          max_local_steps=16, rounds_out=rounds_info)))
     if _drain_if_preempted(po, kv):
         return
     # steps= counts SYNC rounds (the --steps contract); local steps vary
@@ -917,6 +912,7 @@ def main(argv=None):
     # ESync exchanges weights like HFA — servers must run in HFA mode
     # (ref: examples/cnn.py wires --esync the same way)
     cfg.use_hfa = args.hfa or args.esync or cfg.use_hfa
+    cfg.hfa_k1 = args.hfa_k1
     cfg.enable_p3 = args.p3 or cfg.enable_p3
     cfg.enable_intra_ts = args.tsengine or cfg.enable_intra_ts
     cfg.enable_inter_ts = (args.tsengine_inter or args.tsengine_inter_push

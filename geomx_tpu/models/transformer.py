@@ -347,7 +347,7 @@ def make_staged(cfg: TransformerConfig, rng: jax.Array):
 
 def token_cross_entropy(logits, tokens):
     """Next-token cross-entropy (shift by one) — THE LM objective; every
-    consumer (lm_loss, the bench children, the dryrun) must route
+    consumer (lm_loss, the examples, the dryrun) must route
     through here so they all measure the same thing."""
     logp = jax.nn.log_softmax(logits[:, :-1])
     ll = jnp.take_along_axis(logp, tokens[:, 1:, None], axis=-1)
@@ -373,8 +373,8 @@ def lm_loss_with_aux(apply_fn, params, tokens, aux_coef: float = AUX_COEF):
 def make_lm_grad_fn(cfg: "TransformerConfig"):
     """Jitted ``grad_fn(params, x, y) -> (loss, acc, grads)`` with the
     worker-loop signature (``training.run_worker``); y is ignored (the
-    LM objective shifts x).  Shared by the launcher's LM workload and
-    the bench's lm child so they train the identical step.  Top-k MoE
+    LM objective shifts x).  The launcher's LM workload and the
+    benchmark's flagship family train this step.  Top-k MoE
     configs train with the load-balancing aux folded in (the same
     objective examples/lm.py uses)."""
     use_aux = cfg.moe_every > 0 and cfg.moe_top_k > 0

@@ -97,9 +97,8 @@ def available() -> bool:
 def _usable_cores() -> int:
     """Cores this PROCESS may run on — ``os.cpu_count()`` reports the
     host's cores even inside a cpuset/container pinned to one, which is
-    exactly how the r4 bench host ended up spawning cpu_count threads
-    on a single core (0.34 GB/s native vs 0.63 numpy, VERDICT r4
-    weak 7)."""
+    exactly how a one-core host ended up spawning cpu_count threads on
+    a single core, slower than numpy."""
     try:
         return len(os.sched_getaffinity(0)) or 1
     except (AttributeError, OSError):
@@ -180,8 +179,8 @@ def calibrate_async(threads: int = 0) -> None:
 
 def axpy_backend(threads: int = 0) -> str:
     """Which implementation ``accumulate`` would use for a large slab on
-    this host right now: "native" or "numpy" (observability for the
-    bench; runs the calibration if it hasn't happened yet)."""
+    this host right now: "native" or "numpy" (observability; runs the
+    calibration if it hasn't happened yet)."""
     return calibrate(threads)
 
 

@@ -95,9 +95,8 @@ def _kernel(offs_ref, q_ref, k_ref, v_ref, m_ref, l_ref, o_ref, *,
 
 def _pick_bq(Tq: int) -> int:
     """Q-block rows per grid step.  Default ladder prefers the largest
-    tile that divides Tq; ``GEOMX_FLASH_BLOCK_Q`` (set from the on-chip
-    autotune child, bench.py --child flash_autotune) overrides when it
-    divides Tq — tile choice is a pure performance knob, semantics are
+    tile that divides Tq; ``GEOMX_FLASH_BLOCK_Q`` (a tile found by
+    a sweep on the chip) overrides when it divides Tq — tile choice is a pure performance knob, semantics are
     offset-driven and identical for every bq."""
     import os
 

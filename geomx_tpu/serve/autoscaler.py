@@ -74,8 +74,8 @@ class _CmdEndpoint(_App):
 
 class ReplicaAutoscaler:
     """One per deployment, on the global scheduler's postoffice.
-    ``serve_scale_interval_s <= 0`` runs no sweep thread — tests (and
-    the bench soak) drive :meth:`tick` deterministically."""
+    ``serve_scale_interval_s <= 0`` runs no sweep thread — tests drive
+    :meth:`tick` deterministically."""
 
     def __init__(self, postoffice: Postoffice,
                  config: Optional[Config] = None, collector=None,

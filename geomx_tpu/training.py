@@ -6,11 +6,20 @@ with the device↔host handoff at the slice edge: grads leave jit as numpy,
 pulls come back and are re-wrapped as jax arrays.  Per-layer priorities
 mean shallow layers jump the send queue under P3 exactly like the
 reference's engine priorities.
+
+One loop (:func:`run_worker`) and one exchange across the slice edge
+(:func:`_exchange`) serve every sync mode: what differs between FSA /
+MixedSync, HFA and ESync is the :class:`Schedule` the loop is given,
+picked in one place (:meth:`Trainer.schedule_for`).
 """
 
 from __future__ import annotations
 
-from typing import Callable, Iterable, List, Optional, Tuple
+import contextlib
+import itertools
+import time
+from typing import (Callable, Iterable, List, NamedTuple, Optional, Sequence,
+                    Tuple)
 
 import jax
 import numpy as np
@@ -60,103 +69,10 @@ def unflatten_params(treedef, arrs: List[np.ndarray]):
     return jax.tree_util.tree_unflatten(treedef, [jax.numpy.asarray(a) for a in arrs])
 
 
-def run_worker_hfa(
-    kv: WorkerKVStore,
-    params,
-    grad_fn: Callable,
-    data_iter: Iterable,
-    steps: int,
-    k1: int = 2,
-    optimizer=None,
-    barrier_init: bool = True,
-    log_fn: Optional[Callable[[int, float, float], None]] = None,
-    params_out: Optional[dict] = None,
-    measure=None,
-) -> List[Tuple[float, float]]:
-    """HFA client loop (ref: examples/cnn_hfa.py): each worker runs a LOCAL
-    optimizer for k1 steps, then pushes weight/num_workers (the local server
-    averages weights; every k2-th sync the milestone delta crosses the WAN).
-    """
-    import optax
-
-    from geomx_tpu.utils.measure import Measure
-
-    m = measure if measure is not None else Measure()
-    if optimizer is None:
-        optimizer = optax.adam(1e-2)
-    leaves, treedef = flatten_params(params)
-    for tid, leaf in enumerate(leaves):
-        kv.init(tid, leaf, barrier=barrier_init)
-    params = unflatten_params(treedef, leaves)
-    opt_state = optimizer.init(params)
-    history: List[Tuple[float, float]] = []
-    buf: List[Optional[np.ndarray]] = [None] * len(leaves)
-
-    for step, (x, y) in enumerate(data_iter):
-        if step >= steps or _preempt_noticed(kv):
-            break
-        m.step_start()
-        with m.phase("grad"):
-            loss, acc, grads = grad_fn(params, x, y)
-            updates, opt_state = optimizer.update(grads, opt_state, params)
-            import optax as _optax
-
-            params = _optax.apply_updates(params, updates)
-        if (step + 1) % k1 == 0:
-            params, _ = _hfa_sync_round(kv, params, treedef, len(leaves),
-                                        buf, m)
-        m.step_end()
-        history.append((float(loss), float(acc)))
-        if log_fn is not None:
-            log_fn(step, float(loss), float(acc))
-    if params_out is not None:
-        params_out["params"] = params
-    return history
-
-
-def _hfa_sync_round(kv, params, treedef, n_leaves, buf, m,
-                    measure_comm: bool = False):
-    """One weight-exchange sync: push party-mean weights, pull the
-    merged result (shared by the HFA and ESync loops — one place for
-    the push normalization and pull-into-buf pattern).
-
-    Returns ``(params, comm_s)``.  ``comm_s`` (only when
-    ``measure_comm``) is the TRANSMISSION time: the server acks each
-    push on receipt, so waiting on push acks measures the uplink — the
-    pull barrier below it is the straggler wait ESync exists to
-    eliminate, and counting it as comm would feed the wait back into
-    the plan and pin every fast worker at min_steps."""
-    import time as _time
-
-    w_leaves, _ = jax.tree_util.tree_flatten(params)
-    comm_s = None
-    # re-read the party size EVERY sync: dynamic join/leave moves it
-    # mid-training (membership broadcast -> kv.num_workers), and the
-    # denominator each push used is announced as ``hfa_n`` so the
-    # server can renormalize a transition round's mixed-scale mean
-    n = kv.num_workers
-    t1 = _time.perf_counter()
-    with m.phase("push"):
-        push_ts = [kv.push(tid, np.asarray(w) / n, priority=-tid,
-                           body={"hfa_n": n})
-                   for tid, w in enumerate(w_leaves)]
-        if measure_comm:
-            for pts in push_ts:
-                kv.worker.wait(pts)
-            comm_s = _time.perf_counter() - t1
-        for tid in range(n_leaves):
-            kv.pull(tid, lambda t, arr: buf.__setitem__(t, arr),
-                    priority=-tid)
-    with m.phase("pull_wait"):
-        kv.wait_all()
-    return unflatten_params(treedef, buf), comm_s
-
-
 def build_flagship_lm():
-    """One shared builder for the flagship LM workload (>=10 M params)
-    so the TCP acceptance run (launch.py --workload lm) and the bench's
-    lm child train the IDENTICAL step — a size tweak applied to one
-    cannot silently diverge the other.  Size via GEOMX_LM_* env.
+    """The builder of the LM workload (>=10 M params) that the TCP
+    acceptance run trains (launch.py --workload lm).  Size via
+    GEOMX_LM_* env.
     Returns ``(cfg, params, n_params, grad_fn, data)``."""
     import os
 
@@ -197,100 +113,36 @@ def build_flagship_lm():
     return cfg, params, n_params, grad_fn, data
 
 
-def run_worker_esync(
-    kv: WorkerKVStore,
-    params,
-    grad_fn: Callable,
-    data_iter: Iterable,
-    rounds: int,
-    optimizer=None,
-    barrier_init: bool = True,
-    log_fn: Optional[Callable[[int, float, float], None]] = None,
-    params_out: Optional[dict] = None,
-    max_local_steps: int = 64,
-    measure=None,
-    rounds_out: Optional[list] = None,
-) -> List[Tuple[float, float]]:
-    """ESync client loop (geomx_tpu.sched.esync; ref README.md:45 — the
-    reference's planned-but-unintegrated straggler balancer, ESync
-    TSC'20).
+class ESync(NamedTuple):
+    """What a caller hands :class:`Trainer` to train under ESync
+    (geomx_tpu.sched.esync; ref README.md:45 — the reference's
+    planned-but-unintegrated straggler balancer, ESync TSC'20): the
+    party's state server assigns each worker its local steps a round, up
+    to ``max_local_steps``, so that fast workers fill the slowest
+    worker's round with local progress instead of idling at the barrier.
+    ``rounds_out`` collects ``(local steps run, reach-server seconds)`` a
+    round: heterogeneous workers must receive different assignments and
+    their reach spread must shrink (the acceptance observable)."""
 
-    Like HFA, each worker runs a LOCAL optimizer and pushes mean weights
-    at every sync — but the number of local steps between syncs is
-    assigned per worker per round by the party's state server, which
-    balances reach-server time across heterogeneous workers: fast
-    workers fill the slowest worker's round with extra local progress
-    instead of idling at the barrier.
+    max_local_steps: int = 64
+    rounds_out: Optional[list] = None
 
-    ``rounds`` counts SYNC rounds, identical on every worker of the
-    party (one push per worker per round keeps the HFA merge in
-    lockstep; a per-worker local-step budget would deadlock the party
-    when fast workers exhausted it in fewer rounds).  Local step counts
-    per round vary per worker.  ``data_iter`` should yield enough
-    batches (up to rounds × max_local_steps) or be cyclic; if it runs
-    dry the worker still pushes each remaining round.  Requires HFA mode
-    on the servers (weights, not gradients, cross the tiers;
-    Config.use_hfa / SET_HFA).
-    """
-    import time as _time
 
-    import optax
+class Schedule(NamedTuple):
+    """What :func:`run_worker` is told about a sync mode; every other
+    statement of the loop is the same for all of them.  The default is
+    FSA / MixedSync: the step's gradients cross the slice edge every
+    step, times ``1 / party size``, and the global tier runs the
+    optimizer.  :meth:`Trainer.schedule_for` picks one."""
 
-    from geomx_tpu.utils.measure import Measure
-
-    m = measure if measure is not None else Measure()
-    if optimizer is None:
-        optimizer = optax.adam(1e-2)
-    leaves, treedef = flatten_params(params)
-    for tid, leaf in enumerate(leaves):
-        kv.init(tid, leaf, barrier=barrier_init)
-    params = unflatten_params(treedef, leaves)
-    opt_state = optimizer.init(params)
-    history: List[Tuple[float, float]] = []
-    buf: List[Optional[np.ndarray]] = [None] * len(leaves)
-
-    it = iter(data_iter)
-    local_steps = 1  # until the state server has a plan
-    loss = acc = 0.0
-    for _round in range(rounds):
-        if _preempt_noticed(kv):
-            break
-        m.step_start()
-        t0 = _time.perf_counter()
-        ran = 0
-        with m.phase("grad"):
-            for _ in range(local_steps):
-                try:
-                    x, y = next(it)
-                except StopIteration:
-                    break
-                loss, acc, grads = grad_fn(params, x, y)
-                updates, opt_state = optimizer.update(grads, opt_state,
-                                                      params)
-                params = optax.apply_updates(params, updates)
-                ran += 1
-                history.append((float(loss), float(acc)))
-        step_s = (_time.perf_counter() - t0) / max(ran, 1)
-        params, comm_s = _hfa_sync_round(kv, params, treedef, len(leaves),
-                                         buf, m, measure_comm=True)
-        m.step_end()
-        if rounds_out is not None:
-            # acceptance observable: (assigned local steps, reach-server
-            # seconds) per round — heterogeneous workers must receive
-            # different assignments and their reach spread must shrink
-            rounds_out.append((ran, round(step_s * ran + comm_s, 4)))
-        if ran > 0:
-            # a dry data iterator (ran == 0) must not report: its
-            # near-zero "step time" would make the planner believe this
-            # worker is infinitely fast, collapse the reach-time target,
-            # and pin every worker that still has data at min_steps
-            local_steps = kv.esync_report(step_s, comm_s,
-                                          max_steps=max_local_steps)
-        if log_fn is not None:
-            log_fn(_round, float(loss), float(acc))
-    if params_out is not None:
-        params_out["params"] = params
-    return history
+    # the worker's own optimizer (optax): when set, every step ends with
+    # a local update and WEIGHTS cross, divided by the party size (the
+    # local server averages them; HFA and ESync)
+    optimizer: Optional[object] = None
+    # an exchange after every k1-th step (HFA's local steps between syncs)
+    k1: int = 1
+    # the steps of a round come from the party's state server
+    esync: Optional[ESync] = None
 
 
 class Trainer:
@@ -301,31 +153,55 @@ class Trainer:
     bind/init/optimizer/metric handled for the user).  This wraps the
     same ceremony: rank-0 control-plane configuration (optimizer to the
     global tier, compression to the party server), init barrier, the
-    training loop (plain FSA or HFA), and streaming-metric evaluation.
+    training loop under the cluster's sync mode, and streaming-metric
+    evaluation.
     """
 
     def __init__(self, kv: WorkerKVStore, params, grad_fn: Callable,
                  model=None, optimizer: Optional[dict] = None,
                  compression: Optional[dict] = None,
-                 hfa_k1: Optional[int] = None):
+                 hfa_k1: Optional[int] = None,
+                 esync: Optional[ESync] = None):
         self.kv = kv
         self.params = params
         self.grad_fn = grad_fn
         self.model = model  # flax module; needed for evaluate()
-        self.hfa_k1 = hfa_k1
-        if (hfa_k1 is not None) != bool(kv.config.use_hfa):
-            # the HFA client loop pushes WEIGHTS, the plain loop pushes
-            # GRADIENTS — a mismatch with the servers' mode silently
-            # corrupts training (weights fed to the optimizer as grads)
-            raise ValueError(
-                "hfa_k1 must be set if and only if the cluster runs with "
-                f"use_hfa (got hfa_k1={hfa_k1!r}, "
-                f"config.use_hfa={kv.config.use_hfa})")
+        self.schedule = self.schedule_for(kv, hfa_k1, esync)
         if kv.party == 0 and kv.rank == 0 and optimizer is not None:
             kv.set_optimizer(optimizer)
         if kv.rank == 0 and compression is not None:
             kv.set_gradient_compression(compression)
         kv.barrier()
+
+    @staticmethod
+    def schedule_for(kv: WorkerKVStore, hfa_k1: Optional[int] = None,
+                     esync: Optional[ESync] = None) -> Schedule:
+        """The one place a sync mode becomes the loop's schedule, from
+        what the worker can observe: servers in HFA mode
+        (``Config.use_hfa``) average WEIGHTS, every ``Config.hfa_k1``
+        local steps, or after as many as the state server assigns when
+        the caller hands an :class:`ESync`; every other cluster takes
+        GRADIENTS every step.  ``hfa_k1`` is a caller's own statement of
+        the mode (the benchmark's traffic files carry one) and has to
+        agree with the cluster: weights fed to the global optimizer as
+        gradients, or gradients averaged as weights, corrupt training in
+        silence."""
+        cfg = kv.config
+        if hfa_k1 is not None and (not cfg.use_hfa or hfa_k1 != cfg.hfa_k1):
+            raise ValueError(
+                f"hfa_k1={hfa_k1!r} disagrees with the cluster "
+                f"(config.use_hfa={cfg.use_hfa}, "
+                f"config.hfa_k1={cfg.hfa_k1})")
+        if esync is not None and not cfg.use_hfa:
+            raise ValueError("ESync exchanges weights: the cluster has to "
+                             "run with use_hfa")
+        if not cfg.use_hfa:
+            return Schedule()
+        import optax
+
+        return Schedule(optimizer=optax.adam(1e-2),
+                        k1=1 if esync is not None else cfg.hfa_k1,
+                        esync=esync)
 
     def fit(self, data_iter: Iterable, steps: int,
             log_fn: Optional[Callable[[int, float, float], None]] = None,
@@ -336,15 +212,9 @@ class Trainer:
         ``utils.Measure`` to collect the per-phase timing report
         (ref: examples/utils.py:120-192)."""
         captured: dict = {}
-        if self.hfa_k1 is not None:
-            hist = run_worker_hfa(self.kv, self.params, self.grad_fn,
-                                  data_iter, steps, k1=self.hfa_k1,
-                                  log_fn=log_fn, params_out=captured,
-                                  measure=measure)
-        else:
-            hist = run_worker(self.kv, self.params, self.grad_fn,
-                              data_iter, steps, log_fn=log_fn,
-                              params_out=captured, measure=measure)
+        hist = run_worker(self.kv, self.params, self.grad_fn, data_iter,
+                          steps, log_fn=log_fn, params_out=captured,
+                          measure=measure, schedule=self.schedule)
         if "params" in captured:
             self.params = captured["params"]
         return hist
@@ -392,14 +262,16 @@ class Trainer:
         return metric.get()
 
 
-def _edge_to_host(kv: WorkerKVStore, tid: int, g,
-                  scale: float) -> np.ndarray:
-    """One gradient leaf across the slice edge, in ONE pass: the copy
-    off the device (``edge.d2h``) and nothing after it.  A ``scale``
-    other than 1.0 (more than one worker a party) is applied on the
-    device before that copy (``edge.scale``: one elementwise program a
-    leaf), never as a second pass over the host copy; with ``scale ==
-    1.0`` no program is launched and no ``edge.scale`` span recorded.
+def _edge_to_host(kv: WorkerKVStore, tid: int, g, scale: float,
+                  divide: bool = False) -> np.ndarray:
+    """One leaf across the slice edge, in ONE pass: the copy off the
+    device (``edge.d2h``) and nothing after it.  A ``scale`` other than
+    1 (more than one worker a party) is applied on the device before
+    that copy (``edge.scale``: one elementwise program a leaf), never as
+    a second pass over the host copy: a gradient is multiplied by it, a
+    weight divided (``divide``; the two differ in the last bit where the
+    party size is not a power of two).  With ``scale == 1`` no program
+    is launched and no ``edge.scale`` span recorded.
 
     The result is read-only and is the host value jax caches on the
     array it was copied from: nothing writes to it again.  ``kv.push``
@@ -412,10 +284,47 @@ def _edge_to_host(kv: WorkerKVStore, tid: int, g,
     if scale != 1.0:
         with kv.trace_span("edge.scale", key=tid,
                            nbytes=getattr(g, "nbytes", None)):
-            g = g * scale
+            g = g / scale if divide else g * scale
     with kv.trace_span("edge.d2h", key=tid,
                        nbytes=getattr(g, "nbytes", None)):
         return np.asarray(g)
+
+
+def _exchange(kv: WorkerKVStore, tids: Sequence[int], leaves: list,
+              on_pulled: Callable[[int, np.ndarray], None],
+              scale: float = 1.0, divide: bool = False,
+              body: Optional[dict] = None) -> List[int]:
+    """What crosses the slice edge in a training step, and in what
+    order, for every sync mode and loop: each leaf's copy off the device
+    (:func:`_edge_to_host`), its push, and its pull right behind it —
+    the client holds the pull back until that push is acked
+    (``after_ts``), so the early tensors come back and are decoded on
+    the response thread while this thread still copies the later ones
+    off the device.  ``leaves`` is EMPTIED as it goes: a leaf's device
+    buffer is let go as soon as its copy is handed over, so hold no
+    other reference to them.  ``body`` rides every plain push
+    (``{"hfa_n": n}``: the denominator a weight was divided by).
+
+    The variant follows the cluster: under intra-party TS the leaves go
+    through the worker-to-worker merge tree and the elected holder
+    pushes for the party; under P3 a sliced push_pull whose response
+    carries the values.  Returns the pushes' timestamps (ESync times
+    their acks); the caller drains the pulls with ``kv.wait_all()``."""
+    if kv.ts_push is not None:
+        kv.ts_merge_push({tid: _edge_to_host(kv, tid, leaves.pop(0), scale,
+                                             divide) for tid in tids})
+        for tid in tids:
+            kv.pull(tid, on_pulled, priority=-tid)
+        return []
+    push_ts: List[int] = []
+    for tid in tids:
+        host = _edge_to_host(kv, tid, leaves.pop(0), scale, divide)
+        if kv.config.enable_p3:
+            push_ts += kv.push_pull(tid, host, on_pulled, priority=-tid)
+        else:
+            push_ts.append(kv.push(tid, host, priority=-tid, body=body))
+            kv.pull(tid, on_pulled, priority=-tid)
+    return push_ts
 
 
 def run_worker(
@@ -424,13 +333,22 @@ def run_worker(
     grad_fn: Callable,
     data_iter: Iterable,
     steps: int,
-    normalize: bool = True,
     barrier_init: bool = True,
     log_fn: Optional[Callable[[int, float, float], None]] = None,
     params_out: Optional[dict] = None,
     measure=None,
+    schedule: Schedule = Schedule(),
 ) -> List[Tuple[float, float]]:
-    """Train `steps` steps; returns [(loss, acc), ...] per step.
+    """The worker loop of every sync mode; returns [(loss, acc), ...]
+    per gradient step.
+
+    ``steps`` counts gradient steps, and under ESync sync ROUNDS,
+    identical on every worker of the party (one push a worker a round
+    keeps the weight merge in lockstep; a budget of local steps would
+    deadlock the party when fast workers exhausted it in fewer rounds):
+    there ``data_iter`` should be cyclic or yield up to ``steps x
+    max_local_steps`` batches, and a worker whose iterator runs dry
+    still pushes each remaining round.
 
     Under FSA the returned params after each step are identical on every
     worker (the convergence oracle the acceptance tests assert).
@@ -442,80 +360,110 @@ def run_worker(
     from geomx_tpu.utils.measure import Measure
 
     m = measure if measure is not None else Measure()
+    opt, k1, esync = schedule
+    if opt is not None:
+        import optax
     leaves, treedef = flatten_params(params)
     for tid, leaf in enumerate(leaves):
         kv.init(tid, leaf, barrier=barrier_init)
     params = unflatten_params(treedef, leaves)
-    # grads are summed across the party then averaged over parties at the
-    # global server; pre-divide by party size so the update is the all-worker
-    # mean (the reference examples normalize client-side the same way,
-    # ref: examples/cnn_hfa.py pushes param/num_local_workers)
+    opt_state = opt.init(params) if opt is not None else None
     history: List[Tuple[float, float]] = []
     buf: List[Optional[np.ndarray]] = [None] * len(leaves)
 
-    for step, (x, y) in enumerate(data_iter):
-        if step >= steps or _preempt_noticed(kv):
+    def on_pulled(tid, arr):
+        buf[tid] = arr
+
+    it = iter(data_iter)
+    local_steps = 1  # ESync: until the state server has a plan
+    loss = acc = 0.0
+    for step in itertools.count():
+        batch = next(it, None)
+        if (step >= steps or _preempt_noticed(kv)
+                or (batch is None and esync is None)):
             break
-        # re-read per step: dynamic join/leave changes the party size
-        # mid-training (the server broadcasts the new count, the client
-        # hook updates kv.num_workers) — a scale frozen at start would
-        # weight this worker's contribution wrongly after a membership
-        # change
-        scale = 1.0 / kv.num_workers if normalize else 1.0
+        due = (step + 1) % k1 == 0
         m.step_start()
+        t0 = time.perf_counter()
         # the whole step under one sampled root span (no-op unless
         # Config.trace_sample_every hits this round): every push/pull the
-        # step issues joins the round's cross-node trace
-        with kv.trace_round(step):
+        # step issues joins the round's cross-node trace.  Rounds are
+        # numbered by exchanges, the same on every worker.
+        with kv.trace_round(step // k1) if due else contextlib.nullcontext():
             with m.phase("grad"):
-                loss, acc, grads = grad_fn(params, x, y)
-                g_leaves = jax.tree_util.tree_leaves(grads)
-                # g_leaves alone holds the gradient from here on, so
-                # that the plain branch below can let each leaf's device
-                # buffer go as soon as its copy is off the chip
-                del grads
-                # block HERE so the phase split is honest: jax dispatch
-                # is async, and without this the whole backward pass
-                # would be billed to the push phase's first np.asarray
-                # (the plain loop converts leaf-by-leaf right below
-                # anyway, so this does not change the schedule; the
-                # staged OVERLAP loop — overlap.py — is the path that
-                # interleaves, not this one)
-                jax.block_until_ready(g_leaves)
-            with m.phase("push"):
-                if kv.ts_push is not None:
-                    # TS push direction: worker-to-worker merge tree; the
-                    # elected holder pushes the merged set for the party
-                    kv.ts_merge_push({tid: _edge_to_host(kv, tid, g, scale)
-                                      for tid, g in enumerate(g_leaves)})
-                    for tid in range(len(leaves)):
-                        kv.pull(tid,
-                                lambda t, arr: buf.__setitem__(t, arr),
-                                priority=-tid)
-                elif kv.config.enable_p3:
-                    # P3: sliced push+pull, values ride the response
-                    for tid, g in enumerate(g_leaves):
-                        kv.push_pull(tid, _edge_to_host(kv, tid, g, scale),
-                                     lambda t, arr: buf.__setitem__(t, arr),
-                                     priority=-tid)
-                else:
-                    # each tensor's pull right behind its push: the
-                    # client holds the pull back until that push is
-                    # acked (``after_ts``), so the early tensors come
-                    # back and are decoded on the response thread while
-                    # this thread still copies the later ones off the
-                    # device
-                    for tid in range(len(leaves)):
-                        kv.push(tid, _edge_to_host(kv, tid, g_leaves.pop(0),
-                                                   scale), priority=-tid)
-                        kv.pull(tid,
-                                lambda t, arr: buf.__setitem__(t, arr),
-                                priority=-tid)
-            with m.phase("pull_wait"):
-                kv.wait_all()
-        params = unflatten_params(treedef, buf)  # type: ignore[arg-type]
+                ran, grads = [], None
+                while batch is not None:
+                    loss, acc, grads = grad_fn(params, *batch)
+                    ran.append((loss, acc))
+                    if opt is not None:
+                        updates, opt_state = opt.update(grads, opt_state,
+                                                        params)
+                        params = optax.apply_updates(params, updates)
+                    batch = next(it, None) if len(ran) < local_steps else None
+                if due:
+                    # out alone holds a gradient from here on, so that
+                    # the exchange can let each leaf's device buffer go
+                    # as soon as its copy is off the chip
+                    out = jax.tree_util.tree_leaves(
+                        grads if opt is None else params)
+                    del grads
+                    # block HERE so the phase split is honest: jax
+                    # dispatch is async, and without this the whole
+                    # backward pass would be billed to the push phase's
+                    # first np.asarray (the exchange converts
+                    # leaf-by-leaf right below anyway, so this does not
+                    # change the schedule; the staged OVERLAP loop —
+                    # overlap.py — is the path that interleaves, not
+                    # this one)
+                    jax.block_until_ready(out)
+            grad_s = time.perf_counter() - t0
+            if due:
+                # re-read per exchange: dynamic join/leave changes the
+                # party size mid-training (the server broadcasts the new
+                # count, the client hook updates kv.num_workers).  Grads
+                # are summed across the party then averaged over parties
+                # at the global server: pre-scaled by 1 / party size the
+                # update is the all-worker mean (ref: examples/cnn_hfa.py
+                # pushes param / num_local_workers the same way).  The
+                # denominator a weight was divided by is announced as
+                # ``hfa_n``, so that the server can renormalize a
+                # transition round's mixed-scale mean.
+                n = kv.num_workers
+                crossing = (dict(scale=1.0 / n) if opt is None else
+                            dict(scale=n, divide=True, body={"hfa_n": n}))
+                t1 = time.perf_counter()
+                with m.phase("push"):
+                    push_ts = _exchange(kv, range(len(buf)), out, on_pulled,
+                                        **crossing)
+                    if esync is not None:
+                        # ESync's comm time is the TRANSMISSION: the
+                        # server acks each push on receipt, so waiting on
+                        # push acks measures the uplink — the pull barrier
+                        # below is the straggler wait ESync exists to
+                        # eliminate, and counting it as comm would feed
+                        # the wait back into the plan and pin every fast
+                        # worker at min_steps
+                        for ts in push_ts:
+                            kv.worker.wait(ts)
+                        comm_s = time.perf_counter() - t1
+                with m.phase("pull_wait"):
+                    kv.wait_all()
+        if due:
+            params = unflatten_params(treedef, buf)  # type: ignore[arg-type]
         m.step_end()
-        history.append((float(loss), float(acc)))
+        history.extend((float(lo), float(ac)) for lo, ac in ran)
+        if esync is not None:
+            step_s = grad_s / max(len(ran), 1)
+            if esync.rounds_out is not None:
+                esync.rounds_out.append(
+                    (len(ran), round(step_s * len(ran) + comm_s, 4)))
+            if ran:
+                # a dry data iterator must not report: its near-zero
+                # "step time" would make the planner believe this worker
+                # is infinitely fast, collapse the reach-time target, and
+                # pin every worker that still has data at min_steps
+                local_steps = kv.esync_report(
+                    step_s, comm_s, max_steps=esync.max_local_steps)
         if log_fn is not None:
             log_fn(step, float(loss), float(acc))
     if params_out is not None:

@@ -24,9 +24,8 @@ from geomx_tpu.core.config import NodeId
 
 # Wire-format selector: v2 (raw self-describing array framing, the
 # default) vs the legacy v1 np.save frames.  ``GEOMX_WIRE_FORMAT=v1``
-# pins the ENCODER to v1 for mixed-version rollouts and for the serde
-# microbench's same-run comparison; the decoder always auto-detects, so
-# either side may upgrade first.
+# pins the ENCODER to v1 for mixed-version rollouts; the decoder always
+# auto-detects, so either side may upgrade first.
 WIRE_V2 = os.environ.get("GEOMX_WIRE_FORMAT", "v2").strip().lower() != "v1"
 
 # Wire-integrity stamping (``GEOMX_INTEGRITY_WIRE=1`` /
@@ -439,8 +438,7 @@ class Message:
 
     def to_bytes_v1(self) -> bytes:
         """Legacy (pre-PR-5) frame: np.save blobs per array.  Kept so
-        old frames can be GENERATED for compat tests and so the serde
-        microbench can measure both formats in one run
+        old frames can be GENERATED for compat tests
         (``GEOMX_WIRE_FORMAT=v1`` flips to_bytes to this path)."""
         buf = io.BytesIO()
         meta_b = self._meta_blob()

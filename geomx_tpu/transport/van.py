@@ -610,7 +610,7 @@ class Van:
         # distributed tracing (geomx_tpu/trace): recorder fetched lazily
         # (tracing may activate after this van is built), plus per-codec
         # WAN byte counters mirrored into the system-metrics registry so
-        # the tracer's reports and bench.py read the same ledger
+        # the tracer's reports and the registry's readers share one ledger
         self._tracer = None
         # black-box flight recorder (geomx_tpu/obs/flight): wired by the
         # owning Postoffice when Config.enable_flight (default ON); None

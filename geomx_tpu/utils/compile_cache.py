@@ -1,8 +1,7 @@
 """Where the persistent XLA compilation cache lives.
 
-Every entry point that compiles for a device (``chip_smoke.py``, the
-``bench.py`` children, ``examples/``, ``launch.py``,
-``__graft_entry__.py``) calls :func:`enable_compile_cache` before its
+Every entry point that compiles for a device (``chip_smoke.py``,
+``examples/``, ``launch.py``, ``__graft_entry__.py``) calls :func:`enable_compile_cache` before its
 first compile.  The directory is part of the cache key, so it is never a
 temp name, a pid or a time: either the operator places it from outside
 with ``JAX_COMPILATION_CACHE_DIR`` (jax reads that variable itself and

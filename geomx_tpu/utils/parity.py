@@ -1,4 +1,4 @@
-"""Long-horizon convergence-parity harness (VERDICT r4 item 3).
+"""Long-horizon convergence-parity harness.
 
 The reference's acceptance criterion for every comms feature is
 "accuracy curve matches vanilla" over full training runs (ref:
@@ -10,11 +10,9 @@ that show up at horizon, not at step 8.
 
 This module trains the SAME model/data/seed through the two-tier stack
 under each feature config for a long horizon (default 200 steps) and
-reports the FINAL held-out accuracy per config.  It is shared by the
-slow test (tests/test_parity_horizon.py — asserts each config lands
-within its ε of vanilla) and the bench's ``parity`` child (emits the
-per-config deltas into BENCH_r{N}.json), so the numbers the judge sees
-and the numbers the suite gates on come from one code path.
+reports the FINAL held-out accuracy per config; the slow test
+(tests/test_parity_horizon.py) asserts each config lands within its ε
+of vanilla.
 """
 
 from __future__ import annotations
@@ -43,11 +41,10 @@ PARITY_CONFIGS: Dict[str, dict] = {
     # replicas drift for k1*k2=16 steps between WAN syncs: at this scale
     # (2 parties, noise-1.5 task) the measured staleness cost is large
     # and real — ~0.26 final accuracy vs vanilla for a 16x WAN-round
-    # saving (r5 measurement; this IS the staleness cost the scaling
-    # roofline's HFA column is annotated with).  The gate bounds it at
-    # 0.35: regressions that break convergence outright still fail, the
-    # honest cost passes and stays visible in the bench parity block.
-    "hfa_k2_8": {"hfa_k1": 2, "config": {"use_hfa": True, "hfa_k2": 8},
+    # saving (a CPU run of this harness).  The gate bounds it at 0.35:
+    # regressions that break convergence outright still fail, the
+    # honest cost passes.
+    "hfa_k2_8": {"config": {"use_hfa": True, "hfa_k1": 2, "hfa_k2": 8},
                  "eps": 0.35},
     # ESync syncs every round (staleness is bounded by the plan, not by
     # k2), and measured within +-0.07 of vanilla at equal step budget
@@ -81,8 +78,7 @@ def run_parity_config(name: str, steps: int = 200,
     from geomx_tpu.data import ShardedIterator, synthetic_classification
     from geomx_tpu.kvstore import Simulation
     from geomx_tpu.models import create_cnn_state
-    from geomx_tpu.training import (run_worker, run_worker_esync,
-                                    run_worker_hfa)
+    from geomx_tpu.training import ESync, Trainer, run_worker
 
     spec = dict(PARITY_CONFIGS[name] if spec is None else spec)
     fault = None
@@ -115,29 +111,22 @@ def run_parity_config(name: str, steps: int = 200,
             try:
                 kv = sim.worker(widx, 0)
                 if widx == 0:
-                    if spec.get("hfa_k1") is None and not spec.get("esync"):
+                    if not cfg.use_hfa:
                         kv.set_optimizer({"type": "adam", "lr": 0.01})
                     if "compression" in spec:
                         kv.set_gradient_compression(spec["compression"])
                 kv.barrier()
                 it = ShardedIterator(x_tr, y_tr, 16, widx, 2, seed=2)
                 out: dict = {}
-                if spec.get("esync"):
-                    # ESync counts sync ROUNDS.  With homogeneous
-                    # workers the planner assigns ~1 local step per
-                    # round, so rounds ≈ steps keeps the gradient-step
-                    # budget comparable to the plain runs (an unequal
-                    # budget would masquerade as convergence damage)
-                    hist = run_worker_esync(
-                        kv, params, grad_fn, it, rounds=steps,
-                        max_local_steps=8, params_out=out)
-                elif spec.get("hfa_k1") is not None:
-                    hist = run_worker_hfa(kv, params, grad_fn, it,
-                                          steps, k1=spec["hfa_k1"],
-                                          params_out=out)
-                else:
-                    hist = run_worker(kv, params, grad_fn, it,
-                                      steps, params_out=out)
+                # ESync counts sync ROUNDS.  With homogeneous workers
+                # the planner assigns ~1 local step per round, so rounds
+                # ≈ steps keeps the gradient-step budget comparable to
+                # the plain runs (an unequal budget would masquerade as
+                # convergence damage)
+                esync = ESync(max_local_steps=8) if spec.get("esync") else None
+                hist = run_worker(
+                    kv, params, grad_fn, it, steps, params_out=out,
+                    schedule=Trainer.schedule_for(kv, esync=esync))
                 logits = model.apply(out["params"], x_ev)
                 acc = float(np.mean(np.argmax(np.asarray(logits), -1)
                                     == y_ev))

@@ -184,7 +184,7 @@ def test_lm_flagship_tcp_topology():
         steps=3, timeout=420,
         # size bound tuned to the flagship's leaf sizes (the reference's
         # MXNET_KVSTORE_SIZE_LOWER_BOUND knob): 147k-element qkv/wo
-        # belong on BSC, not fp16 — same setting as bench child_lm
+        # belong on BSC, not fp16
         extra_env={"GEOMX_MPQ_SIZE_BOUND": "100000"})
     worker_out = outputs["worker:0@p0"]
     m = re.search(r"n_params=(\d+)", worker_out)

@@ -647,7 +647,7 @@ def _join_trains_under(cfg_kwargs, loop="plain"):
 
     from geomx_tpu.data import ShardedIterator, synthetic_classification
     from geomx_tpu.models import create_cnn_state
-    from geomx_tpu.training import run_worker, run_worker_esync
+    from geomx_tpu.training import ESync, Trainer, run_worker
 
     sim = Simulation(Config(
         topology=Topology(num_parties=1, workers_per_party=2),
@@ -666,13 +666,10 @@ def _join_trains_under(cfg_kwargs, loop="plain"):
             # no cycling wrapper needed (esync draws rounds x local
             # steps batches from it)
             it = ShardedIterator(x, y, 16, widx, nw, seed=1)
-            if loop == "esync":
-                hist[widx] = run_worker_esync(
-                    kv, params, grad_fn, it, n, barrier_init=False,
-                    max_local_steps=4)
-            else:
-                hist[widx] = run_worker(kv, params, grad_fn, it, n,
-                                        barrier_init=False)
+            esync = ESync(max_local_steps=4) if loop == "esync" else None
+            hist[widx] = run_worker(
+                kv, params, grad_fn, it, n, barrier_init=False,
+                schedule=Trainer.schedule_for(kv, esync=esync))
 
         ths = [threading.Thread(target=train, args=(w, i, 2, 2))
                for i, w in enumerate(ws)]

@@ -10,7 +10,7 @@ import numpy as np
 from geomx_tpu.core.config import Config, Topology
 from geomx_tpu.kvstore import Simulation
 from geomx_tpu.sched.esync import EsyncState
-from geomx_tpu.training import run_worker_esync
+from geomx_tpu.training import ESync, Trainer, run_worker
 
 
 def test_planner_balances_reach_time():
@@ -110,9 +110,11 @@ def test_esync_training_assigns_more_steps_to_fast_worker():
         def worker_main(rank, delay_s):
             kv = sim.worker(0, rank)
             out = {}
-            hist = run_worker_esync(
+            hist = run_worker(
                 kv, {"w": np.zeros(8, np.float32)}, make_grad_fn(delay_s),
-                batches(), rounds, params_out=out, max_local_steps=8)
+                batches(), rounds, params_out=out,
+                schedule=Trainer.schedule_for(
+                    kv, esync=ESync(max_local_steps=8)))
             results[rank] = (hist, out["params"])
 
         ts = [threading.Thread(target=worker_main, args=(0, 0.15)),

@@ -62,7 +62,7 @@ def test_flash_backward_matches_dense_interpret():
 
 
 def test_flash_bf16_within_tolerance_interpret():
-    """bf16 inputs — the dtype the MFU bench actually times."""
+    """bf16 inputs — the dtype the benchmark's flagship computes in."""
     cfg = TransformerConfig(attn_impl="flash")
     q, k, v = _qkv(jnp.bfloat16, seed=2)
     with force_tpu_interpret_mode():
@@ -71,18 +71,3 @@ def test_flash_bf16_within_tolerance_interpret():
     r = np.asarray(dense_attention(q, k, v, causal=True)
                    .astype(jnp.float32))
     assert np.max(np.abs(o - r)) < 5e-2
-
-
-def test_bench_flash_gate_fails_off_chip():
-    """bench.py's pre-timing exactness gate has no fallback: off-chip
-    (no interpret context) flash cannot lower, and the gate raises
-    instead of quietly timing ``attn_impl='fast'`` under flash's name."""
-    import sys
-    from pathlib import Path
-    sys.path.insert(0, str(Path(__file__).resolve().parent.parent))
-    from bench import _flash_exactness_check
-
-    with pytest.raises(ValueError, match="interpret mode"):
-        _flash_exactness_check("flash")
-    # non-flash configs skip the gate untouched
-    assert "skipped" in _flash_exactness_check("fast")

@@ -18,8 +18,7 @@ Four layers, each pinned here:
   seeded fuzz of truncations and bit flips — typed ``CodecError`` or a
   right-shaped tensor, never a crash or a silently wrong shape.
 
-The real-TCP operator tour is ``scripts/run_integrity_demo.sh``; the
-cost/coverage numbers come from ``bench.py --child integrity``.
+The real-TCP operator tour is ``scripts/run_integrity_demo.sh``.
 """
 
 import os
@@ -85,8 +84,7 @@ def test_flag_off_is_bit_for_bit_legacy(monkeypatch):
 def test_stamped_frame_detects_every_bit_flip(monkeypatch):
     """Random single-bit-flip sweep: every flip in a stamped frame must
     raise a typed error or fail framing — zero silently-wrong
-    deliveries.  (The larger randomized sweep runs in
-    ``bench.py --child integrity``.)"""
+    deliveries."""
     monkeypatch.setattr(message_mod, "WIRE_INTEGRITY", True)
     m = _msg(64)
     raw = bytearray(m.to_bytes())

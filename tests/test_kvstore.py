@@ -565,15 +565,15 @@ def test_merged_round_parks_member_pulls_until_complete():
 
 
 def test_partial_merge_parks_member_with_no_push_history():
-    """ADVICE r5 (round 5): under the TS push overlay, non-elected
-    workers NEVER push directly, so a push-history test would serve
-    their pulls from the previous round for every partial-merge window
-    — replicas silently diverging one round apart.  A known party
+    """Under the TS push overlay, non-elected workers NEVER push
+    directly, so a push-history test would serve their pulls from the
+    previous round for every partial-merge window — replicas silently
+    diverging one round apart.  A known party
     member with NO push history must PARK during a TS-merged partial
     round (its contribution rode the merge tree; the round completes
     without its direct push by construction), while an out-of-plan
     joiner's BOOTSTRAP pull (nothing pushed yet) is still served from
-    the last completed round — the advisor-r4 deadlock-free answer."""
+    the last completed round, which keeps a join free of deadlock."""
     import threading
     import time
 

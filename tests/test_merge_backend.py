@@ -139,8 +139,7 @@ def test_f16_promotion_rule_pinned_across_backends():
 
 def test_f32_merge_exact_parity_numpy_vs_jax():
     """Integer-valued f32 gradients make float accumulation exact in
-    any order, so the two backends must agree BIT-identically — the
-    CPU parity bar the bench child re-checks at 20M elements."""
+    any order, so the two backends must agree BIT-identically."""
     rng = np.random.default_rng(3)
     pushes = [rng.integers(-64, 64, 8192).astype(np.float32)
               for _ in range(8)]

@@ -352,7 +352,7 @@ def test_heartbeat_rtt_and_clock_offsets_in_registry():
 def test_wan_codec_bytes_in_registry():
     """Satellite: every GLOBAL-domain data send is ledgered per wire
     codec tag in the system-metrics registry (wan_bytes_vanilla /
-    wan_bytes_fp16 / ...) — the ledger bench.py's wan child reports."""
+    wan_bytes_fp16 / ...)."""
     base = system_snapshot()
     sim = Simulation(Config(topology=Topology(num_parties=2,
                                               workers_per_party=1)))

@@ -1,7 +1,7 @@
 """Pin the r4 ownership rules that produced the 13x server-merge win
 (kvstore/server.py: Message.donated adoption, frozen store aliasing,
-copy-on-write at the BSC decode).  The stress bench covers throughput;
-these tests pin the MECHANISM — on a faster host a reintroduced copy
+copy-on-write at the BSC decode).
+These tests pin the MECHANISM — on a faster host a reintroduced copy
 would not show up as a wall-clock regression until real scale.
 """
 
