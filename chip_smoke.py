@@ -146,6 +146,7 @@ def check_kernels(cfg: SmokeConfig) -> None:
     """Every pallas kernel in the package, compiled for the device it
     runs on, against its reference."""
     from geomx_tpu.models.transformer import (TransformerConfig,
+                                              _flash_block_sizes,
                                               _single_device_attention)
     from geomx_tpu.ops.block_attention import (_block_attn_ref,
                                                flash_block_attention)
@@ -166,7 +167,8 @@ def check_kernels(cfg: SmokeConfig) -> None:
     assert err < 5e-2, f"flash fwd vs fast_dense: max abs diff {err}"
     _assert_grads_close(gf, gr, "flash")
     _say(f"kernel flash_attention {cfg.flash_shape} bf16: fwd max abs diff "
-         f"{err:.2e}, grads within 5e-2 of fast_dense_attention")
+         f"{err:.2e}, grads within 5e-2 of fast_dense_attention; tiles "
+         f"{_flash_block_sizes(cfg.flash_shape[1], cfg.flash_shape[3])}")
 
     # the on-chip codec kernels against numpy
     n, thr, mom = cfg.codec_elems, 0.5, 0.9

@@ -60,8 +60,11 @@ class TransformerConfig:
     #                          per-device heads divisible by sp)
     attn_impl: str = "fast"  # single-device attention: "fast" (bf16 MXU
     #                          matmuls, fp32 accum/softmax), "dense"
-    #                          (all-fp32 reference), "flash" (pallas
-    #                          fused kernel, real TPU only)
+    #                          (all-fp32 reference), "flash" (jax's
+    #                          pallas fused kernel, real TPU only; its
+    #                          tiles come from the call's sequence
+    #                          length and head width:
+    #                          _flash_block_sizes)
     remat: bool = False      # jax.checkpoint each layer: recompute
     #                          activations in bwd, trading ~1/3 more
     #                          fwd FLOPs for O(L) less HBM — the TPU
@@ -232,6 +235,42 @@ def make_apply(cfg: TransformerConfig, mesh: Optional[Mesh] = None,
     return apply
 
 
+# Tiles of jax's TPU flash kernels (forward, dK/dV, dQ), largest first,
+# and the largest each of the kernels' sizes takes.  jax's own default
+# is 128 for every size, which at [4, 16, 2048, 128] is 16,384 grid
+# steps a call at 0.35-0.5 us each: the kernels ran at 8% of their
+# roofline on the v5e, bound by the price of a grid step.  The tops are
+# the fastest sizes of a sweep at that shape on the v5e that fit the
+# default scoped VMEM and add under 2 s of Mosaic compile (PERF.md
+# section 6, PR 34); a minor size never tops its major.
+_FLASH_TILES = (1024, 512, 256, 128)
+_FLASH_TILE_TOPS = dict(
+    block_q=512, block_k_major=512, block_k=512,
+    block_q_major_dkv=512, block_q_dkv=512,
+    block_k_major_dkv=1024, block_k_dkv=512,
+    block_q_dq=1024, block_k_major_dq=512, block_k_dq=512)
+
+
+def _flash_block_sizes(seq_len: int, head_dim: int):
+    """The ``BlockSizes`` for one call of jax's TPU flash attention,
+    chosen from the shape it is called with: each size is the largest
+    tile, up to its top, that divides ``seq_len``.  ``None``, jax's
+    default, where no tile does (a sequence shorter than 128 or not a
+    multiple of it)."""
+    from jax.experimental.pallas.ops.tpu.flash_attention import BlockSizes
+
+    least = _FLASH_TILES[-1]
+    if seq_len % least:
+        return None
+    # a tile's rows are head_dim wide in VMEM: the tops fit it up to a
+    # head width of 512 and shrink with a wider one
+    narrow = -(-head_dim // 512)
+    return BlockSizes(block_b=1, **{
+        size: next(t for t in _FLASH_TILES
+                   if t <= max(top // narrow, least) and seq_len % t == 0)
+        for size, top in _FLASH_TILE_TOPS.items()})
+
+
 def _single_device_attention(cfg: TransformerConfig, q, k, v):
     """Dispatch the single-device attention per ``cfg.attn_impl``."""
     if cfg.attn_impl == "dense":
@@ -248,7 +287,8 @@ def _single_device_attention(cfg: TransformerConfig, q, k, v):
         sm = float(1.0 / np.sqrt(q.shape[-1]))
         o = flash_attention(
             q.swapaxes(1, 2), k.swapaxes(1, 2), v.swapaxes(1, 2),
-            causal=True, sm_scale=sm)
+            causal=True, sm_scale=sm,
+            block_sizes=_flash_block_sizes(q.shape[1], q.shape[-1]))
         return o.swapaxes(1, 2)
     raise ValueError(f"unknown attn_impl {cfg.attn_impl!r}")
 

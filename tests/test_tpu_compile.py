@@ -88,3 +88,55 @@ def test_bsc_encoder_for_the_v5e_has_no_sort_and_keeps_its_name(one_chip):
     assert not re.search(r"\bsort\(", text)
     assert compiled.memory_analysis().temp_size_in_bytes <= 8 * n
     assert _executed_operations(text, trips=8) <= 115
+
+
+@pytest.mark.parametrize("shape", [(4, 2048, 16, 128), (4, 2048, 2, 1024)],
+                         ids=["flagship", "wide-head"])
+def test_flash_kernels_for_the_v5e_keep_their_names_and_carry_their_tiles(
+        one_chip, shape):
+    """``jax.grad`` through ``attn_impl="flash"`` at the flagship's
+    ``[B, T, H, Dh]`` and at a head wider than 512: the tiles
+    ``_flash_block_sizes`` picks fit the v5e's default scoped VMEM (the
+    compile would refuse them), the program still calls one kernel for
+    each pattern the benchmark's ``attn_roofline_pct`` reads the trace by
+    (a trace names an event by the instruction), and the backward
+    kernels' names carry the chosen sizes: the sign in a trace that the
+    chooser engaged."""
+    import json
+    from pathlib import Path
+
+    import jax
+    import jax.numpy as jnp
+
+    from benchmark.lib.trace import op_name
+    from geomx_tpu.models.transformer import (
+        TransformerConfig, _flash_block_sizes, _single_device_attention)
+
+    cfg = TransformerConfig(attn_impl="flash")
+    x = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+
+    def loss(q, k, v):
+        return jnp.sum(
+            _single_device_attention(cfg, q, k, v).astype(jnp.float32))
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        x, x, x).compile().as_text()
+    calls = [op_name(line.strip()) for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    reader = json.loads((Path(__file__).parent.parent / "benchmark"
+                         / "layer_metrics" / "attn_roofline_pct.json"
+                         ).read_text())
+    assert len(reader["kernels"]) == 3 == len(calls), calls
+    for kernel in reader["kernels"]:
+        hits = [c for c in calls if re.search(kernel["pattern"], c)]
+        assert len(hits) == 1, (kernel["pattern"], calls)
+
+    bs = _flash_block_sizes(shape[1], shape[3])
+    (dkv,) = [c for c in calls if "bwd_dkv" in c]
+    (dq,) = [c for c in calls if "bwd_dq" in c]
+    assert (f"block_q_major_{bs.block_q_major_dkv}_block_q_{bs.block_q_dkv}"
+            f"_block_k_major_{bs.block_k_major_dkv}_block_k_{bs.block_k_dkv}"
+            ) in dkv, dkv
+    assert (f"block_q_major_{bs.block_q_dq}_block_k_major_"
+            f"{bs.block_k_major_dq}_block_k_{bs.block_k_dq}") in dq, dq
+    assert bs.block_q_major_dkv > 128 and bs.block_q_dq > 128
