@@ -326,7 +326,7 @@ class WorkerKVStore:
 
     # ---- public API ---------------------------------------------------------
     def init(self, tid: int, value: np.ndarray, barrier: bool = False,
-             overwrite: bool = False):
+             overwrite: bool = False, group: Optional[str] = None):
         """Initialize a tensor. Call on every worker; rank-0 of each party
         does the actual send (ref: kvstore_dist.h:300-330 InitImpl — only
         rank 0 pushes init, others wait on barrier).
@@ -339,13 +339,23 @@ class WorkerKVStore:
         ``overwrite`` replaces the servers' value even if the key exists
         (checkpoint restore onto a live cluster).  Only call it between
         rounds — an overwrite racing an in-flight aggregation round
-        mixes old- and new-weight gradients."""
+        mixes old- and new-weight gradients.
+
+        ``group`` names what kind of state the tensor is (the KeyPlan's
+        ``group``: ``expert`` for a stacked expert leaf, else ``dense``).
+        It rides the init up both tiers, and from then on every span
+        that carries one of the tensor's keys carries its group."""
         value = np.asarray(value)
         self._shapes[tid] = value.shape
         self._dtypes[tid] = value.dtype
+        body = {"overwrite": True} if overwrite else None
+        if group is not None:
+            self.plan.set_group(tid, group)
+            keys = [int(p.ps_key) for p in self.plan.parts(tid, value.size)]
+            self._tracer.key_groups.update(dict.fromkeys([tid] + keys, group))
+            body = dict(body or {}, groups={group: keys})
         if self.rank == 0:
             flat = value.astype(np.float32).ravel()
-            body = {"overwrite": True} if overwrite else None
             self.worker.zpush(self._encode(tid, flat), cmd=Cmd.INIT,
                               wait=True, body=body)
         if barrier:

@@ -99,10 +99,27 @@ def encode_tensor(
     return parts
 
 
+DENSE, EXPERT = "dense", "expert"
+
+
+def leaf_groups(params) -> List[str]:
+    """The group of every leaf of a parameter tree, in flattening order
+    (the worker loop's tensor ids): ``expert`` for a leaf under a dict
+    key ``experts`` (a stack of the experts a chip holds, one leaf and
+    one key a stack), ``dense`` for the rest."""
+    import jax
+
+    return [EXPERT if any(getattr(k, "key", None) == "experts" for k in path)
+            else DENSE
+            for path, _ in jax.tree_util.tree_flatten_with_path(params)[0]]
+
+
 @dataclasses.dataclass
 class KeyPlan:
     """Cached encoding for a model's tensors (ref: the encode cache
-    kvstore_dist.h:711-719 ps_kv_)."""
+    kvstore_dist.h:711-719 ps_kv_), and each tensor's ``group``: what
+    kind of state its keys hold (``dense`` unless its init said
+    otherwise), which every span that carries a key carries beside it."""
 
     num_shards: int
     bigarray_bound: int = 1_000_000
@@ -110,6 +127,13 @@ class KeyPlan:
 
     def __post_init__(self):
         self._cache = {}
+        self._groups = {}
+
+    def set_group(self, tensor_id: int, group: str) -> None:
+        self._groups[tensor_id] = group
+
+    def group(self, tensor_id: int) -> str:
+        return self._groups.get(tensor_id, DENSE)
 
     def parts(self, tensor_id: int, size: int, priority: int = 0) -> List[KeyPart]:
         ent = self._cache.get(tensor_id)

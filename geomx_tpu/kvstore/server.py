@@ -50,6 +50,17 @@ from geomx_tpu.trace import context as _tctx
 from geomx_tpu.transport.message import Control, Domain, Message
 
 
+def _note_key_groups(tracer, msg):
+    """An INIT's ``groups`` ({group: [ps keys]}: the KeyPlan's group of
+    each tensor that has one, ``kvstore/client.py`` ``init``) into this
+    node's tracer, whose spans then carry ``group`` beside ``key``;
+    returns it for the init that goes on to the next tier, or None."""
+    groups = msg.body.get("groups") if isinstance(msg.body, dict) else None
+    for group, keys in (groups or {}).items():
+        tracer.key_groups.update(dict.fromkeys(map(int, keys), group))
+    return groups
+
+
 def _ctx_bound(fn, lane_tracer=None, key=None):
     """Carry the calling (handler) thread's trace context onto a merge
     lane: a sampled round's merge spans — and the WAN push-up messages
@@ -620,6 +631,7 @@ class LocalServer:
             return
         overwrite = bool(isinstance(msg.body, dict)
                          and msg.body.get("overwrite"))
+        groups = _note_key_groups(self._tr, msg)
         with self._mu:
             fresh = []
             for k, v in kvs.slices():
@@ -658,7 +670,8 @@ class LocalServer:
             self.up.zpush(
                 KVPairs(ks, vals, lens), cmd=Cmd.INIT,
                 on_complete=ack,
-                body=msg.body if overwrite else None,
+                body=msg.body if overwrite
+                else {"groups": groups} if groups else None,
             )
         else:
             self._recent.mark_done(msg)
@@ -3218,6 +3231,7 @@ class GlobalServer:
                 return
             overwrite = bool(isinstance(msg.body, dict)
                              and msg.body.get("overwrite"))
+            _note_key_groups(self._tr, msg)
             stale_acks: List[Message] = []
             with self._mu:
                 fresh = False

@@ -773,4 +773,12 @@ class Simulation:
             s.stop()
         for po in self.offices.values():
             po.stop()
+        if self.config.trace_sample_every > 0:
+            # the tracers outlive the deployment (a registry by node
+            # name): attached, each would hold its stopped node, and a
+            # server's weights and optimizer state on the device with it
+            from geomx_tpu.trace import get_tracer
+
+            for node in self.offices:
+                get_tracer(str(node)).detach()
         self.fabric.shutdown()

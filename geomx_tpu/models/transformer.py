@@ -18,6 +18,16 @@ parallelism the reference lacks:
   GShard-style top-k dispatch with capacity (``parallel/moe.py``),
   per-token FLOPs independent of the expert count.
 
+Beyond the flagship's GPT block the config composes what today's
+published architectures are made of, each off by default (all off is
+the flagship, whose program does not change): grouped-query heads
+(``n_kv_heads``), RMSNorm on q and k (``qk_norm``), rotary positions
+(``rope_theta``), a gated FFN (``gated_ffn``), a per-layer operator
+pattern (``layer_types``: ``full_attention`` or the gated short
+convolution ``conv``), and after ``n_dense_layers`` leading dense
+layers one chip's share of routed experts with no capacity and no
+dropped token (``router_experts``; ``parallel/moe.py`` ``routed_ffn``).
+
 Pure-jax functional style: ``init_params`` builds a pytree,
 ``param_specs`` mirrors it with PartitionSpecs, ``make_apply`` returns the
 forward.  bf16 activations, f32 params/accumulators.
@@ -26,7 +36,7 @@ forward.  bf16 activations, f32 params/accumulators.
 from __future__ import annotations
 
 import dataclasses
-from typing import Any, Dict, Optional
+from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -69,14 +79,70 @@ class TransformerConfig:
     #                          activations in bwd, trading ~1/3 more
     #                          fwd FLOPs for O(L) less HBM — the TPU
     #                          recipe for big batches / long seq
+    # ---- off by default: all off is the flagship's GPT block ----------
+    n_kv_heads: int = 0      # grouped-query attention: k and v heads,
+    #                          each serving n_heads // n_kv_heads
+    #                          consecutive q heads (0 = n_heads)
+    qk_norm: bool = False    # RMSNorm with a learned scale over each
+    #                          head's channels of q and of k
+    rope_theta: float = 0.0  # > 0: rotary positions over the whole head
+    #                          (rotate-half pairing) and no learned
+    #                          ``pos`` table
+    norm_eps: float = 1e-6
+    gated_ffn: bool = False  # w2(silu(w1 x) * w3 x), not w2 gelu(w1 x)
+    layer_types: Tuple[str, ...] = ()   # per layer "full_attention" or
+    #                          "conv" (gated short convolution); empty =
+    #                          attention everywhere
+    conv_kernel: int = 3     # taps of the short convolution
+    n_dense_layers: int = 0  # leading layers that keep the dense FFN
+    router_experts: int = 0  # > 0: every later layer routes over this
+    #                          many experts (the whole deployment's),
+    #                          moe_top_k a token, and holds n_experts of
+    #                          them, first_expert onwards, d_expert wide
+    first_expert: int = 0
+    d_expert: int = 0
+    routed_scale: float = 1.0
+    expert_impl: str = "ragged"   # the grouped products: "ragged"
+    #                          (lax.ragged_dot, any backend) or "gmm"
+    #                          (jax's megablox kernels, TPU only)
+
+    def __post_init__(self):
+        if self.layer_types and len(self.layer_types) != self.n_layers:
+            raise ValueError(f"layer_types names {len(self.layer_types)} "
+                             f"layers, n_layers is {self.n_layers}")
+        unknown = set(self.layer_types) - {"full_attention", "conv"}
+        if unknown:
+            raise ValueError(f"unknown layer type(s) {sorted(unknown)}")
+        if self.n_heads % self.kv_heads:
+            raise ValueError(f"{self.n_heads} q heads do not divide over "
+                             f"{self.kv_heads} k/v heads")
+        if self.router_experts and (self.moe_every or not (
+                0 < self.moe_top_k <= self.router_experts
+                and 0 <= self.first_expert
+                and self.first_expert + self.n_experts
+                <= self.router_experts and self.d_expert > 0)):
+            raise ValueError(
+                "a routed layer needs moe_every 0, moe_top_k in 1.."
+                "router_experts, d_expert > 0 and the held experts "
+                "first_expert..first_expert+n_experts-1 among them")
 
     @property
     def head_dim(self) -> int:
         assert self.d_model % self.n_heads == 0
         return self.d_model // self.n_heads
 
+    @property
+    def kv_heads(self) -> int:
+        return self.n_kv_heads or self.n_heads
+
     def is_moe(self, layer: int) -> bool:
         return self.moe_every > 0 and (layer + 1) % self.moe_every == 0
+
+    def is_routed(self, layer: int) -> bool:
+        return self.router_experts > 0 and layer >= self.n_dense_layers
+
+    def is_conv(self, layer: int) -> bool:
+        return bool(self.layer_types) and self.layer_types[layer] == "conv"
 
 
 def init_params(cfg: TransformerConfig, rng: jax.Array) -> Dict:
@@ -91,25 +157,58 @@ def init_params(cfg: TransformerConfig, rng: jax.Array) -> Dict:
         "ln_f": jnp.ones((cfg.d_model,), jnp.float32),
         "layers": [],
     }
-    H, Dh, D, F = cfg.n_heads, cfg.head_dim, cfg.d_model, cfg.d_ff
+    if cfg.rope_theta:
+        del params["pos"]
+    H, Hkv, Dh, D, F = (cfg.n_heads, cfg.kv_heads, cfg.head_dim,
+                        cfg.d_model, cfg.d_ff)
     for i in range(cfg.n_layers):
         k = jax.random.split(keys[3 + i], 8)
         layer = {
             "ln1": jnp.ones((D,), jnp.float32),
             "ln2": jnp.ones((D,), jnp.float32),
-            "wq": dense(k[0], (D, H, Dh)),
-            "wk": dense(k[1], (D, H, Dh)),
-            "wv": dense(k[2], (D, H, Dh)),
-            "wo": dense(k[3], (H, Dh, D), scale=1.0 / np.sqrt(D)),
         }
+        if cfg.is_conv(i):
+            layer["w_in"] = dense(k[0], (D, 3 * D))
+            layer["conv"] = dense(k[1], (D, cfg.conv_kernel),
+                                  scale=1.0 / np.sqrt(cfg.conv_kernel))
+            layer["w_out"] = dense(k[3], (D, D))
+        else:
+            layer["wq"] = dense(k[0], (D, H, Dh))
+            layer["wk"] = dense(k[1], (D, Hkv, Dh))
+            layer["wv"] = dense(k[2], (D, Hkv, Dh))
+            layer["wo"] = dense(k[3], (H, Dh, D), scale=1.0 / np.sqrt(D))
+            if cfg.qk_norm:
+                layer["q_norm"] = jnp.ones((Dh,), jnp.float32)
+                layer["k_norm"] = jnp.ones((Dh,), jnp.float32)
         if cfg.is_moe(i):
             E = cfg.n_experts
             layer["router"] = dense(k[6], (D, E), scale=0.02)
             layer["we1"] = dense(k[4], (E, D, F))
             layer["we2"] = dense(k[5], (E, F, D), scale=1.0 / np.sqrt(F))
+        elif cfg.is_routed(i):
+            E, Fe = cfg.n_experts, cfg.d_expert
+            ke = jax.random.split(k[4], 3)
+            layer["router"] = dense(k[6], (D, cfg.router_experts))
+            # selects and never weighs; no gradient reaches it, and no
+            # published rule moves it: a seeded constant.  Its spread is
+            # small where it acts: at the top-k threshold a score moves
+            # 0.15 a unit of logit, so 0.002 is a logit offset of 0.013
+            # (0.02 was one of 0.13 and skewed the experts' loads by a
+            # quarter, against what a selection bias is for)
+            layer["expert_bias"] = dense(k[7], (cfg.router_experts,),
+                                         scale=0.002)
+            # one leaf a stack: a kvstore key each (KeyPlan group
+            # "expert": a path through the key ``experts``)
+            layer["experts"] = {
+                "w1": dense(ke[0], (E, D, Fe), scale=1.0 / np.sqrt(D)),
+                "w3": dense(ke[1], (E, D, Fe), scale=1.0 / np.sqrt(D)),
+                "w2": dense(ke[2], (E, Fe, D), scale=1.0 / np.sqrt(Fe)),
+            }
         else:
             layer["w1"] = dense(k[4], (D, F))
             layer["w2"] = dense(k[5], (F, D), scale=1.0 / np.sqrt(F))
+            if cfg.gated_ffn:
+                layer["w3"] = dense(k[7], (D, F))
         params["layers"].append(layer)
     return params
 
@@ -132,33 +231,74 @@ def param_specs(cfg: TransformerConfig) -> Dict:
         "ln_f": P(None),
         "layers": [],
     }
+    if cfg.rope_theta:
+        del specs["pos"]
     for i in range(cfg.n_layers):
-        layer = {
-            "ln1": P(None),
-            "ln2": P(None),
-            "wq": P(None, "tp", None),
-            "wk": P(None, "tp", None),
-            "wv": P(None, "tp", None),
-            "wo": P("tp", None, None),
-        }
+        layer = {"ln1": P(None), "ln2": P(None)}
+        if cfg.is_conv(i):
+            # the depthwise taps follow their channels: not split yet
+            layer.update(w_in=P(None, None), conv=P(None, None),
+                         w_out=P(None, None))
+        else:
+            layer.update(wq=P(None, "tp", None), wk=P(None, "tp", None),
+                         wv=P(None, "tp", None), wo=P("tp", None, None))
+            if cfg.qk_norm:
+                layer.update(q_norm=P(None), k_norm=P(None))
         if cfg.is_moe(i):
             layer["router"] = P(None, None)
             layer["we1"] = P("tp", None, None)   # expert-parallel (ep≡tp)
             layer["we2"] = P("tp", None, None)
+        elif cfg.is_routed(i):
+            # a held share is one chip's: whole on every device
+            layer["router"] = P(None, None)
+            layer["expert_bias"] = P(None)
+            layer["experts"] = {n: P(None, None, None)
+                                for n in ("w1", "w3", "w2")}
         else:
             layer["w1"] = P(None, "tp")
             layer["w2"] = P("tp", None)
+            if cfg.gated_ffn:
+                layer["w3"] = P(None, "tp")
         specs["layers"].append(layer)
     return specs
 
 
-def _rms_norm(x, scale):
+def _rms_norm(x, scale, eps: float = 1e-6):
     var = jnp.mean(jnp.square(x.astype(jnp.float32)), axis=-1, keepdims=True)
-    return (x.astype(jnp.float32) * jax.lax.rsqrt(var + 1e-6) * scale).astype(x.dtype)
+    return (x.astype(jnp.float32) * jax.lax.rsqrt(var + eps) * scale).astype(x.dtype)
+
+
+def _rope(x, theta: float):
+    """Rotary positions over the whole head of ``x`` [B, T, H, Dh],
+    rotate-half pairing: channel i turns with channel i + Dh/2 by the
+    angle ``t * theta ** (-2i / Dh)``; float32 inside."""
+    T, half = x.shape[1], x.shape[-1] // 2
+    freq = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
+    ang = jnp.arange(T, dtype=jnp.float32)[:, None] * freq[None]
+    cos, sin = jnp.cos(ang)[None, :, None], jnp.sin(ang)[None, :, None]
+    x1 = x[..., :half].astype(jnp.float32)
+    x2 = x[..., half:].astype(jnp.float32)
+    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
+                           axis=-1).astype(x.dtype)
+
+
+def _short_conv(cfg: "TransformerConfig", layer, h):
+    """The gated short convolution: ``[b, c, z] = split(w_in h)``, a
+    depthwise causal convolution of ``b * z`` over time (``conv_kernel``
+    taps, zeros left of the sequence, no bias), gated by ``c``, then
+    ``w_out``.  The taps are summed in float32."""
+    cd, K, T = cfg.compute_dtype, cfg.conv_kernel, h.shape[1]
+    b, c, z = jnp.split(
+        jnp.einsum("btd,de->bte", h, layer["w_in"].astype(cd)), 3, axis=-1)
+    u = jnp.pad((b * z).astype(jnp.float32), ((0, 0), (K - 1, 0), (0, 0)))
+    taps = layer["conv"].astype(jnp.float32)
+    y = sum(u[:, j:j + T] * taps[:, j] for j in range(K))
+    return jnp.einsum("btd,de->bte", c * y.astype(cd),
+                      layer["w_out"].astype(cd))
 
 
 def make_apply(cfg: TransformerConfig, mesh: Optional[Mesh] = None,
-               return_aux: bool = False):
+               return_aux: bool = False, return_route: bool = False):
     """Build the forward fn.  With a mesh containing an ``sp`` axis of
     size > 1, attention runs sequence-parallel in shard_map — ring
     attention or Ulysses all-to-all per ``cfg.sp_attn`` — otherwise the
@@ -169,7 +309,12 @@ def make_apply(cfg: TransformerConfig, mesh: Optional[Mesh] = None,
     default keeps the historical logits-only signature.  TRAINING a
     top-k MoE through the logits-only form discards the load-balancing
     pressure (router collapse, silent capacity drops) — fine for
-    inference/forward comparisons, so it warns instead of raising."""
+    inference/forward comparisons, so it warns instead of raising.
+
+    ``return_route=True`` appends what the routed layers saw (None
+    without any): int32 ``rows`` [routed layers, experts held],
+    ``held_pairs`` and ``empty_tokens`` [routed layers], as
+    ``parallel/moe.py`` ``routed_ffn`` counts them."""
     if cfg.moe_every > 0 and cfg.moe_top_k > 0 and not return_aux:
         import warnings
 
@@ -213,7 +358,8 @@ def make_apply(cfg: TransformerConfig, mesh: Optional[Mesh] = None,
         cd = cfg.compute_dtype
         B, T = tokens.shape
         x = params["embed"][tokens].astype(cd)
-        x = x + params["pos"][:T][None].astype(cd)
+        if not cfg.rope_theta:
+            x = x + params["pos"][:T][None].astype(cd)
         shard = None
         if use_ring:
             shard = NamedSharding(mesh, P("dp", "sp", "tp", None))
@@ -224,13 +370,20 @@ def make_apply(cfg: TransformerConfig, mesh: Optional[Mesh] = None,
         if cfg.remat:
             layer_fn = jax.checkpoint(layer_fn, static_argnums=(2,))
         aux_total = jnp.zeros((), jnp.float32)
+        routes = []
         for i, layer in enumerate(params["layers"]):
-            x, aux = layer_fn(layer, x, i)
+            x, aux, route = layer_fn(layer, x, i)
             aux_total = aux_total + aux
-        x = _rms_norm(x, params["ln_f"])
+            if route is not None:
+                routes.append(route)
+        x = _rms_norm(x, params["ln_f"], cfg.norm_eps)
         logits = jnp.einsum("btd,vd->btv", x, params["embed"].astype(cd))
         logits = logits.astype(jnp.float32)
-        return (logits, aux_total) if return_aux else logits
+        out = (logits, aux_total) if return_aux else (logits,)
+        if return_route:
+            out += (jax.tree_util.tree_map(lambda *a: jnp.stack(a), *routes)
+                    if routes else None,)
+        return out if len(out) > 1 else logits
 
     return apply
 
@@ -293,23 +446,57 @@ def _single_device_attention(cfg: TransformerConfig, q, k, v):
     raise ValueError(f"unknown attn_impl {cfg.attn_impl!r}")
 
 
-def _layer_forward(cfg: TransformerConfig, i: int, layer, x, attn_op,
-                   shard=None):
-    """One transformer block (attention + MLP/MoE residual)."""
+def _attention(cfg: TransformerConfig, layer, h, attn_op, shard=None):
+    """Causal self-attention of one block on the normed stream ``h``:
+    the flagship's multi-head form, and with ``n_kv_heads`` /
+    ``qk_norm`` / ``rope_theta`` set grouped-query heads (each k/v head
+    repeated for the consecutive q heads it serves, so that every
+    attention implementation sees equal head counts), RMSNorm over each
+    head's channels of q and k, and rotary positions."""
     cd = cfg.compute_dtype
-    h = _rms_norm(x, layer["ln1"])
     q = jnp.einsum("btd,dhk->bthk", h, layer["wq"].astype(cd))
     k = jnp.einsum("btd,dhk->bthk", h, layer["wk"].astype(cd))
     v = jnp.einsum("btd,dhk->bthk", h, layer["wv"].astype(cd))
+    if cfg.qk_norm:
+        q = _rms_norm(q, layer["q_norm"], cfg.norm_eps)
+        k = _rms_norm(k, layer["k_norm"], cfg.norm_eps)
+    if cfg.rope_theta:
+        q, k = _rope(q, cfg.rope_theta), _rope(k, cfg.rope_theta)
+    if cfg.kv_heads != cfg.n_heads:
+        group = cfg.n_heads // cfg.kv_heads
+        k, v = jnp.repeat(k, group, axis=2), jnp.repeat(v, group, axis=2)
     if shard is not None:
         q = lax.with_sharding_constraint(q, shard)
         k = lax.with_sharding_constraint(k, shard)
         v = lax.with_sharding_constraint(v, shard)
     a = attn_op(q, k, v)
-    x = x + jnp.einsum("bthk,hkd->btd", a, layer["wo"].astype(cd))
-    h = _rms_norm(x, layer["ln2"])
+    return jnp.einsum("bthk,hkd->btd", a, layer["wo"].astype(cd))
+
+
+def _layer_forward(cfg: TransformerConfig, i: int, layer, x, attn_op,
+                   shard=None):
+    """One block: the layer's operator (attention or the gated short
+    convolution) and its FFN (dense, MoE or routed share), each a
+    residual.  Returns ``(x, aux, route)``: the capacity MoE's
+    load-balancing loss (0 elsewhere) and a routed layer's counts (None
+    elsewhere)."""
+    cd = cfg.compute_dtype
+    h = _rms_norm(x, layer["ln1"], cfg.norm_eps)
+    if cfg.is_conv(i):
+        x = x + _short_conv(cfg, layer, h)
+    else:
+        x = x + _attention(cfg, layer, h, attn_op, shard)
+    h = _rms_norm(x, layer["ln2"], cfg.norm_eps)
     aux = jnp.zeros((), jnp.float32)
-    if cfg.is_moe(i):
+    route = None
+    if cfg.is_routed(i):
+        from geomx_tpu.parallel.moe import routed_ffn
+        y, route = routed_ffn(
+            h, layer["router"], layer["expert_bias"], layer["experts"],
+            first=cfg.first_expert, k=cfg.moe_top_k,
+            scale=cfg.routed_scale, impl=cfg.expert_impl, compute_dtype=cd)
+        x = x + y
+    elif cfg.is_moe(i):
         if cfg.moe_top_k > 0:
             # real EP: top-k routing with capacity; each token computed
             # by only its k experts (parallel/moe.py, batch = groups)
@@ -331,10 +518,14 @@ def _layer_forward(cfg: TransformerConfig, i: int, layer, x, attn_op,
             down = jnp.einsum("btef,efd->bted", up, layer["we2"].astype(cd))
             x = x + jnp.einsum("bted,bte->btd", down, gates)
     else:
-        up = jax.nn.gelu(jnp.einsum("btd,df->btf", h,
-                                    layer["w1"].astype(cd)))
+        up = jnp.einsum("btd,df->btf", h, layer["w1"].astype(cd))
+        if cfg.gated_ffn:
+            up = jax.nn.silu(up) * jnp.einsum("btd,df->btf", h,
+                                              layer["w3"].astype(cd))
+        else:
+            up = jax.nn.gelu(up)
         x = x + jnp.einsum("btf,fd->btd", up, layer["w2"].astype(cd))
-    return x, aux
+    return x, aux, route
 
 
 def make_staged(cfg: TransformerConfig, rng: jax.Array):
@@ -354,6 +545,9 @@ def make_staged(cfg: TransformerConfig, rng: jax.Array):
         raise ValueError("make_staged supports dense-routing MoE only "
                          "(moe_top_k must be 0): the staged loss has no "
                          "aux-loss channel")
+    if cfg.rope_theta:
+        raise ValueError("make_staged's embedding stage adds the learned "
+                         "positions: rope_theta must be 0")
     params = init_params(cfg, rng)
     head = jax.random.normal(
         jax.random.fold_in(rng, 7), (cfg.d_model, cfg.vocab),
@@ -414,22 +608,31 @@ def make_lm_grad_fn(cfg: "TransformerConfig"):
     """Jitted ``grad_fn(params, x, y) -> (loss, acc, grads)`` with the
     worker-loop signature (``training.run_worker``); y is ignored (the
     LM objective shifts x).  The launcher's LM workload and the
-    benchmark's flagship family train this step.  Top-k MoE
+    benchmark's families train this step.  Top-k MoE
     configs train with the load-balancing aux folded in (the same
-    objective examples/lm.py uses)."""
+    objective examples/lm.py uses).  A config with routed layers
+    (``router_experts``) returns a fourth value, ``{"moe_route": ...}``:
+    the layers' int32 counts, which the worker loop reads to the host in
+    a sampled round only (span ``moe.route``)."""
     use_aux = cfg.moe_every > 0 and cfg.moe_top_k > 0
-    apply_fn = make_apply(cfg, return_aux=use_aux)
+    routed = any(cfg.is_routed(i) for i in range(cfg.n_layers))
+    apply_fn = make_apply(cfg, return_aux=use_aux, return_route=routed)
 
     @jax.jit
     def grad_fn(p, x, _y):
         def loss_fn(p):
             out = apply_fn(p, x)
-            logits, aux = out if use_aux else (out, 0.0)
+            out = out if isinstance(out, tuple) else (out,)
+            logits = out[0]
+            aux = out[1] if use_aux else 0.0
             loss = token_cross_entropy(logits, x) + AUX_COEF * aux
             acc = jnp.mean(jnp.argmax(logits[:, :-1], axis=-1) == x[:, 1:])
-            return loss, acc
+            return loss, (acc, out[-1] if routed else None)
 
-        (loss, acc), g = jax.value_and_grad(loss_fn, has_aux=True)(p)
+        (loss, (acc, route)), g = jax.value_and_grad(
+            loss_fn, has_aux=True)(p)
+        if routed:
+            return loss, acc, g, {"moe_route": route}
         return loss, acc, g
 
     return grad_fn

@@ -141,7 +141,9 @@ def init_pp_transformer(cfg, rng):
     stage's grads are pushed to the kvstore independently)."""
     from geomx_tpu.models.transformer import init_params
 
-    assert cfg.moe_every == 0, "pp flagship pipelines homogeneous layers"
+    assert not (cfg.moe_every or cfg.layer_types or cfg.router_experts
+                or cfg.rope_theta), \
+        "pp flagship pipelines homogeneous layers with learned positions"
     params = init_params(cfg, rng)
     import numpy as np
     head = jax.random.normal(
@@ -183,7 +185,9 @@ def make_pp_apply(cfg, mesh: Mesh, n_microbatches: int,
     # same guard as init_pp_transformer: block() routes every layer
     # through _layer_forward(idx=0), which silently applies dense FFN
     # (and drops the aux loss) for a MoE config
-    assert cfg.moe_every == 0, "pp flagship pipelines homogeneous layers"
+    assert not (cfg.moe_every or cfg.layer_types or cfg.router_experts
+                or cfg.rope_theta), \
+        "pp flagship pipelines homogeneous layers with learned positions"
 
     def block(layer, x):
         return _layer_forward(

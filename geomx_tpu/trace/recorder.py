@@ -50,6 +50,8 @@ class _NullSpan:
 
 
 _NULL_SPAN = _NullSpan()
+# the group of a key no init named one for (kvstore/keys.py KeyPlan)
+DEFAULT_GROUP = "dense"
 
 # the span's name on the profiler's timeline: ``geomx:<node>:<name>``
 # (NOT the benchmark's ``bench:``, which names the workers' phases)
@@ -99,8 +101,15 @@ class _Span:
         self.span_id = _ctx.new_span_id()
         self.args = {k: v for k, v in args.items() if v is not None} \
             if args else {}
-        if of is not None:
+        if callable(of):
+            self.args.update(of())
+        elif of is not None:
             self.args.update(_carried(of))
+        if "key" in self.args:
+            # the key's group of the KeyPlan ("expert" for a stacked
+            # expert leaf), as this node was told at the key's init
+            self.args["group"] = tracer.key_groups.get(
+                self.args["key"], DEFAULT_GROUP)
         self.dur_us = 0.0
 
     def __enter__(self):
@@ -137,6 +146,10 @@ class Tracer:
         self.dropped_events = 0
         self._cap = 100_000
         self._names: Dict[str, str] = {}
+        # key -> group, for the spans that carry ``key``: filled at a
+        # key's init (a worker's tensor ids and ps keys, a server's ps
+        # keys); read on the sampled path only
+        self.key_groups: Dict[int, str] = {}
 
     def annotation_name(self, name: str) -> str:
         full = self._names.get(name)
@@ -152,7 +165,9 @@ class Tracer:
         the site carries (``key``, ``nbytes``, ``queued_us``): plain
         values already at hand, because the call evaluates them with
         tracing off too; ``of`` is a ``Message`` or ``KVPairs`` whose
-        first key and bytes are read only when the span is recorded."""
+        first key and bytes are read only when the span is recorded, or
+        a callable that returns the arguments, called only then (what
+        costs a device-to-host read, like ``moe.route``'s counts)."""
         if not _ctx.ACTIVE:
             return _NULL_SPAN
         cur = _ctx.current()
@@ -221,6 +236,14 @@ class Tracer:
         self._po = postoffice
         self._collector = collector
         return self
+
+    def detach(self) -> None:
+        """Let go of the postoffice: this tracer lives in a registry
+        for as long as the process does, and through the postoffice it
+        would keep a stopped node's servers, and what they hold on the
+        device, alive with it."""
+        self._po = None
+        self._collector = None
 
     def flush(self) -> int:
         """Ship every pending span to the collector; returns the count.

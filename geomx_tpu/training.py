@@ -25,6 +25,7 @@ import jax
 import numpy as np
 
 from geomx_tpu.kvstore.client import WorkerKVStore
+from geomx_tpu.kvstore.keys import DENSE, leaf_groups
 
 
 def save_params(path: str, params) -> None:
@@ -290,6 +291,28 @@ def _edge_to_host(kv: WorkerKVStore, tid: int, g, scale: float,
         return np.asarray(g)
 
 
+def _route_args(route, tokens: int) -> dict:
+    """The arguments of a step's ``moe.route`` span from the counts the
+    gradient program returned (``parallel/moe.py`` ``routed_ffn``, a row
+    a routed layer), read to the host HERE: ``rows`` every row the held
+    experts' grouped products were given; ``max_over_mean`` the fullest
+    held expert's rows over the mean, in the worst layer;
+    ``empty_pct`` the share of the step's tokens none of whose experts
+    is held, mean over the layers; ``dropped`` the routed pairs whose
+    expert is held that reached no group: 0, or the layer is broken."""
+    rows = np.asarray(route["rows"], np.int64)
+    dropped = int(np.asarray(route["held_pairs"], np.int64).sum()
+                  - rows.sum())
+    assert dropped == 0, f"the routed layers dropped {dropped} rows"
+    return {
+        "rows": int(rows.sum()),
+        "max_over_mean": float(
+            (rows.max(axis=1) / np.maximum(rows.mean(axis=1), 1e-9)).max()),
+        "empty_pct": float(100.0 * np.mean(
+            np.asarray(route["empty_tokens"])) / tokens),
+        "dropped": dropped}
+
+
 def _exchange(kv: WorkerKVStore, tids: Sequence[int], leaves: list,
               on_pulled: Callable[[int, np.ndarray], None],
               scale: float = 1.0, divide: bool = False,
@@ -364,8 +387,10 @@ def run_worker(
     if opt is not None:
         import optax
     leaves, treedef = flatten_params(params)
+    groups = leaf_groups(params)
     for tid, leaf in enumerate(leaves):
-        kv.init(tid, leaf, barrier=barrier_init)
+        kv.init(tid, leaf, barrier=barrier_init,
+                group=groups[tid] if groups[tid] != DENSE else None)
     params = unflatten_params(treedef, leaves)
     opt_state = opt.init(params) if opt is not None else None
     history: List[Tuple[float, float]] = []
@@ -393,8 +418,11 @@ def run_worker(
             with m.phase("grad"):
                 ran, grads = [], None
                 while batch is not None:
-                    loss, acc, grads = grad_fn(params, *batch)
+                    # a model with routed experts hands back a fourth
+                    # value, its routers' counts: still on the device
+                    loss, acc, grads, *extra = grad_fn(params, *batch)
                     ran.append((loss, acc))
+                    fed = batch
                     if opt is not None:
                         updates, opt_state = opt.update(grads, opt_state,
                                                         params)
@@ -416,6 +444,12 @@ def run_worker(
                     # overlap.py — is the path that interleaves, not
                     # this one)
                     jax.block_until_ready(out)
+                    if extra and extra[0].get("moe_route") is not None:
+                        # the counts cross to the host only where the
+                        # span is recorded: in a sampled round
+                        with kv.trace_span("moe.route", of=lambda: _route_args(
+                                extra[0]["moe_route"], np.size(fed[0]))):
+                            pass
             grad_s = time.perf_counter() - t0
             if due:
                 # re-read per exchange: dynamic join/leave changes the

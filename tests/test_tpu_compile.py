@@ -140,3 +140,52 @@ def test_flash_kernels_for_the_v5e_keep_their_names_and_carry_their_tiles(
     assert (f"block_q_major_{bs.block_q_dq}_block_k_major_"
             f"{bs.block_k_major_dq}_block_k_{bs.block_k_dq}") in dq, dq
     assert bs.block_q_major_dkv > 128 and bs.block_q_dq > 128
+
+
+def test_the_routed_layer_for_the_v5e_keeps_its_kernels_names(one_chip):
+    """One chip's share of a routed layer at the LFM2 cut's shapes
+    (8,192 tokens of 2048, 8 of 64 experts 1536 wide, top 4) with the
+    megablox grouped products: the tiling of ``parallel/moe.py`` fits
+    the v5e's scoped VMEM (the compile would refuse it), the gradient
+    calls three products forward (again under the layer's checkpoint in
+    a whole model; here, with nothing between them, XLA merges the
+    two), three with the stacks transposed and three ``tgmm`` for the
+    stacks' gradients, and every one is found by
+    the pattern the benchmark's ``expert_gmm_roofline_pct`` reads the
+    trace by.  With its worst-case buffers recomputed, not kept, the
+    layer's temporaries stay under 1 GB (0.90 here), which a model pays
+    once, in the layer whose backward pass is running, not once a
+    layer."""
+    import json
+    from pathlib import Path
+
+    import jax
+    import jax.numpy as jnp
+
+    from benchmark.lib.trace import op_name
+    from geomx_tpu.parallel.moe import routed_ffn
+
+    def arg(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+
+    def loss(x, router, bias, experts):
+        y, route = routed_ffn(x, router, bias, experts, first=0, k=4,
+                              impl="gmm", compute_dtype=jnp.bfloat16)
+        return jnp.sum(y.astype(jnp.float32)), route
+
+    experts = {"w1": arg(8, 2048, 1536), "w3": arg(8, 2048, 1536),
+               "w2": arg(8, 1536, 2048)}
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 3), has_aux=True)).lower(
+        jax.ShapeDtypeStruct((1, 8192, 2048), jnp.bfloat16,
+                             sharding=one_chip),
+        arg(2048, 64), arg(64), experts).compile()
+    calls = [op_name(line.strip()) for line in compiled.as_text().splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    reader = json.loads((Path(__file__).parent.parent / "benchmark"
+                         / "layer_metrics" / "expert_gmm_roofline_pct.json"
+                         ).read_text())
+    (kernel,) = reader["kernels"]
+    assert len(calls) in (9, 12), calls
+    assert all(re.search(kernel["pattern"], c) for c in calls), calls
+    assert sum(c.startswith("tgmm") for c in calls) == 3
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.0e9
