@@ -313,8 +313,8 @@ def make_apply(cfg: TransformerConfig, mesh: Optional[Mesh] = None,
 
     ``return_route=True`` appends what the routed layers saw (None
     without any): int32 ``rows`` [routed layers, experts held],
-    ``held_pairs`` and ``empty_tokens`` [routed layers], as
-    ``parallel/moe.py`` ``routed_ffn`` counts them."""
+    ``held_pairs``, ``empty_tokens``, ``chunks`` and ``buffer_rows``
+    [routed layers], as ``parallel/moe.py`` ``routed_ffn`` counts them."""
     if cfg.moe_every > 0 and cfg.moe_top_k > 0 and not return_aux:
         import warnings
 

@@ -163,11 +163,16 @@ def moe_ffn_topk(
 # chips' tokens here and send the partial sums back is not simulated.
 #
 # Shapes are static: the (token, choice) pairs are sorted so that those
-# whose expert is held come first, by expert, in a buffer of tokens x k
-# rows (the worst case: every choice of every token held here).  The
-# three products run as grouped products over the held experts' row
-# groups and visit no row past the last group, so their time follows
-# the rows really routed here, not the buffer.
+# whose expert is held come first, by expert, and go through a buffer of
+# a static number of rows, twice what an even router sends
+# (``chunk_rows``), a chunk of the sorted pairs at a time: as many
+# chunks as the held pairs fill, counted in the program (one for the
+# router of an even deployment, tokens x k rows' worth in the worst
+# case: every choice of every token held here).  The three products run
+# as grouped products over the held experts' row groups and visit no
+# row past the last group, so their time follows the rows really routed
+# here; everything XLA does around them (the gathers, the masks, the
+# gate) runs over the whole buffer, hence a buffer sized by the load.
 
 # (tm, tk, tn) of jax's megablox kernels, by a sweep on the v5e at 8
 # groups of about 512 rows of 2048 x 1536 (PERF.md section 6, PR 35)
@@ -180,64 +185,102 @@ def _grouped(lhs, rhs, group_sizes, impl: str):
     [K, N].  ``ragged`` is ``lax.ragged_dot`` (any backend; XLA's own
     grouped product on a TPU); ``gmm`` jax's megablox Pallas kernels
     (TPU, or its interpreter), whose output rows past the last group are
-    uninitialised: the caller masks them."""
+    uninitialised: the caller masks them.  A trace names a kernel by the
+    scope it was called in, and a transformation traced around the call
+    wraps the innermost scope's name (``transpose(jvp(jit(gmm)))``): the
+    scope here takes that, and the kernels stay ``gmm`` and ``tgmm``
+    however the layer is differentiated."""
     if impl == "ragged":
         return lax.ragged_dot(lhs, rhs, group_sizes)
     if impl == "gmm":
         from jax.experimental.pallas.ops.tpu.megablox import ops
 
         tm = math.gcd(GMM_TILING[0], lhs.shape[0])
-        return ops.gmm(lhs, rhs, group_sizes, lhs.dtype,
-                       (tm,) + GMM_TILING[1:])
+        with jax.named_scope("grouped"):
+            return ops.gmm(lhs, rhs, group_sizes, lhs.dtype,
+                           (tm,) + GMM_TILING[1:])
     raise ValueError(f"unknown expert_impl {impl!r}")
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-def _to_sorted(x, order, inv, k: int):
-    """Tokens ``x`` [N, D] to their (token, choice) pairs' rows in
-    sorted order [N * k, D]: row m is the token of pair ``order[m]``.
-    The transpose sums, for each token, the k rows its pairs went to
-    (``inv``, the inverse of ``order``): gathers, where XLA would
+def chunk_rows(pairs: int, held: int, experts: int) -> int:
+    """The sorted buffer's rows: twice the ``pairs * held / experts``
+    rows an even router sends the ``held`` of ``experts`` experts, a
+    whole number of ``GMM_TILING`` row tiles, ``pairs`` (every choice of
+    every token) at most: all of them where every expert is held."""
+    tile = GMM_TILING[0]
+    return min(pairs, tile * math.ceil(2 * pairs * held / experts / tile))
+
+
+def _rows_of(inv, c: int, k: int, first, n_rows, buffer_rows: int):
+    """Where choice ``c`` of every token sits in the buffer that holds
+    the ``n_rows`` sorted pairs from pair ``first`` on, and whether it
+    is there at all: a pair of another chunk, or one whose expert is not
+    held (those sort behind every held pair), has its index clamped and
+    its term zeroed."""
+    at = inv[c::k] - first
+    return (jnp.clip(at, 0, buffer_rows - 1),
+            ((at >= 0) & (at < n_rows))[:, None])
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
+def _to_sorted(x, order, inv, first, n_rows, k: int):
+    """Tokens ``x`` [N, D] to the rows of their (token, choice) pairs in
+    sorted order [B, D]: row m is the token of pair ``order[m]``, the
+    B = ``len(order)`` sorted pairs from pair ``first`` on.  The
+    transpose sums, for each token, the rows its pairs went to (``inv``,
+    the inverse of the whole order): gathers, where XLA would
     scatter-add."""
     return x[order // k]
 
 
-def _to_sorted_fwd(x, order, inv, k):
-    return x[order // k], inv
+def _to_sorted_fwd(x, order, inv, first, n_rows, k):
+    return x[order // k], (inv, first, n_rows)
 
 
-def _to_sorted_bwd(k, inv, g):
-    return (sum(g[inv[c::k]].astype(jnp.float32) for c in range(k)
-                ).astype(g.dtype), None, None)
+def _to_sorted_bwd(k, res, g):
+    inv, first, n_rows = res
+    total = 0
+    for c in range(k):
+        at, there = _rows_of(inv, c, k, first, n_rows, g.shape[0])
+        total = total + jnp.where(there, g[at], 0).astype(jnp.float32)
+    return total.astype(g.dtype), None, None, None, None
 
 
 _to_sorted.defvjp(_to_sorted_fwd, _to_sorted_bwd)
 
 
 @jax.custom_vjp
-def _from_sorted(out, weights, order, inv):
-    """Sorted rows ``out`` [N * k, D] back to tokens [N, D] float32:
-    each token's k rows (choice c of token n is row ``inv[n * k + c]``)
-    summed with its ``weights`` [N, k].  Row by choice, never an
-    [N, k, D] array, whose short middle dimension costs a relayout."""
+def _from_sorted(out, weights, order, inv, first, n_rows):
+    """Sorted rows ``out`` [B, D] back to tokens [N, D] float32: each
+    token's rows in this buffer (choice c of token n is row ``inv[n * k
+    + c] - first``) summed with its ``weights`` [N, k].  Row by choice,
+    never an [N, k, D] array, whose short middle dimension costs a
+    relayout."""
     k = weights.shape[1]
-    return sum(out[inv[c::k]].astype(jnp.float32) * weights[:, c, None]
-               for c in range(k))
+    total = 0
+    for c in range(k):
+        at, there = _rows_of(inv, c, k, first, n_rows, out.shape[0])
+        total = total + (jnp.where(there, out[at], 0).astype(jnp.float32)
+                         * weights[:, c, None])
+    return total
 
 
-def _from_sorted_fwd(out, weights, order, inv):
-    return _from_sorted(out, weights, order, inv), (out, weights, order, inv)
+def _from_sorted_fwd(out, weights, order, inv, first, n_rows):
+    return (_from_sorted(out, weights, order, inv, first, n_rows),
+            (out, weights, order, inv, first, n_rows))
 
 
 def _from_sorted_bwd(res, dy):
-    out, weights, order, inv = res
+    out, weights, order, inv, first, n_rows = res
     k = weights.shape[1]
     d_out = (dy[order // k] * weights.reshape(-1)[order][:, None]
              ).astype(out.dtype)
-    d_weights = jnp.stack(
-        [jnp.sum(out[inv[c::k]].astype(jnp.float32) * dy, axis=-1)
-         for c in range(k)], axis=1)
-    return d_out, d_weights, None, None
+    d_weights = []
+    for c in range(k):
+        at, there = _rows_of(inv, c, k, first, n_rows, out.shape[0])
+        d_weights.append(jnp.sum(
+            jnp.where(there, out[at], 0).astype(jnp.float32) * dy, axis=-1))
+    return d_out, jnp.stack(d_weights, axis=1), None, None, None, None
 
 
 _from_sorted.defvjp(_from_sorted_fwd, _from_sorted_bwd)
@@ -263,13 +306,100 @@ def routed_ffn(x, router_w, bias, experts, first: int, k: int,
                scale: float = 1.0, impl: str = "ragged",
                compute_dtype=jnp.bfloat16):
     """:func:`_routed_ffn` under ``jax.checkpoint``: the backward pass
-    sorts, gathers and multiplies again rather than keep the layer's
-    buffers, which are sized for the worst case (tokens x k rows, eight
-    times the rows an even router sends an eighth of the experts): kept,
-    they are 0.6 GB a layer at 8,192 tokens, 2048 wide (PERF.md)."""
+    routes and sorts again rather than keep what the router computed
+    (the experts' own buffers are never kept: :func:`_experts`)."""
     fn = functools.partial(_routed_ffn, first=first, k=k, scale=scale,
                            impl=impl, compute_dtype=compute_dtype)
     return jax.checkpoint(fn)(x, router_w, bias, experts)
+
+
+def _chunk(c, x, weights, order, inv, rows, experts, *, buffer_rows: int,
+           k: int, impl: str):
+    """The held experts' part of the layer for chunk ``c`` of the sorted
+    pairs, pairs ``c * buffer_rows`` on, in a buffer of ``buffer_rows``
+    rows: tokens ``x`` [N, D] to the buffer, the three grouped products
+    over what the chunk holds of the groups ``rows``, and back to the
+    tokens [N, D] float32 with the router's ``weights``.  The products
+    leave the rows past the last group uninitialised (``gmm``): masked
+    going in and coming out."""
+    first = c * buffer_rows
+    ends = jnp.cumsum(rows)
+    mine = (jnp.clip(ends - first, 0, buffer_rows)
+            - jnp.clip(ends - rows - first, 0, buffer_rows))
+    n_rows = jnp.sum(mine)
+    order = lax.dynamic_slice(order, (first,), (buffer_rows,))
+    valid = (jnp.arange(buffer_rows) < n_rows)[:, None]
+    xs = jnp.where(valid, _to_sorted(x, order, inv, first, n_rows, k), 0)
+    gate = _grouped(xs, experts["w1"], mine, impl)
+    up = _grouped(xs, experts["w3"], mine, impl)
+    hidden = (jax.nn.silu(gate.astype(jnp.float32))
+              * up.astype(jnp.float32)).astype(x.dtype)
+    out = jnp.where(valid, _grouped(hidden, experts["w2"], mine, impl), 0)
+    return _from_sorted(out, weights, order, inv, first, n_rows)
+
+
+def _over_chunks(chunks, whole: bool, body, init):
+    """``init`` with ``body(c)`` added for every chunk ``c`` of
+    ``chunks``, a count made in the program: a loop of that many turns,
+    and one call where the buffer is the ``whole`` of the pairs."""
+    add = functools.partial(jax.tree_util.tree_map,
+                            lambda a, b: a + b.astype(a.dtype))
+    if whole:
+        return add(init, body(0))
+    return lax.while_loop(
+        lambda carry: carry[0] < chunks,
+        lambda carry: (carry[0] + 1, add(carry[1], body(carry[0]))),
+        (jnp.zeros((), jnp.int32), init))[1]
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2))
+def _experts(buffer_rows: int, k: int, impl: str, chunks, x, weights, order,
+             inv, rows, experts):
+    """The held experts' part of the layer, [N, D] float32: the sum of
+    :func:`_chunk` over the ``chunks`` chunks of ``buffer_rows`` sorted
+    pairs that hold a held pair.  Differentiated by hand: a loop whose
+    length the program counts has no transpose, and the buffers are not
+    to be kept; the backward pass is a loop of its own in which every
+    chunk computes its buffer again and transposes it, and what is kept
+    is what came in."""
+    stacks = {n: w.astype(x.dtype) for n, w in experts.items()}
+    return _over_chunks(
+        chunks, order.shape[0] == buffer_rows,
+        lambda c: _chunk(c, x, weights, order, inv, rows, stacks,
+                         buffer_rows=buffer_rows, k=k, impl=impl),
+        jnp.zeros(x.shape, jnp.float32))
+
+
+def _experts_fwd(buffer_rows, k, impl, chunks, x, weights, order, inv, rows,
+                 experts):
+    return (_experts(buffer_rows, k, impl, chunks, x, weights, order, inv,
+                     rows, experts),
+            (chunks, x, weights, order, inv, rows, experts))
+
+
+def _experts_bwd(buffer_rows, k, impl, res, dy):
+    chunks, x, weights, order, inv, rows, experts = res
+    stacks = {n: w.astype(x.dtype) for n, w in experts.items()}
+
+    def body(c):
+        _, pull = jax.vjp(
+            lambda x, weights, experts: _chunk(
+                c, x, weights, order, inv, rows, experts,
+                buffer_rows=buffer_rows, k=k, impl=impl),
+            x, weights, stacks)
+        return pull(dy)
+
+    # summed in float32 whatever the compute dtype, as one chunk sums
+    dx, dweights, dexperts = _over_chunks(
+        chunks, order.shape[0] == buffer_rows, body, jax.tree_util.tree_map(
+            lambda a: jnp.zeros(a.shape, jnp.float32),
+            (x, weights, experts)))
+    return (None, dx.astype(x.dtype), dweights, None, None, None,
+            jax.tree_util.tree_map(lambda d, w: d.astype(w.dtype), dexperts,
+                                   experts))
+
+
+_experts.defvjp(_experts_fwd, _experts_bwd)
 
 
 def _routed_ffn(x, router_w, bias, experts, first: int, k: int,
@@ -287,7 +417,15 @@ def _routed_ffn(x, router_w, bias, experts, first: int, k: int,
     ``held_pairs`` the (token, choice) pairs whose expert is held
     (counted from the router's choice, not from the groups: their
     difference is what was dropped, 0), ``empty_tokens`` the tokens none
-    of whose experts is held."""
+    of whose experts is held, ``chunks`` the chunks the held pairs went
+    through the sorted buffer in and ``buffer_rows`` the buffer's rows
+    over all of them (:func:`chunk_rows` each).
+
+    The buffer is sized by what the router sent: the held pairs go
+    through it a chunk at a time, in as many chunks as they fill,
+    counted in the program.  Tokens x k rows' worth of chunks take the
+    worst case, so no load is refused; the router of an even deployment
+    fills half of one."""
     cd = compute_dtype
     lead, D = x.shape[:-1], x.shape[-1]
     x = x.reshape(-1, D)
@@ -303,19 +441,21 @@ def _routed_ffn(x, router_w, bias, experts, first: int, k: int,
         jnp.arange(order.shape[0], dtype=jnp.int32))
     rows = jnp.sum(key[:, None] == jnp.arange(E, dtype=key.dtype)[None],
                    axis=0, dtype=jnp.int32)
-    valid = (jnp.arange(N * k) < jnp.sum(rows))[:, None]
 
-    xs = jnp.where(valid, _to_sorted(x.astype(cd), order, inv, k), 0)
-    gate = _grouped(xs, experts["w1"].astype(cd), rows, impl)
-    up = _grouped(xs, experts["w3"].astype(cd), rows, impl)
-    hidden = (jax.nn.silu(gate.astype(jnp.float32))
-              * up.astype(jnp.float32)).astype(cd)
-    out = jnp.where(valid, _grouped(hidden, experts["w2"].astype(cd), rows,
-                                    impl), 0)
-    # back to the tokens; a pair not held lands on a zero row
-    y = _from_sorted(out, weights, order, inv)
+    buffer_rows = chunk_rows(N * k, E, router_w.shape[1])
+    if buffer_rows == N * k:
+        chunks = jnp.ones((), jnp.int32)
+    else:
+        chunks = -(-jnp.sum(rows) // buffer_rows)
+        # whole chunks to slice, and more rows than one (what tells
+        # ``_experts`` that there is a loop): the last chunk may reach
+        # past the pairs
+        order = jnp.pad(order, (0, buffer_rows - (N * k) % buffer_rows))
+    y = _experts(buffer_rows, k, impl, chunks, x.astype(cd), weights, order,
+                 inv, rows, experts)
     route = {"rows": rows,
              "held_pairs": jnp.sum(held, dtype=jnp.int32),
              "empty_tokens": jnp.sum(~jnp.any(held, axis=-1),
-                                     dtype=jnp.int32)}
+                                     dtype=jnp.int32),
+             "chunks": chunks, "buffer_rows": chunks * buffer_rows}
     return y.astype(cd).reshape(*lead, D), route

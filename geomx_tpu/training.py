@@ -299,13 +299,22 @@ def _route_args(route, tokens: int) -> dict:
     held expert's rows over the mean, in the worst layer;
     ``empty_pct`` the share of the step's tokens none of whose experts
     is held, mean over the layers; ``dropped`` the routed pairs whose
-    expert is held that reached no group: 0, or the layer is broken."""
+    expert is held that reached no group: 0, or the layer is broken;
+    ``buffer_rows`` the rows of the layers' sorted buffers over all the
+    chunks their held pairs took (``moe.chunk_rows`` a chunk),
+    ``fill_pct`` the share of them that ``rows`` filled, and
+    ``chunks_max`` the most chunks a layer took (1: every layer's load
+    fitted one buffer)."""
     rows = np.asarray(route["rows"], np.int64)
     dropped = int(np.asarray(route["held_pairs"], np.int64).sum()
                   - rows.sum())
     assert dropped == 0, f"the routed layers dropped {dropped} rows"
+    buffer_rows = int(np.asarray(route["buffer_rows"], np.int64).sum())
     return {
         "rows": int(rows.sum()),
+        "buffer_rows": buffer_rows,
+        "fill_pct": float(100.0 * rows.sum() / max(buffer_rows, 1)),
+        "chunks_max": int(np.max(route["chunks"])),
         "max_over_mean": float(
             (rows.max(axis=1) / np.maximum(rows.mean(axis=1), 1e-9)).max()),
         "empty_pct": float(100.0 * np.mean(

@@ -202,6 +202,96 @@ def test_no_token_is_dropped_when_every_token_routes_to_one_held_expert():
         assert not np.any(np.asarray(g[n][4]))       # an idle expert
 
 
+# 512 tokens, top 4 of 64, 8 held: an even router sends 256 of the 2,048
+# pairs, and the sorted buffer is 512 rows, a chunk of the pairs at a time
+LOADS = {
+    "even router: half a buffer": (lambda b: b, 1),
+    # test_no_token_is_dropped's router: every token's first choice is
+    # expert 3 and its others are held elsewhere, 512 pairs: one buffer
+    # filled to its last row
+    "one crowded expert: one buffer, full": (
+        lambda b: b.at[jnp.array([3, 40, 41, 42])].add(10.0), 1),
+    "held experts favoured: two chunks": (lambda b: b.at[:8].add(0.15), 2),
+    "held experts favoured more: three chunks": (lambda b: b.at[:8].add(0.3),
+                                                 3),
+    # every choice of every token is held: the worst case, four full
+    # chunks, tokens x k rows in all
+    "every pair held: four chunks, full": (
+        lambda b: b.at[jnp.array([3, 4, 5, 6])].add(10.0), 4),
+}
+
+
+@pytest.mark.parametrize("case", sorted(LOADS))
+def test_however_many_chunks_the_load_takes_the_layer_is_the_references(case):
+    """The held pairs go through the sorted buffer in as many chunks as
+    they fill (``buffer_rows`` says how many rows that was), a group of
+    rows split wherever a chunk ends, and however many it takes the
+    layer is the reference's: its result and the gradient of every leaf
+    (tokens, router, the three stacks; the bias has none), with no pair
+    dropped."""
+    from geomx_tpu.parallel.moe import chunk_rows
+
+    bias, chunks = LOADS[case]
+    layer, _ = _routed_layer(1)
+    h = jax.random.normal(jax.random.PRNGKey(11), (2, 256, 16))
+    held = {"router": layer["router"],
+            "expert_bias": bias(layer["expert_bias"]),
+            "experts": {n: w[:8] for n, w in layer["experts"].items()}}
+    assert chunk_rows(2 * 256 * 4, 8, 64) == 512
+
+    def program(l, h):
+        y, route = routed_ffn(h, l["router"], l["expert_bias"], l["experts"],
+                              first=0, k=4, compute_dtype=jnp.float32)
+        return jnp.sum(y ** 2), (y, route)
+
+    def plain(l, h):
+        y = reference.expert_share(l, h, first=0)
+        return jnp.sum(y ** 2), y
+
+    (_, (y, route)), g = jax.jit(jax.value_and_grad(
+        program, argnums=(0, 1), has_aux=True))(held, h)
+    (_, y_ref), g_ref = jax.value_and_grad(
+        plain, argnums=(0, 1), has_aux=True)(held, h)
+    assert (int(route["chunks"]), int(route["buffer_rows"])) == (
+        chunks, 512 * chunks)
+    pairs = int(route["held_pairs"])
+    assert 512 * (chunks - 1) < pairs <= 512 * chunks
+    assert int(route["rows"].sum()) == pairs            # none dropped
+    if "full" in case:
+        assert pairs == 512 * chunks
+    np.testing.assert_allclose(y, y_ref, rtol=1e-5, atol=1e-6)
+    assert len(jax.tree_util.tree_leaves(g)) == 6
+    for (path, a), r in zip(jax.tree_util.tree_leaves_with_path(g),
+                            jax.tree_util.tree_leaves(g_ref)):
+        np.testing.assert_allclose(a, r, rtol=1e-4, atol=1e-5,
+                                   err_msg=jax.tree_util.keystr(path))
+    assert not np.any(np.asarray(g[0]["expert_bias"]))
+
+
+def test_a_chip_that_holds_every_expert_has_one_buffer_and_no_loop():
+    """Where the stacks are the whole layer the worst case is the
+    expected case: one chunk of tokens x k rows, and the lowered
+    gradient holds no loop over chunks; one chip's share of eight has
+    one a pass (the sort brings its own loops, in both)."""
+    from geomx_tpu.parallel.moe import chunk_rows
+
+    layer, h = _routed_layer(0)
+
+    def lowered(held, h):
+        return jax.jit(jax.grad(lambda e: jnp.sum(_share(
+            {**layer, "experts": e}, h, 0, held)[0] ** 2))).lower(
+            {n: w[:held] for n, w in layer["experts"].items()}).as_text()
+
+    assert chunk_rows(2 * 24 * 4, 64, 64) == 192
+    tall = jnp.tile(h, (1, 16, 1))                       # 768 tokens
+    assert chunk_rows(2 * 384 * 4, 8, 64) == 768
+    whole, share = lowered(64, h), lowered(8, tall)
+    assert (share.count("stablehlo.while") - whole.count("stablehlo.while")
+            == 2)                                        # forward, backward
+    _, route = _share(layer, tall, 0, 64)
+    assert int(route["chunks"]) == 1 and int(route["buffer_rows"]) == 3072
+
+
 def test_the_megablox_path_is_the_ragged_path():
     """``expert_impl="gmm"``, what the chip runs, under the TPU
     interpreter against ``lax.ragged_dot``, forward and the stacks'
@@ -443,3 +533,8 @@ def test_moe_route_is_recorded_in_the_sampled_round_only(trained):
         assert 0 < a["rows"] <= 4 * 64 * 4      # 4 layers x 64 tokens x 4
         assert a["max_over_mean"] >= 1.0
         assert 0.0 <= a["empty_pct"] <= 100.0
+        # 64 tokens x 4 choices, 4 of 16 held: 128 rows would hold twice
+        # an even router's load, less than one row tile of 256, so the
+        # buffer is the 256 rows of all the pairs, one chunk a layer
+        assert a["buffer_rows"] == 4 * 256 and a["chunks_max"] == 1
+        assert a["fill_pct"] == pytest.approx(100.0 * a["rows"] / (4 * 256))

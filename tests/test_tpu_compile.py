@@ -146,16 +146,20 @@ def test_the_routed_layer_for_the_v5e_keeps_its_kernels_names(one_chip):
     """One chip's share of a routed layer at the LFM2 cut's shapes
     (8,192 tokens of 2048, 8 of 64 experts 1536 wide, top 4) with the
     megablox grouped products: the tiling of ``parallel/moe.py`` fits
-    the v5e's scoped VMEM (the compile would refuse it), the gradient
-    calls three products forward (again under the layer's checkpoint in
-    a whole model; here, with nothing between them, XLA merges the
-    two), three with the stacks transposed and three ``tgmm`` for the
-    stacks' gradients, and every one is found by
-    the pattern the benchmark's ``expert_gmm_roofline_pct`` reads the
-    trace by.  With its worst-case buffers recomputed, not kept, the
-    layer's temporaries stay under 1 GB (0.90 here), which a model pays
-    once, in the layer whose backward pass is running, not once a
-    layer."""
+    the v5e's scoped VMEM (the compile would refuse it).  The sorted
+    buffer is 8,192 rows, twice an even router's 4,096, and the held
+    pairs go through it in a loop of as many turns as they fill: no
+    array of the worst case's 32,768 rows is left anywhere in the
+    program (a later edit that returns to one buffer for every load
+    fails here, not on a ledger line), and the loop's body holds the
+    layer once: three products forward (the forward pass's own loop has
+    nothing to give the gradient of a sum and is gone here; a whole
+    model keeps both), three with the stacks transposed and three
+    ``tgmm`` for the stacks' gradients, every one found by the pattern
+    the benchmark's ``expert_gmm_roofline_pct`` reads the trace by.  No
+    buffer is kept for the backward pass, so the layer's temporaries
+    stay under 1 GB (0.75 here), which a model pays once, in the layer
+    whose backward pass is running, not once a layer."""
     import json
     from pathlib import Path
 
@@ -163,7 +167,7 @@ def test_the_routed_layer_for_the_v5e_keeps_its_kernels_names(one_chip):
     import jax.numpy as jnp
 
     from benchmark.lib.trace import op_name
-    from geomx_tpu.parallel.moe import routed_ffn
+    from geomx_tpu.parallel.moe import chunk_rows, routed_ffn
 
     def arg(*shape):
         return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
@@ -179,13 +183,19 @@ def test_the_routed_layer_for_the_v5e_keeps_its_kernels_names(one_chip):
         jax.ShapeDtypeStruct((1, 8192, 2048), jnp.bfloat16,
                              sharding=one_chip),
         arg(2048, 64), arg(64), experts).compile()
-    calls = [op_name(line.strip()) for line in compiled.as_text().splitlines()
+    hlo = compiled.as_text()
+    calls = [op_name(line.strip()) for line in hlo.splitlines()
              if 'custom_call_target="tpu_custom_call"' in line]
     reader = json.loads((Path(__file__).parent.parent / "benchmark"
                          / "layer_metrics" / "expert_gmm_roofline_pct.json"
                          ).read_text())
     (kernel,) = reader["kernels"]
-    assert len(calls) in (9, 12), calls
+    assert len(calls) == 9, calls
     assert all(re.search(kernel["pattern"], c) for c in calls), calls
     assert sum(c.startswith("tgmm") for c in calls) == 3
+    assert chunk_rows(8192 * 4, 8, 64) == 8192
+    # the products are over the buffer's rows, and nothing as wide as the
+    # model or an expert is as long as the pairs
+    assert sum(c.startswith("gmm") and "[8192," in c for c in calls) == 6
+    assert not re.search(r"\[32768,(1536|2048)\]", hlo)
     assert compiled.memory_analysis().temp_size_in_bytes < 1.0e9
