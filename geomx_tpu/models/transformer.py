@@ -24,9 +24,14 @@ the flagship, whose program does not change): grouped-query heads
 (``n_kv_heads``), RMSNorm on q and k (``qk_norm``), rotary positions
 (``rope_theta``), a gated FFN (``gated_ffn``), a per-layer operator
 pattern (``layer_types``: ``full_attention`` or the gated short
-convolution ``conv``), and after ``n_dense_layers`` leading dense
-layers one chip's share of routed experts with no capacity and no
-dropped token (``router_experts``; ``parallel/moe.py`` ``routed_ffn``).
+convolution ``conv``, the gated delta-rule scan ``kda`` of
+``ops/kda.py``, or latent attention ``mla``: keys and values through a
+low-rank latent, wider q/k heads than v heads), no positions at all
+(``no_positions``), an untied head (``tied_head=False``), and after
+``n_dense_layers`` leading dense layers one chip's share of routed
+experts with no capacity and no dropped token (``router_experts``;
+``parallel/moe.py`` ``routed_ffn``), with shared experts that every
+token passes added to them (``n_shared_experts``).
 
 Pure-jax functional style: ``init_params`` builds a pytree,
 ``param_specs`` mirrors it with PartitionSpecs, ``make_apply`` returns the
@@ -90,9 +95,10 @@ class TransformerConfig:
     #                          ``pos`` table
     norm_eps: float = 1e-6
     gated_ffn: bool = False  # w2(silu(w1 x) * w3 x), not w2 gelu(w1 x)
-    layer_types: Tuple[str, ...] = ()   # per layer "full_attention" or
-    #                          "conv" (gated short convolution); empty =
-    #                          attention everywhere
+    layer_types: Tuple[str, ...] = ()   # per layer "full_attention",
+    #                          "conv" (gated short convolution), "kda"
+    #                          (gated delta-rule scan) or "mla" (latent
+    #                          attention); empty = attention everywhere
     conv_kernel: int = 3     # taps of the short convolution
     n_dense_layers: int = 0  # leading layers that keep the dense FFN
     router_experts: int = 0  # > 0: every later layer routes over this
@@ -105,14 +111,52 @@ class TransformerConfig:
     expert_impl: str = "ragged"   # the grouped products: "ragged"
     #                          (lax.ragged_dot, any backend) or "gmm"
     #                          (jax's megablox kernels, TPU only)
+    n_shared_experts: int = 0     # experts every token passes, added to
+    #                          a routed layer's part, d_expert wide each
+    router_grad: bool = True      # False: a routed layer's routing
+    #                          weights are constants of the backward
+    #                          pass (``routed_ffn``): a share's part of
+    #                          the router's gradient pulls the load onto
+    #                          the experts it holds
+    no_positions: bool = False    # neither a learned table nor a
+    #                          rotation: the order is the scan's and the
+    #                          causal mask's
+    tied_head: bool = True   # logits = final norm @ embed^T; False: a
+    #                          leaf ``head`` of its own
+    # "kda" layers: q, k and v heads of kda_head_dim channels through a
+    # depthwise causal convolution of kda_conv_kernel taps and silu, the
+    # scan of ops/kda.py in chunks of kda_chunk positions
+    kda_heads: int = 0
+    kda_head_dim: int = 0
+    kda_conv_kernel: int = 4
+    kda_chunk: int = 64
+    # "mla" layers: n_heads q/k heads of qk_nope_dim + qk_rope_dim
+    # channels (the latter one key shared by every head, straight from
+    # the input; never rotated here) against v heads of v_head_dim, k
+    # and v from a normed latent of kv_lora_rank
+    kv_lora_rank: int = 0
+    qk_nope_dim: int = 0
+    qk_rope_dim: int = 0
+    v_head_dim: int = 0
 
     def __post_init__(self):
         if self.layer_types and len(self.layer_types) != self.n_layers:
             raise ValueError(f"layer_types names {len(self.layer_types)} "
                              f"layers, n_layers is {self.n_layers}")
-        unknown = set(self.layer_types) - {"full_attention", "conv"}
+        unknown = set(self.layer_types) - {"full_attention", "conv", "kda",
+                                           "mla"}
         if unknown:
             raise ValueError(f"unknown layer type(s) {sorted(unknown)}")
+        if "kda" in self.layer_types and not (
+                self.kda_heads > 0 and self.kda_head_dim > 0):
+            raise ValueError("a kda layer needs kda_heads and kda_head_dim")
+        if "mla" in self.layer_types and not (
+                self.kv_lora_rank > 0 and self.qk_nope_dim > 0
+                and self.v_head_dim > 0):
+            raise ValueError("an mla layer needs kv_lora_rank, qk_nope_dim "
+                             "and v_head_dim")
+        if self.no_positions and self.rope_theta:
+            raise ValueError("no_positions and rope_theta exclude each other")
         if self.n_heads % self.kv_heads:
             raise ValueError(f"{self.n_heads} q heads do not divide over "
                              f"{self.kv_heads} k/v heads")
@@ -142,7 +186,11 @@ class TransformerConfig:
         return self.router_experts > 0 and layer >= self.n_dense_layers
 
     def is_conv(self, layer: int) -> bool:
-        return bool(self.layer_types) and self.layer_types[layer] == "conv"
+        return self.kind(layer) == "conv"
+
+    def kind(self, layer: int) -> str:
+        return (self.layer_types[layer] if self.layer_types
+                else "full_attention")
 
 
 def init_params(cfg: TransformerConfig, rng: jax.Array) -> Dict:
@@ -157,8 +205,11 @@ def init_params(cfg: TransformerConfig, rng: jax.Array) -> Dict:
         "ln_f": jnp.ones((cfg.d_model,), jnp.float32),
         "layers": [],
     }
-    if cfg.rope_theta:
+    if cfg.rope_theta or cfg.no_positions:
         del params["pos"]
+    if not cfg.tied_head:
+        params["head"] = dense(keys[2], (cfg.vocab, cfg.d_model),
+                               scale=1.0 / np.sqrt(cfg.d_model))
     H, Hkv, Dh, D, F = (cfg.n_heads, cfg.kv_heads, cfg.head_dim,
                         cfg.d_model, cfg.d_ff)
     for i in range(cfg.n_layers):
@@ -172,6 +223,10 @@ def init_params(cfg: TransformerConfig, rng: jax.Array) -> Dict:
             layer["conv"] = dense(k[1], (D, cfg.conv_kernel),
                                   scale=1.0 / np.sqrt(cfg.conv_kernel))
             layer["w_out"] = dense(k[3], (D, D))
+        elif cfg.kind(i) == "kda":
+            layer.update(_init_kda(cfg, k[0], dense))
+        elif cfg.kind(i) == "mla":
+            layer.update(_init_mla(cfg, k[0], dense))
         else:
             layer["wq"] = dense(k[0], (D, H, Dh))
             layer["wk"] = dense(k[1], (D, Hkv, Dh))
@@ -204,6 +259,12 @@ def init_params(cfg: TransformerConfig, rng: jax.Array) -> Dict:
                 "w3": dense(ke[1], (E, D, Fe), scale=1.0 / np.sqrt(D)),
                 "w2": dense(ke[2], (E, Fe, D), scale=1.0 / np.sqrt(Fe)),
             }
+            if cfg.n_shared_experts:
+                Fs = cfg.n_shared_experts * Fe
+                ks = jax.random.split(k[5], 3)
+                layer["shared"] = {
+                    "w1": dense(ks[0], (D, Fs)), "w3": dense(ks[1], (D, Fs)),
+                    "w2": dense(ks[2], (Fs, D))}
         else:
             layer["w1"] = dense(k[4], (D, F))
             layer["w2"] = dense(k[5], (F, D), scale=1.0 / np.sqrt(F))
@@ -211,6 +272,45 @@ def init_params(cfg: TransformerConfig, rng: jax.Array) -> Dict:
                 layer["w3"] = dense(k[7], (D, F))
         params["layers"].append(layer)
     return params
+
+
+def _init_kda(cfg: TransformerConfig, key, dense) -> Dict:
+    """A ``kda`` layer's leaves.  The decay's are the family's and not
+    all near 1: ``A_log`` = log U(1, 16) a head, ``dt_bias`` the inverse
+    softplus of a step drawn log-uniformly in [0.001, 0.1] a channel."""
+    D, H, K, taps = (cfg.d_model, cfg.kda_heads, cfg.kda_head_dim,
+                     cfg.kda_conv_kernel)
+    k = jax.random.split(key, 14)
+    dt = jnp.exp(jax.random.uniform(
+        k[12], (H, K), jnp.float32, np.log(1e-3), np.log(1e-1)))
+    layer = {
+        "A_log": jnp.log(jax.random.uniform(k[11], (H,), jnp.float32,
+                                            1.0, 16.0)),
+        "dt_bias": dt + jnp.log(-jnp.expm1(-dt)),
+        "f_a": dense(k[6], (D, K)), "f_b": dense(k[7], (K, H, K)),
+        "w_beta": dense(k[8], (D, H)),
+        "g_a": dense(k[9], (D, K)), "g_b": dense(k[10], (K, H, K)),
+        "o_norm": jnp.ones((K,), jnp.float32),
+        "wo": dense(k[13], (H, K, D), scale=1.0 / np.sqrt(H * K)),
+    }
+    for j, n in enumerate("qkv"):
+        layer["w" + n] = dense(k[j], (D, H, K))
+        layer["conv_" + n] = dense(k[3 + j], (H, K, taps),
+                                   scale=1.0 / np.sqrt(taps))
+    return layer
+
+
+def _init_mla(cfg: TransformerConfig, key, dense) -> Dict:
+    D, H, R = cfg.d_model, cfg.n_heads, cfg.kv_lora_rank
+    k = jax.random.split(key, 4)
+    return {
+        "wq": dense(k[0], (D, H, cfg.qk_nope_dim + cfg.qk_rope_dim)),
+        "w_kv_a": dense(k[1], (D, R + cfg.qk_rope_dim)),
+        "kv_norm": jnp.ones((R,), jnp.float32),
+        "w_kv_b": dense(k[2], (R, H, cfg.qk_nope_dim + cfg.v_head_dim)),
+        "wo": dense(k[3], (H, cfg.v_head_dim, D),
+                    scale=1.0 / np.sqrt(H * cfg.v_head_dim)),
+    }
 
 
 def param_specs(cfg: TransformerConfig) -> Dict:
@@ -231,14 +331,24 @@ def param_specs(cfg: TransformerConfig) -> Dict:
         "ln_f": P(None),
         "layers": [],
     }
-    if cfg.rope_theta:
+    if cfg.rope_theta or cfg.no_positions:
         del specs["pos"]
+    if not cfg.tied_head:
+        specs["head"] = P("tp", None)
+    whole = lambda leaf: P(*(None,) * leaf.ndim)      # noqa: E731
     for i in range(cfg.n_layers):
         layer = {"ln1": P(None), "ln2": P(None)}
         if cfg.is_conv(i):
             # the depthwise taps follow their channels: not split yet
             layer.update(w_in=P(None, None), conv=P(None, None),
                          w_out=P(None, None))
+        elif cfg.kind(i) in ("kda", "mla"):
+            # not split yet: whole on every device
+            init = _init_kda if cfg.kind(i) == "kda" else _init_mla
+            layer.update(jax.tree_util.tree_map(whole, jax.eval_shape(
+                lambda: init(cfg, jax.random.PRNGKey(0),
+                             lambda key, shape, scale=None:
+                             jnp.zeros(shape, jnp.float32)))))
         else:
             layer.update(wq=P(None, "tp", None), wk=P(None, "tp", None),
                          wv=P(None, "tp", None), wo=P("tp", None, None))
@@ -254,6 +364,9 @@ def param_specs(cfg: TransformerConfig) -> Dict:
             layer["expert_bias"] = P(None)
             layer["experts"] = {n: P(None, None, None)
                                 for n in ("w1", "w3", "w2")}
+            if cfg.n_shared_experts:
+                layer["shared"] = {"w1": P(None, "tp"), "w3": P(None, "tp"),
+                                   "w2": P("tp", None)}
         else:
             layer["w1"] = P(None, "tp")
             layer["w2"] = P("tp", None)
@@ -314,7 +427,9 @@ def make_apply(cfg: TransformerConfig, mesh: Optional[Mesh] = None,
     ``return_route=True`` appends what the routed layers saw (None
     without any): int32 ``rows`` [routed layers, experts held],
     ``held_pairs``, ``empty_tokens``, ``chunks`` and ``buffer_rows``
-    [routed layers], as ``parallel/moe.py`` ``routed_ffn`` counts them."""
+    [routed layers], as ``parallel/moe.py`` ``routed_ffn`` counts them;
+    and, for a model with ``kda`` layers, what their scans saw, a row a
+    layer (``ops/kda.py`` ``chunk_kda``'s ``stats``)."""
     if cfg.moe_every > 0 and cfg.moe_top_k > 0 and not return_aux:
         import warnings
 
@@ -358,7 +473,7 @@ def make_apply(cfg: TransformerConfig, mesh: Optional[Mesh] = None,
         cd = cfg.compute_dtype
         B, T = tokens.shape
         x = params["embed"][tokens].astype(cd)
-        if not cfg.rope_theta:
+        if not (cfg.rope_theta or cfg.no_positions):
             x = x + params["pos"][:T][None].astype(cd)
         shard = None
         if use_ring:
@@ -370,19 +485,25 @@ def make_apply(cfg: TransformerConfig, mesh: Optional[Mesh] = None,
         if cfg.remat:
             layer_fn = jax.checkpoint(layer_fn, static_argnums=(2,))
         aux_total = jnp.zeros((), jnp.float32)
-        routes = []
+        routes, scans = [], []
         for i, layer in enumerate(params["layers"]):
-            x, aux, route = layer_fn(layer, x, i)
+            x, aux, route, scan = layer_fn(layer, x, i)
             aux_total = aux_total + aux
             if route is not None:
                 routes.append(route)
+            if scan is not None:
+                scans.append(scan)
         x = _rms_norm(x, params["ln_f"], cfg.norm_eps)
-        logits = jnp.einsum("btd,vd->btv", x, params["embed"].astype(cd))
+        head = params["embed" if cfg.tied_head else "head"]
+        logits = jnp.einsum("btd,vd->btv", x, head.astype(cd))
         logits = logits.astype(jnp.float32)
         out = (logits, aux_total) if return_aux else (logits,)
         if return_route:
-            out += (jax.tree_util.tree_map(lambda *a: jnp.stack(a), *routes)
-                    if routes else None,)
+            stack = lambda rows: jax.tree_util.tree_map(    # noqa: E731
+                lambda *a: jnp.stack(a), *rows) if rows else None
+            out += (stack(routes),)
+            if scans:
+                out += (stack(scans),)
         return out if len(out) > 1 else logits
 
     return apply
@@ -438,11 +559,21 @@ def _single_device_attention(cfg: TransformerConfig, q, k, v):
             flash_attention)
 
         sm = float(1.0 / np.sqrt(q.shape[-1]))
+        dv = v.shape[-1]
+        if q.shape[-1] != dv:
+            # the kernels take one head width, a multiple of 128 past
+            # 128: q/k heads wider than v's (latent attention's 192
+            # against 128) all go in padded with zeros to the next such
+            # width, which adds nothing to q k^T and zero channels to
+            # the output, cut off again
+            wide = -(-max(q.shape[-1], dv) // 128) * 128
+            q, k, v = (jnp.pad(a, ((0, 0),) * 3 + ((0, wide - a.shape[-1]),))
+                       for a in (q, k, v))
         o = flash_attention(
             q.swapaxes(1, 2), k.swapaxes(1, 2), v.swapaxes(1, 2),
             causal=True, sm_scale=sm,
             block_sizes=_flash_block_sizes(q.shape[1], q.shape[-1]))
-        return o.swapaxes(1, 2)
+        return o.swapaxes(1, 2)[..., :dv]
     raise ValueError(f"unknown attn_impl {cfg.attn_impl!r}")
 
 
@@ -473,17 +604,90 @@ def _attention(cfg: TransformerConfig, layer, h, attn_op, shard=None):
     return jnp.einsum("bthk,hkd->btd", a, layer["wo"].astype(cd))
 
 
+def _latent_attention(cfg: TransformerConfig, layer, h, attn_op):
+    """Causal attention whose keys and values come through a low-rank
+    latent: ``[c, k_r] = split(w_kv_a h)``, ``[k_n, v] = split(w_kv_b
+    rms(c))`` a head, ``k = [k_n, k_r]`` with ``k_r`` the same for every
+    head.  q and k heads are ``qk_nope_dim + qk_rope_dim`` wide, v heads
+    ``v_head_dim``; the softmax scale is the q/k width's.  The ``rope``
+    channels are not rotated (no positions)."""
+    cd = cfg.compute_dtype
+    R, Dn = cfg.kv_lora_rank, cfg.qk_nope_dim
+    q = jnp.einsum("btd,dhk->bthk", h, layer["wq"].astype(cd))
+    kv_a = jnp.einsum("btd,dr->btr", h, layer["w_kv_a"].astype(cd))
+    c = _rms_norm(kv_a[..., :R], layer["kv_norm"], cfg.norm_eps)
+    kv = jnp.einsum("btr,rhk->bthk", c, layer["w_kv_b"].astype(cd))
+    k_r = jnp.broadcast_to(kv_a[:, :, None, R:],
+                           kv.shape[:3] + (cfg.qk_rope_dim,))
+    k = jnp.concatenate([kv[..., :Dn], k_r], axis=-1)
+    a = attn_op(q, k, kv[..., Dn:])
+    return jnp.einsum("bthk,hkd->btd", a, layer["wo"].astype(cd))
+
+
+def _conv_silu(x, taps):
+    """``silu`` of the depthwise causal convolution of ``x`` [B, T, H,
+    K] over time with ``taps`` [H, K, taps] (zeros left of the sequence,
+    no bias), float32."""
+    T, n = x.shape[1], taps.shape[-1]
+    u = jnp.pad(x.astype(jnp.float32), ((0, 0), (n - 1, 0), (0, 0), (0, 0)))
+    taps = taps.astype(jnp.float32)
+    return jax.nn.silu(sum(u[:, j:j + T] * taps[..., j] for j in range(n)))
+
+
+def _unit(x):
+    return x * lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True) + 1e-6)
+
+
+def _kda(cfg: TransformerConfig, layer, h):
+    """The gated delta-rule mixer: q, k, v through a short convolution
+    and silu, q and k to unit length a head (q times head size ^ -1/2),
+    a per-channel log decay ``-exp(A_log) softplus(f_b f_a h +
+    dt_bias)`` and a step ``sigmoid(w_beta h)``, both float32, the scan
+    (``ops/kda.py``), then RMSNorm over each head's channels, a sigmoid
+    output gate through a low-rank pair, and ``wo``.  Returns ``(y,
+    scan)``, the scan's counts for the tracer."""
+    from geomx_tpu.ops.kda import chunk_kda
+
+    cd = cfg.compute_dtype
+    q, k, v = (_conv_silu(
+        jnp.einsum("btd,dhk->bthk", h, layer["w" + n].astype(cd)),
+        layer["conv_" + n]) for n in "qkv")
+    q = _unit(q) * float(cfg.kda_head_dim ** -0.5)
+    low = lambda a, b: jnp.einsum(                      # noqa: E731
+        "btr,rhk->bthk", jnp.einsum("btd,dr->btr", h, layer[a].astype(cd)),
+        layer[b].astype(cd)).astype(jnp.float32)
+    g = -jnp.exp(layer["A_log"])[:, None] * jax.nn.softplus(
+        low("f_a", "f_b") + layer["dt_bias"])
+    beta = jax.nn.sigmoid(jnp.einsum(
+        "btd,dh->bth", h, layer["w_beta"].astype(cd)).astype(jnp.float32))
+    o, stats = chunk_kda(q.astype(cd), _unit(k).astype(cd), v.astype(cd), g,
+                         beta, chunk=cfg.kda_chunk)
+    o = (_rms_norm(o, layer["o_norm"], cfg.norm_eps).astype(jnp.float32)
+         * jax.nn.sigmoid(low("g_a", "g_b"))).astype(cd)
+    scan = {"log_decay_min": stats["log_decay_min"],
+            "chunks": jnp.int32(stats["chunks"]),
+            "chunk": jnp.int32(cfg.kda_chunk),
+            "state_bytes": jnp.float32(stats["state_bytes"])}
+    return jnp.einsum("bthk,hkd->btd", o, layer["wo"].astype(cd)), scan
+
+
 def _layer_forward(cfg: TransformerConfig, i: int, layer, x, attn_op,
                    shard=None):
-    """One block: the layer's operator (attention or the gated short
-    convolution) and its FFN (dense, MoE or routed share), each a
-    residual.  Returns ``(x, aux, route)``: the capacity MoE's
-    load-balancing loss (0 elsewhere) and a routed layer's counts (None
-    elsewhere)."""
+    """One block: the layer's operator (attention, latent attention, the
+    gated short convolution or the delta-rule scan) and its FFN (dense,
+    MoE or routed share), each a residual.  Returns ``(x, aux, route,
+    scan)``: the capacity MoE's load-balancing loss (0 elsewhere), a
+    routed layer's counts and a ``kda`` layer's (None elsewhere)."""
     cd = cfg.compute_dtype
     h = _rms_norm(x, layer["ln1"], cfg.norm_eps)
+    scan = None
     if cfg.is_conv(i):
         x = x + _short_conv(cfg, layer, h)
+    elif cfg.kind(i) == "kda":
+        y, scan = _kda(cfg, layer, h)
+        x = x + y
+    elif cfg.kind(i) == "mla":
+        x = x + _latent_attention(cfg, layer, h, attn_op)
     else:
         x = x + _attention(cfg, layer, h, attn_op, shard)
     h = _rms_norm(x, layer["ln2"], cfg.norm_eps)
@@ -494,7 +698,8 @@ def _layer_forward(cfg: TransformerConfig, i: int, layer, x, attn_op,
         y, route = routed_ffn(
             h, layer["router"], layer["expert_bias"], layer["experts"],
             first=cfg.first_expert, k=cfg.moe_top_k,
-            scale=cfg.routed_scale, impl=cfg.expert_impl, compute_dtype=cd)
+            scale=cfg.routed_scale, impl=cfg.expert_impl, compute_dtype=cd,
+            shared=layer.get("shared"), router_grad=cfg.router_grad)
         x = x + y
     elif cfg.is_moe(i):
         if cfg.moe_top_k > 0:
@@ -525,7 +730,7 @@ def _layer_forward(cfg: TransformerConfig, i: int, layer, x, attn_op,
         else:
             up = jax.nn.gelu(up)
         x = x + jnp.einsum("btf,fd->btd", up, layer["w2"].astype(cd))
-    return x, aux, route
+    return x, aux, route, scan
 
 
 def make_staged(cfg: TransformerConfig, rng: jax.Array):
@@ -545,9 +750,14 @@ def make_staged(cfg: TransformerConfig, rng: jax.Array):
         raise ValueError("make_staged supports dense-routing MoE only "
                          "(moe_top_k must be 0): the staged loss has no "
                          "aux-loss channel")
-    if cfg.rope_theta:
+    if cfg.rope_theta or cfg.no_positions:
         raise ValueError("make_staged's embedding stage adds the learned "
-                         "positions: rope_theta must be 0")
+                         "positions: rope_theta must be 0 and no_positions "
+                         "false")
+    if cfg.layer_types or cfg.router_experts:
+        raise ValueError("make_staged's stages are attention with a dense "
+                         "FFN: layer_types must be empty and "
+                         "router_experts 0")
     params = init_params(cfg, rng)
     head = jax.random.normal(
         jax.random.fold_in(rng, 7), (cfg.d_model, cfg.vocab),
@@ -613,10 +823,13 @@ def make_lm_grad_fn(cfg: "TransformerConfig"):
     objective examples/lm.py uses).  A config with routed layers
     (``router_experts``) returns a fourth value, ``{"moe_route": ...}``:
     the layers' int32 counts, which the worker loop reads to the host in
-    a sampled round only (span ``moe.route``)."""
+    a sampled round only (span ``moe.route``); one with ``kda`` layers
+    adds ``"kda_scan"`` to it, their scans' counts (span ``kda.scan``)."""
     use_aux = cfg.moe_every > 0 and cfg.moe_top_k > 0
     routed = any(cfg.is_routed(i) for i in range(cfg.n_layers))
-    apply_fn = make_apply(cfg, return_aux=use_aux, return_route=routed)
+    scanned = "kda" in cfg.layer_types
+    apply_fn = make_apply(cfg, return_aux=use_aux,
+                          return_route=routed or scanned)
 
     @jax.jit
     def grad_fn(p, x, _y):
@@ -627,12 +840,17 @@ def make_lm_grad_fn(cfg: "TransformerConfig"):
             aux = out[1] if use_aux else 0.0
             loss = token_cross_entropy(logits, x) + AUX_COEF * aux
             acc = jnp.mean(jnp.argmax(logits[:, :-1], axis=-1) == x[:, 1:])
-            return loss, (acc, out[-1] if routed else None)
+            seen = {}
+            if routed:
+                seen["moe_route"] = out[1 + use_aux]
+            if scanned:
+                seen["kda_scan"] = out[-1]
+            return loss, (acc, seen)
 
-        (loss, (acc, route)), g = jax.value_and_grad(
+        (loss, (acc, seen)), g = jax.value_and_grad(
             loss_fn, has_aux=True)(p)
-        if routed:
-            return loss, acc, g, {"moe_route": route}
+        if seen:
+            return loss, acc, g, seen
         return loss, acc, g
 
     return grad_fn

@@ -175,8 +175,34 @@ def moe_ffn_topk(
 # gate) runs over the whole buffer, hence a buffer sized by the load.
 
 # (tm, tk, tn) of jax's megablox kernels, by a sweep on the v5e at 8
-# groups of about 512 rows of 2048 x 1536 (PERF.md section 6, PR 35)
+# groups of about 512 rows of 2048 x 1536 (PERF.md section 6, PR 35):
+# the row tile, and the most of a stack's tile, tk x tn elements of it
+# in VMEM twice over
 GMM_TILING = (256, 2048, 768)
+
+
+def gmm_tiling(rows: int, k: int, n: int):
+    """The (tm, tk, tn) of one grouped product of ``rows`` x ``k`` by
+    stacks of ``k`` x ``n``, chosen from the shape as
+    ``_flash_block_sizes`` chooses attention's: the row tile of
+    :data:`GMM_TILING`, cut to the rows; along the wider of the stacks'
+    two dimensions the largest multiple of 128 that divides it, 2048 at
+    most; along the other the largest such divisor that keeps the
+    stack's tile within 2048 x 768 elements.  The backward products of
+    a layer (the stacks transposed, the stacks' gradient) are handed
+    the same tiling by jax's ``gmm``, so both of a layer's shapes, (k,
+    n) and (n, k), get one answer: (256, 2048, 768) at 2048 x 1536,
+    (256, 1152, 1024) at 2304 x 1024."""
+    tm, top_k, top_n = GMM_TILING
+
+    def tile(size: int, most: int) -> int:
+        fits = [t for t in range(128, min(size, most) + 1, 128)
+                if size % t == 0]
+        return fits[-1] if fits else size
+
+    tk = tile(max(k, n), top_k)
+    tn = tile(min(k, n), max(128, top_k * top_n // tk))
+    return math.gcd(tm, rows), tk, tn
 
 
 def _grouped(lhs, rhs, group_sizes, impl: str):
@@ -195,10 +221,9 @@ def _grouped(lhs, rhs, group_sizes, impl: str):
     if impl == "gmm":
         from jax.experimental.pallas.ops.tpu.megablox import ops
 
-        tm = math.gcd(GMM_TILING[0], lhs.shape[0])
         with jax.named_scope("grouped"):
             return ops.gmm(lhs, rhs, group_sizes, lhs.dtype,
-                           (tm,) + GMM_TILING[1:])
+                           gmm_tiling(lhs.shape[0], *rhs.shape[1:]))
     raise ValueError(f"unknown expert_impl {impl!r}")
 
 
@@ -304,13 +329,42 @@ def route_topk(x, router_w, bias, k: int, scale: float = 1.0):
 
 def routed_ffn(x, router_w, bias, experts, first: int, k: int,
                scale: float = 1.0, impl: str = "ragged",
-               compute_dtype=jnp.bfloat16):
+               compute_dtype=jnp.bfloat16, shared=None,
+               router_grad: bool = True):
     """:func:`_routed_ffn` under ``jax.checkpoint``: the backward pass
     routes and sorts again rather than keep what the router computed
-    (the experts' own buffers are never kept: :func:`_experts`)."""
+    (the experts' own buffers are never kept: :func:`_experts`).
+    ``shared``, the stacks ``w1`` / ``w3`` [D, F] and ``w2`` [F, D] of
+    the experts every token passes, adds :func:`shared_ffn` of ``x`` to
+    the routed part, once, whichever share of the experts is held.
+    ``router_grad=False``: the routing weights are constants of the
+    backward pass, so the router's gradient is zero and none reaches
+    ``x`` through the scores.  A share's part of that gradient is a pull
+    toward the experts it holds (the deployment sums it over the
+    shares, and every expert pulls); stepped on alone it moves the load
+    onto them."""
     fn = functools.partial(_routed_ffn, first=first, k=k, scale=scale,
-                           impl=impl, compute_dtype=compute_dtype)
-    return jax.checkpoint(fn)(x, router_w, bias, experts)
+                           impl=impl, compute_dtype=compute_dtype,
+                           router_grad=router_grad)
+    if shared is None:
+        return jax.checkpoint(fn)(x, router_w, bias, experts)
+
+    def both(x, router_w, bias, experts, shared):
+        y, route = fn(x, router_w, bias, experts)
+        return y + shared_ffn(x, shared, compute_dtype), route
+
+    return jax.checkpoint(both)(x, router_w, bias, experts, shared)
+
+
+def shared_ffn(x, shared, compute_dtype=jnp.bfloat16):
+    """The shared experts: ``w2 (silu(w1 x) * w3 x)`` of every token,
+    dense work (several shared experts are one of their summed
+    width)."""
+    cd = compute_dtype
+    x = x.astype(cd)
+    up = (jax.nn.silu(jnp.dot(x, shared["w1"].astype(cd)))
+          * jnp.dot(x, shared["w3"].astype(cd)))
+    return jnp.dot(up, shared["w2"].astype(cd))
 
 
 def _chunk(c, x, weights, order, inv, rows, experts, *, buffer_rows: int,
@@ -403,7 +457,8 @@ _experts.defvjp(_experts_fwd, _experts_bwd)
 
 
 def _routed_ffn(x, router_w, bias, experts, first: int, k: int,
-                scale: float, impl: str, compute_dtype):
+                scale: float, impl: str, compute_dtype,
+                router_grad: bool = True):
     """One chip's share of a routed expert layer, no token dropped.
 
     ``x`` [..., D]; ``router_w`` [D, E_all] and ``bias`` [E_all] over
@@ -431,6 +486,8 @@ def _routed_ffn(x, router_w, bias, experts, first: int, k: int,
     x = x.reshape(-1, D)
     N, E = x.shape[0], experts["w1"].shape[0]
     idx, weights = route_topk(x, router_w, bias, k, scale)
+    if not router_grad:
+        weights = lax.stop_gradient(weights)
 
     local = idx - first
     held = (local >= 0) & (local < E)

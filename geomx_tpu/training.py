@@ -322,6 +322,22 @@ def _route_args(route, tokens: int) -> dict:
         "dropped": dropped}
 
 
+def _scan_args(scan) -> dict:
+    """The arguments of a step's ``kda.scan`` span from the counts the
+    gradient program returned (``ops/kda.py`` ``chunk_kda``, a row a
+    ``kda`` layer), read to the host HERE: ``layers``; ``chunk`` the
+    positions of a chunk and ``chunks`` the chunks of a layer's scan;
+    ``state_MB`` the carried states a layer keeps for the backward pass;
+    ``log_decay_min`` the most negative log decay cumulated inside any
+    chunk of the step (the naive factored form overflows past -88)."""
+    return {
+        "layers": int(np.size(scan["chunks"])),
+        "chunk": int(np.max(scan["chunk"])),
+        "chunks": int(np.max(scan["chunks"])),
+        "state_MB": float(np.max(scan["state_bytes"]) / 1e6),
+        "log_decay_min": float(np.min(scan["log_decay_min"]))}
+
+
 def _exchange(kv: WorkerKVStore, tids: Sequence[int], leaves: list,
               on_pulled: Callable[[int, np.ndarray], None],
               scale: float = 1.0, divide: bool = False,
@@ -458,6 +474,10 @@ def run_worker(
                         # span is recorded: in a sampled round
                         with kv.trace_span("moe.route", of=lambda: _route_args(
                                 extra[0]["moe_route"], np.size(fed[0]))):
+                            pass
+                    if extra and extra[0].get("kda_scan") is not None:
+                        with kv.trace_span("kda.scan", of=lambda: _scan_args(
+                                extra[0]["kda_scan"])):
                             pass
             grad_s = time.perf_counter() - t0
             if due:

@@ -199,3 +199,123 @@ def test_the_routed_layer_for_the_v5e_keeps_its_kernels_names(one_chip):
     assert sum(c.startswith("gmm") and "[8192," in c for c in calls) == 6
     assert not re.search(r"\[32768,(1536|2048)\]", hlo)
     assert compiled.memory_analysis().temp_size_in_bytes < 1.0e9
+
+
+def _kernel_calls(hlo: str) -> list:
+    from benchmark.lib.trace import op_name
+
+    return [op_name(line.strip()) for line in hlo.splitlines()
+            if 'custom_call_target="tpu_custom_call"' in line]
+
+
+def _reader(name: str) -> dict:
+    import json
+    from pathlib import Path
+
+    return json.loads((Path(__file__).parent.parent / "benchmark"
+                       / "layer_metrics" / f"{name}.json").read_text())
+
+
+def test_flash_at_the_latent_layers_widths_for_the_v5e(one_chip):
+    """Kimi-Linear's latent attention: q and k heads of 192 channels, v
+    heads of 128, padded to 256 for jax's kernels
+    (``_single_device_attention``).  The tiles ``_flash_block_sizes(8192,
+    256)`` picks fit the scoped VMEM at that width, the three kernels
+    are found by the patterns ``attn_roofline_pct`` reads, and the
+    gradients come back at the widths that went in."""
+    import jax
+    import jax.numpy as jnp
+
+    from geomx_tpu.models.transformer import (
+        TransformerConfig, _single_device_attention)
+
+    cfg = TransformerConfig(attn_impl="flash")
+    arg = lambda width: jax.ShapeDtypeStruct(            # noqa: E731
+        (1, 8192, 4, width), jnp.bfloat16, sharding=one_chip)
+
+    def loss(q, k, v):
+        return jnp.sum(
+            _single_device_attention(cfg, q, k, v).astype(jnp.float32))
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        arg(192), arg(192), arg(128)).compile()
+    calls = _kernel_calls(compiled.as_text())
+    assert len(calls) == 3, calls
+    for kernel in _reader("attn_roofline_pct")["kernels"]:
+        assert len([c for c in calls if re.search(kernel["pattern"], c)]) == 1
+    assert all("256]" in c for c in calls), calls
+    assert [g.shape[-1] for g in compiled.out_info] == [192, 192, 128]
+
+
+def test_kimi_linears_routed_layer_for_the_v5e(one_chip):
+    """One chip's share of Kimi-Linear's routed layer (8,192 tokens of
+    2304, 8 of 256 experts 1024 wide, top 8, a shared expert): the
+    tiling ``gmm_tiling`` picks from the shape, (256, 1152, 1024), fits
+    the scoped VMEM; the sorted buffer is 4,096 rows, twice an even
+    router's 2,048 (``chunk_rows``); nine grouped products found by
+    ``expert_gmm_roofline_pct``'s pattern; the shared expert is plain
+    matmuls."""
+    import jax
+    import jax.numpy as jnp
+
+    from geomx_tpu.parallel.moe import routed_ffn
+
+    def arg(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+
+    def loss(x, router, bias, experts, shared):
+        y, route = routed_ffn(x, router, bias, experts, first=0, k=8,
+                              scale=2.446, impl="gmm",
+                              compute_dtype=jnp.bfloat16, shared=shared)
+        return jnp.sum(y.astype(jnp.float32)), route
+
+    experts = {"w1": arg(8, 2304, 1024), "w3": arg(8, 2304, 1024),
+               "w2": arg(8, 1024, 2304)}
+    shared = {"w1": arg(2304, 1024), "w3": arg(2304, 1024),
+              "w2": arg(1024, 2304)}
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 3, 4), has_aux=True)).lower(
+        jax.ShapeDtypeStruct((1, 8192, 2304), jnp.bfloat16,
+                             sharding=one_chip),
+        arg(2304, 256), arg(256), experts, shared).compile()
+    hlo = compiled.as_text()
+    calls = _kernel_calls(hlo)
+    (kernel,) = _reader("expert_gmm_roofline_pct")["kernels"]
+    assert len(calls) == 9, calls
+    assert all(re.search(kernel["pattern"], c) for c in calls), calls
+    assert sum(c.startswith("gmm") and "[4096," in c for c in calls) == 6
+    assert not re.search(r"\[65536,(1024|2304)\]", hlo)
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.0e9
+
+
+def test_the_chunked_scan_for_the_v5e(one_chip):
+    """``chunk_kda`` and its gradient at the cell's shape (8,192
+    positions, 32 heads of 128, chunks of 64): the compiler takes it,
+    and because a block of 4 chunks is alive at a time and is computed
+    again in the backward pass, its temporaries stay a fraction of the
+    4 GB they were with every chunk's products kept (PERF.md section 6,
+    PR 37)."""
+    import jax
+    import jax.numpy as jnp
+
+    from geomx_tpu.ops.kda import chunk_kda
+
+    def arg(dtype, *shape):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    x = arg(jnp.bfloat16, 1, 8192, 32, 128)
+
+    seen = {}
+
+    def loss(q, k, v, g, beta):
+        o, stats = chunk_kda(q, k, v, g, beta, chunk=64)
+        seen.update(stats)                  # its counts are from shapes
+        return jnp.sum(o.astype(jnp.float32))
+
+    args = (x, x, x, arg(jnp.float32, 1, 8192, 32, 128),
+            arg(jnp.float32, 1, 8192, 32))
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
+        *args).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.5e9
+    # 128 chunks in 32 blocks: 32 float32 states of 32 x 128 x 128 kept
+    assert seen["chunks"] == 128
+    assert seen["state_bytes"] == 32 * 32 * 128 * 128 * 4
