@@ -49,6 +49,7 @@ import numpy as np
 from jax import lax, shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from geomx_tpu.ops.kda import KEPT_NAMES, chunk_kda
 from geomx_tpu.parallel.ring_attention import (
     dense_attention, fast_dense_attention, ring_attention)
 from geomx_tpu.parallel.ulysses import ulysses_attention
@@ -83,7 +84,13 @@ class TransformerConfig:
     remat: bool = False      # jax.checkpoint each layer: recompute
     #                          activations in bwd, trading ~1/3 more
     #                          fwd FLOPs for O(L) less HBM — the TPU
-    #                          recipe for big batches / long seq
+    #                          recipe for big batches / long seq.
+    #                          Kept and not recomputed: what a layer's
+    #                          program names for it (ops/kda.py
+    #                          KEPT_NAMES: a kda layer's scan states and
+    #                          output, 134 MB a layer at [1, 8192, 32,
+    #                          128] in bf16, so that the recompute runs
+    #                          no scan); no other layer names anything
     # ---- off by default: all off is the flagship's GPT block ----------
     n_kv_heads: int = 0      # grouped-query attention: k and v heads,
     #                          each serving n_heads // n_kv_heads
@@ -483,7 +490,11 @@ def make_apply(cfg: TransformerConfig, mesh: Optional[Mesh] = None,
             return _layer_forward(cfg, i, layer, x, attn_op, shard)
 
         if cfg.remat:
-            layer_fn = jax.checkpoint(layer_fn, static_argnums=(2,))
+            # a layer that names nothing keeps nothing
+            layer_fn = jax.checkpoint(
+                layer_fn, static_argnums=(2,),
+                policy=jax.checkpoint_policies.save_only_these_names(
+                    *KEPT_NAMES))
         aux_total = jnp.zeros((), jnp.float32)
         routes, scans = [], []
         for i, layer in enumerate(params["layers"]):
@@ -645,9 +656,9 @@ def _kda(cfg: TransformerConfig, layer, h):
     dt_bias)`` and a step ``sigmoid(w_beta h)``, both float32, the scan
     (``ops/kda.py``), then RMSNorm over each head's channels, a sigmoid
     output gate through a low-rank pair, and ``wo``.  Returns ``(y,
-    scan)``, the scan's counts for the tracer."""
-    from geomx_tpu.ops.kda import chunk_kda
-
+    scan)``, the scan's counts for the tracer: ``chunk_kda``'s, and
+    ``kept_bytes``, the states and output that the layer's checkpoint
+    keeps for the backward pass under ``cfg.remat`` (0 without)."""
     cd = cfg.compute_dtype
     q, k, v = (_conv_silu(
         jnp.einsum("btd,dhk->bthk", h, layer["w" + n].astype(cd)),
@@ -667,7 +678,10 @@ def _kda(cfg: TransformerConfig, layer, h):
     scan = {"log_decay_min": stats["log_decay_min"],
             "chunks": jnp.int32(stats["chunks"]),
             "chunk": jnp.int32(cfg.kda_chunk),
-            "state_bytes": jnp.float32(stats["state_bytes"])}
+            "state_bytes": jnp.float32(stats["state_bytes"]),
+            # what make_apply's checkpoint of the layer keeps of it
+            "kept_bytes": jnp.float32(
+                stats["named_bytes"] if cfg.remat else 0)}
     return jnp.einsum("bthk,hkd->btd", o, layer["wo"].astype(cd)), scan
 
 

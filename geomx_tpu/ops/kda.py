@@ -42,6 +42,22 @@ to chunk (a backward pass of their own, eight heads a grid step) were
 written, compiled for the v5e and measured against this scan at the
 cell's shape, lost at every block size, and are not here: PERF.md
 section 6 (PR 37).
+
+**What a layer's checkpoint keeps.**  The scan over blocks names two of
+its values (``jax.ad_checkpoint.checkpoint_name``): the state a block
+starts from (:data:`STATE_NAME`, float32) and the block's output
+(:data:`OUT_NAME`).  Under a plain ``jax.checkpoint`` of the layer
+around it the names mean nothing and the layer's backward pass runs the
+whole scan forward again only to get those two back, then each block
+once more inside the backward scan: three runs of the chunk-parallel
+products.  A checkpoint whose policy is ``save_only_these_names(*
+KEPT_NAMES)`` (``models/transformer.py`` ``make_apply`` under ``remat``)
+keeps them from the first forward pass, ``named_bytes`` a layer (at
+[1, 8192, 32, 128] in bfloat16 67 MB of states and 67 MB of output),
+and the scan runs twice: forward, and a block at a time in the backward
+scan, whose own ``jax.checkpoint`` stays inside the named body so that a
+block's 4 GB a layer of chunk products are still never kept (PERF.md
+section 6, PR 38).
 """
 
 from __future__ import annotations
@@ -51,6 +67,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 
 SUB = 16        # rows of the triangular system inverted by substitution
 
@@ -231,6 +248,11 @@ BLOCK_CHUNKS = 4    # chunks of a block: the chunk-parallel products of
 #                     block again (jax.checkpoint); 4 by a sweep at the
 #                     cell's shape on the v5e (PERF.md section 6, PR 37)
 
+# what the scan over blocks names for a checkpoint around it to keep
+STATE_NAME = "kda_state"    # the state a block starts from, float32
+OUT_NAME = "kda_out"        # a block's output
+KEPT_NAMES = (STATE_NAME, OUT_NAME)
+
 
 def chunk_kda(q, k, v, g, beta, chunk: int = 64):
     """The gated delta rule over ``q``, ``k`` [B, T, H, K] (the caller's
@@ -243,8 +265,10 @@ def chunk_kda(q, k, v, g, beta, chunk: int = 64):
     decay cumulated inside any chunk (float32 scalar, no gradient), how
     near the naive factored form would be to overflow (-88); ``chunks``
     and ``state_bytes``, the float32 states the backward pass is handed
-    (one a block of :data:`BLOCK_CHUNKS` chunks, head and sequence),
-    from shapes."""
+    (one a block of :data:`BLOCK_CHUNKS` chunks, head and sequence), and
+    ``named_bytes``, those states and the output, what a checkpoint
+    around the scan keeps if its policy saves :data:`KEPT_NAMES`; all
+    three from shapes."""
     B, T, H, K = k.shape
     V = v.shape[-1]
     per = min(BLOCK_CHUNKS, -(-T // chunk))
@@ -259,20 +283,24 @@ def chunk_kda(q, k, v, g, beta, chunk: int = 64):
         x = x.reshape((B, blocks, per, chunk) + x.shape[2:])
         return jnp.moveaxis(jnp.moveaxis(x, 4, 1), 2, 0)
 
+    block = jax.checkpoint(_block)
+
     def body(S, xs):
-        o, S, low = _block(S, *xs)
-        return S, (o, low)
+        o, S, low = block(checkpoint_name(S, STATE_NAME), *xs)
+        return S, (checkpoint_name(o, OUT_NAME), low)
 
     with jax.named_scope("kda"):
         _, (o, low) = lax.scan(
-            jax.checkpoint(body), jnp.zeros((B, H, V, K), jnp.float32),
+            body, jnp.zeros((B, H, V, K), jnp.float32),
             (blocked(q), blocked(k), blocked(v),
              blocked(g.astype(jnp.float32)),
              blocked(beta.astype(jnp.float32))))
+    state_bytes = blocks * B * H * K * V * 4
+    named_bytes = state_bytes + o.size * o.dtype.itemsize
     # [blocks, B, H, per, C, V] -> [B, T, H, V]
     o = jnp.moveaxis(jnp.moveaxis(o, 0, 2), 1, 4).reshape(
         B, blocks * per * chunk, H, V)[:, :T]
     stats = {"log_decay_min": lax.stop_gradient(jnp.min(low)),
              "chunks": blocks * per,
-             "state_bytes": blocks * B * H * K * V * 4}
+             "state_bytes": state_bytes, "named_bytes": named_bytes}
     return o, stats

@@ -327,7 +327,9 @@ def _scan_args(scan) -> dict:
     gradient program returned (``ops/kda.py`` ``chunk_kda``, a row a
     ``kda`` layer), read to the host HERE: ``layers``; ``chunk`` the
     positions of a chunk and ``chunks`` the chunks of a layer's scan;
-    ``state_MB`` the carried states a layer keeps for the backward pass;
+    ``state_MB`` the carried states a layer keeps for the backward pass
+    and ``kept_MB`` what the layer's checkpoint keeps of the scan (those
+    states and its output under ``remat``, 0 without);
     ``log_decay_min`` the most negative log decay cumulated inside any
     chunk of the step (the naive factored form overflows past -88)."""
     return {
@@ -335,6 +337,7 @@ def _scan_args(scan) -> dict:
         "chunk": int(np.max(scan["chunk"])),
         "chunks": int(np.max(scan["chunks"])),
         "state_MB": float(np.max(scan["state_bytes"]) / 1e6),
+        "kept_MB": float(np.max(scan["kept_bytes"]) / 1e6),
         "log_decay_min": float(np.min(scan["log_decay_min"]))}
 
 

@@ -3,6 +3,8 @@ against the recurrence a token at a time, forward and gradients, at
 decays strong enough that the naive factored form overflows, at a
 length no chunk divides; the triangular inverse; bfloat16."""
 
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -136,3 +138,40 @@ def test_unit_lower_inverse(size):
     # (I + L)^-1 of the all-ones L: 1 on the diagonal, -1, then +-2^n
     np.testing.assert_allclose(
         kda.unit_lower_inverse(alike) @ (eye + alike), eye, atol=1e-2)
+
+
+def test_a_checkpoint_that_saves_the_names_keeps_states_and_output(capsys):
+    """What the scan names for a checkpoint around it (ISSUE 38): with
+    ``save_only_these_names(*KEPT_NAMES)`` the residuals are the
+    arguments, the float32 state each block starts from and the blocks'
+    output, ``named_bytes`` in all, and nothing of a block's inside; a
+    plain checkpoint keeps the arguments alone.  The gradient is the
+    same to the bit either way."""
+    from jax.ad_checkpoint import print_saved_residuals
+
+    x = _inputs(1.0)                 # 150 positions: 3 blocks of 4 x 16
+    seen = {}
+
+    def loss(*a):
+        o, stats = kda.chunk_kda(*a, chunk=16)
+        seen.update(stats)
+        return jnp.sum(o ** 2)
+
+    def kept(f):
+        print_saved_residuals(f, *x)
+        lines = capsys.readouterr().out.splitlines()
+        return sorted(re.match(r"\w+\[([\d,]+)\]", line).group(1)
+                      for line in lines if "from the argument" not in line)
+
+    saving = jax.checkpoint(
+        loss, policy=jax.checkpoint_policies.save_only_these_names(
+            *kda.KEPT_NAMES))
+    assert kept(jax.checkpoint(loss)) == []
+    assert kept(saving) == ["3,2,2,4,16,8", "3,2,2,8,8"]     # float32
+    assert seen["state_bytes"] == 3 * 2 * 2 * 8 * 8 * 4
+    assert seen["named_bytes"] == seen["state_bytes"] + (
+        3 * 2 * 2 * 4 * 16 * 8 * 4)
+    argnums = (0, 1, 2, 3, 4)
+    for a, b in zip(jax.jit(jax.grad(saving, argnums))(*x),
+                    jax.jit(jax.grad(jax.checkpoint(loss), argnums))(*x)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))

@@ -5,8 +5,11 @@ the family's plain reference (``benchmark/families/kimi_linear/
 reference.py``, which imports nothing of the program and steps the scan
 a token at a time) on seeded random weights at tiny sizes; then one
 party through both kvstore tiers against the reference's Adam step, and
-the ``kda.scan`` span of a sampled round."""
+the ``kda.scan`` span of a sampled round.  ISSUE 38: what the layer's
+checkpoint keeps of the scan, against a plain ``jax.checkpoint`` and
+against no checkpoint at all."""
 
+import contextlib
 import hashlib
 import json
 import subprocess
@@ -388,6 +391,162 @@ def test_lfm2s_lowered_gradient_program_is_unchanged(dtype):
 
 
 # ---------------------------------------------------------------------------
+# what the layer's checkpoint keeps (ISSUE 38)
+# ---------------------------------------------------------------------------
+
+@contextlib.contextmanager
+def _plain_checkpoint():
+    """``jax.checkpoint`` without whatever policy it is given: the
+    layer's checkpoint as it was before the scan named anything."""
+    checkpoint = jax.checkpoint
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(jax, "checkpoint",
+                      lambda f, policy=None, **kw: checkpoint(f, **kw))
+        yield
+
+
+LONG = 256      # 16 chunks of 16: four blocks of BLOCK_CHUNKS a scan
+
+
+def _loops(jaxpr, depth=0):
+    """``[(depth, equations in the body)]`` of every ``scan`` and
+    ``while`` of a jaxpr, by how many loops it lies inside: each
+    occurrence counts (the lowered text holds a function once however
+    often it is called)."""
+    out = []
+    for eqn in jaxpr.eqns:
+        loop = eqn.primitive.name in ("scan", "while")
+        if loop:
+            body = eqn.params.get("jaxpr", eqn.params.get("body_jaxpr"))
+            out.append((depth, len(body.jaxpr.eqns)))
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            out += _loops(sub, depth + loop)
+    return out
+
+
+@pytest.fixture(scope="module")
+def long_grads():
+    """Loss, gradient, the scans' counts, the gradient program's loops
+    and the ``stablehlo.while`` of its lowered text, of the tiny model over 256 positions in
+    float32, by what surrounds a layer: ``make_apply``'s checkpoint with
+    its policy (``remat`` true, the cell's), a plain ``jax.checkpoint``
+    of the layer, and nothing (``remat`` false)."""
+    x = jnp.asarray(np.random.default_rng(0).integers(
+        0, TINY["vocab"], (2, LONG)), jnp.int32)
+    out = {}
+
+    def run(name, remat):
+        params, grad_fn = _build("float32", max_seq=LONG, remat=remat)
+        traced = grad_fn.trace(params, x, x)        # traced once
+        lowered = traced.lower()
+        loss, _acc, grads, extra = lowered.compile()(params, x, x)
+        out[name] = {
+            "loss": float(loss), "grads": grads, "scan": extra["kda_scan"],
+            "loops": _loops(traced.jaxpr.jaxpr),
+            "whiles": lowered.as_text().count("stablehlo.while")}
+
+    run("policy", True)
+    run("none", False)
+    with _plain_checkpoint():
+        run("plain", True)
+    return out
+
+
+def test_the_kept_scan_is_the_recomputed_one_to_the_bit(long_grads):
+    """The saved output and states are the values the layer's recompute
+    would have produced again: against a plain ``jax.checkpoint`` of the
+    layer the loss and every gradient leaf are equal to the bit."""
+    mine, plain = long_grads["policy"], long_grads["plain"]
+    assert mine["loss"] == plain["loss"]
+    flat = jax.tree_util.tree_flatten_with_path(mine["grads"])[0]
+    assert len(flat) == 113
+    for (path, g), r in zip(flat, jax.tree_util.tree_leaves(plain["grads"])):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(r),
+                                      err_msg=jax.tree_util.keystr(path))
+
+
+def test_remat_leaves_the_gradient_within_the_files_tolerance(long_grads):
+    mine, free = long_grads["policy"], long_grads["none"]
+    assert mine["loss"] == pytest.approx(free["loss"], abs=2e-6)
+    worst = _worst(mine["grads"], free["grads"])
+    assert max(worst.values()) < 2e-4, worst
+
+
+def test_the_scan_runs_twice_a_layer_under_the_layers_checkpoint(long_grads):
+    """The loops of the gradient program.  A KDA layer's forward scan is
+    a loop over blocks with a loop over a block's chunks inside; its
+    backward scan a loop over blocks whose body runs the block again (a
+    chunk loop) and then backward (another).  A plain checkpoint's
+    recompute of the layer adds the forward scan a third time; with the
+    states and the output kept, what is left of it is a loop over blocks
+    with an empty body.  Beside them a loop forward and a loop backward
+    a routed layer."""
+    loops = {k: v["loops"] for k, v in long_grads.items()}
+    total = {k: len(v) for k, v in loops.items()}
+    assert total == {"none": 4 * 5 + 8, "plain": 4 * 7 + 8,
+                     "policy": 4 * 6 + 8}                   # 28, 36, 32
+    chunk_loops = {k: sum(d == 1 for d, _ in v) for k, v in loops.items()}
+    assert chunk_loops == {"none": 4 * 3, "plain": 4 * 4, "policy": 4 * 3}
+    empty = {k: sum(n == 0 for _, n in v) for k, v in loops.items()}
+    assert empty == {"none": 0, "plain": 0, "policy": 4}
+    # the lowered text: one loop fewer a KDA layer than under the plain
+    # checkpoint (the block's checkpointed function is lowered once)
+    assert long_grads["policy"]["whiles"] == long_grads["plain"]["whiles"] - 4
+    # what is kept: a float32 state a block and the output, a layer
+    kept = 4 * 2 * 4 * 8 * 8 * 4 + 2 * LONG * 4 * 8 * 4
+    for name, want in (("policy", kept), ("plain", kept), ("none", 0)):
+        scan = long_grads[name]["scan"]
+        assert scan["kept_bytes"].tolist() == [want] * 4
+        assert scan["state_bytes"].tolist() == [4 * 2 * 4 * 8 * 8 * 4] * 4
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_without_kda_layers_the_policy_is_a_plain_checkpoint(dtype):
+    """LFM2's family (short convolutions, grouped-query attention, a
+    dense and four routed FFNs: no ``kda`` layer) with ``remat`` true:
+    the names match nothing, so the lowered gradient program is, text
+    for text, the one under a plain ``jax.checkpoint`` of the layer."""
+    lfm2 = family.load(ROOT, ["benchmark"], "lfm2_moe")
+    config = json.loads((ROOT / "benchmark/configs/"
+                         "lfm2-24b-a2b-ep8-l5-1chip.json").read_text())
+    tiny = {**{k: config[k] for k in (*family.MODEL_KEYS,
+                                      *lfm2.needs["keys"])},
+            **lfm2.needs["rehearsal"], "remat": True}
+    x = jax.ShapeDtypeStruct((2, tiny["max_seq"]), jnp.int32)
+
+    def lowered():
+        init, grad_fn = lfm2.system.build(tiny, dtype)
+        return grad_fn.lower(jax.eval_shape(init, jax.random.PRNGKey(0)),
+                             x, x).as_text()
+
+    with_policy = lowered()
+    with _plain_checkpoint():
+        assert lowered() == with_policy
+    assert "optimization_barrier" in with_policy     # it is checkpointed
+
+
+@pytest.mark.parametrize("remat, kept", [
+    (True, 2 * 4 * 8 * 8 * 4 + 2 * 48 * 4 * 8 * 4), (False, 0)])
+def test_the_scans_counts_say_what_the_layers_checkpoint_keeps(remat, kept):
+    """``_kda``'s ``kept_bytes`` and the ``kda.scan`` span's ``kept_MB``:
+    the float32 states (one block of 3 chunks, 2 sequences, 4 heads of 8
+    x 8) and the output (2 x 48 x 4 x 8 float32) under ``remat``, 0
+    without; ``state_MB`` is the scan's own either way."""
+    from geomx_tpu import training
+
+    cfg = FAMILY.system.config({**TINY, "remat": remat}, "float32")
+    params, _ = _build("float32")
+    h = jax.random.normal(jax.random.PRNGKey(5), (2, 48, 32))
+    _, scan = tf._kda(cfg, params["layers"][1], h)
+    assert float(scan["kept_bytes"]) == kept
+    args = training._scan_args(jax.tree_util.tree_map(
+        lambda a: jnp.stack([a, a]), scan))
+    assert args["layers"] == 2
+    assert args["kept_MB"] == pytest.approx(kept / 1e6)
+    assert args["state_MB"] == pytest.approx(2 * 4 * 8 * 8 * 4 / 1e6)
+
+
+# ---------------------------------------------------------------------------
 # one party through both tiers; kda.scan
 # ---------------------------------------------------------------------------
 
@@ -480,6 +639,9 @@ def test_kda_scan_is_recorded_in_the_sampled_round_only(trained):
     # one float32 state of 4 heads x 8 x 8 a block (3 chunks: one block)
     # and sequence (2)
     assert a["state_MB"] == pytest.approx(2 * 4 * 8 * 8 * 4 / 1e6)
+    # the cell's remat: the layer's checkpoint keeps those and the output
+    assert a["kept_MB"] == pytest.approx(
+        (2 * 4 * 8 * 8 * 4 + 2 * 48 * 4 * 8 * 4) / 1e6)
     assert a["log_decay_min"] < 0
     routes = [e for e in events if e["name"] == "moe.route"]
     assert len(routes) == 1 and routes[0]["args"]["dropped"] == 0

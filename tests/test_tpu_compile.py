@@ -319,3 +319,41 @@ def test_the_chunked_scan_for_the_v5e(one_chip):
     # 128 chunks in 32 blocks: 32 float32 states of 32 x 128 x 128 kept
     assert seen["chunks"] == 128
     assert seen["state_bytes"] == 32 * 32 * 128 * 128 * 4
+
+
+def test_a_kda_layer_under_the_layers_checkpoint_scans_twice(one_chip):
+    """One KDA layer (``_layer_forward`` under ``make_apply``'s
+    checkpoint, ``remat`` true) at the cell's shape: 8,192 positions,
+    2,304 wide, 32 heads of 128, chunks of 64; a narrow FFN and a small
+    vocabulary, which are not the point.  The layer's checkpoint keeps
+    the scan's 32 states and its output (``ops/kda.py`` ``KEPT_NAMES``),
+    so the compiled gradient has two loops over the 32 blocks, forward
+    and backward, and three over a block's chunks (forward; again and
+    backward inside the backward loop); under a plain ``jax.checkpoint``
+    the layer's recompute ran a third and a fourth (PERF.md section 6,
+    PR 38).  Kept: 0.13 GB of 2.03 GB of temporaries."""
+    import jax
+    import jax.numpy as jnp
+
+    from geomx_tpu.models import transformer as tf
+
+    cfg = tf.TransformerConfig(
+        vocab=256, d_model=2304, n_heads=32, n_layers=1, d_ff=256,
+        max_seq=8192, layer_types=("kda",), kda_heads=32, kda_head_dim=128,
+        kda_chunk=64, no_positions=True, gated_ffn=True, remat=True)
+    apply = tf.make_apply(cfg)
+    params = jax.tree_util.tree_map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip),
+        jax.eval_shape(lambda k: tf.init_params(cfg, k),
+                       jax.random.PRNGKey(0)))
+    x = jax.ShapeDtypeStruct((1, 8192), jnp.int32, sharding=one_chip)
+    compiled = jax.jit(jax.grad(
+        lambda p, x: tf.lm_loss(apply, p, x))).lower(params, x).compile()
+    loops = [line for line in compiled.as_text().splitlines()
+             if " while(" in line]
+    # a loop over the blocks carries arrays stacked over the 32 of them
+    over_blocks = [line for line in loops
+                   if "[32,1,32," in line.split(" while(")[0]]
+    assert (len(loops), len(over_blocks)) == (5, 2), loops
+    assert not any("rematted_computation/kda" in line for line in loops)
+    assert compiled.memory_analysis().temp_size_in_bytes < 2.15e9
