@@ -59,7 +59,16 @@ def _is_lock_attr(name: str) -> bool:
 def _lock_expr_name(expr: ast.expr) -> Optional[Tuple[str, bool]]:
     """``(attr_name, is_stripe)`` when ``expr`` acquires a lock:
     ``self._mu`` → ("_mu", False); ``self._mu.stripe(k)`` → ("_mu",
-    True); bare module-level ``_registry_mu`` also counts."""
+    True); bare module-level ``_registry_mu`` also counts.  A lock
+    taken on behalf of an open trace span is the lock itself:
+    ``tracer.locked(<lock>)`` / ``span.locked(<lock>)``, and the
+    ``<lock> if <tracing off> else <...>.locked(<lock>)`` the sites
+    write so that the off path pays one branch."""
+    if isinstance(expr, ast.IfExp):
+        return _lock_expr_name(expr.body) or _lock_expr_name(expr.orelse)
+    if isinstance(expr, ast.Call) and isinstance(expr.func, ast.Attribute) \
+            and expr.func.attr == "locked" and len(expr.args) == 1:
+        return _lock_expr_name(expr.args[0])
     if isinstance(expr, ast.Call) and isinstance(expr.func, ast.Attribute) \
             and expr.func.attr == "stripe":
         inner = _attr_chain(expr.func.value)

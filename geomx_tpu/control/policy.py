@@ -27,9 +27,11 @@ Hysteresis discipline (what keeps an oscillating link from thrashing):
 - **cooldown** — after any shift, decisions are frozen for
   ``cooldown_s`` so the new tier's effect is actually observed before
   the next move;
-- **compute veto** — when tracing supplies a ``dominant_stage`` that is
-  compute (local/global merge), downshifts are vetoed: more compression
-  cannot shorten a compute-bound round, it only loses gradient mass.
+- **compute veto** — when tracing supplies a ``dominant_stage`` (the
+  stage with the largest share of the newest finished round's blocking
+  chain, ``TraceCollector.critical_path``) that is compute (local/global
+  merge), downshifts are vetoed: more compression cannot shorten a
+  compute-bound round, it only loses gradient mass.
 """
 
 from __future__ import annotations

@@ -168,8 +168,12 @@ class SignalEstimator:
     def _last_round(report: Optional[dict]) -> Optional[dict]:
         if not report:
             return None
-        rounds = report.get("rounds") or ()
-        return rounds[-1] if rounds else None
+        # the newest round that is whole: one the collector still holds
+        # (``held``) may lack a node's events, and its chain with them
+        for r in reversed(report.get("rounds") or ()):
+            if not r.get("held"):
+                return r
+        return None
 
     def _dominant(self, report: Optional[dict]) -> Optional[str]:
         r = self._last_round(report)

@@ -607,8 +607,11 @@ class Config:
     # critical-path report.  0 (default) = off; the disabled hot path is
     # a single flag check per message, no allocation.
     trace_sample_every: int = 0
-    trace_dir: str = ""          # launch.py dumps the merged trace +
-    #                              critical-path report here at shutdown
+    trace_dir: str = ""          # launch.py (and Simulation.shutdown)
+    #                              write the per-round report here, and
+    #                              only where it is set does the
+    #                              collector keep the merged timeline
+    #                              for launch.py's geomx_trace.json
     trace_batch_events: int = 256  # spans per TRACE_REPORT batch
     # --- adaptive WAN control plane (geomx_tpu/control; beyond the
     # reference, whose codec/ratio choice is fixed at launch).  When on,

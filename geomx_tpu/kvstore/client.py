@@ -807,8 +807,12 @@ class WorkerKVStore:
         semantics).  Raises if any server rejected a request."""
         with self._mu:
             pending, self._pending = self._pending, []
-        for ts in pending:
-            self.worker.wait(ts)
+        # ``worker.wait``: this thread is blocked until the response
+        # thread has handled the last answer; the collector's chain goes
+        # on with what that thread (and the tiers behind it) did
+        with self._tracer.span("worker.wait"):
+            for ts in pending:
+                self.worker.wait(ts)
         if self.worker.errors:
             errs, self.worker.errors = list(self.worker.errors), []
             raise RuntimeError("; ".join(errs))

@@ -126,11 +126,13 @@ class _Timed:
         self._span = span
 
     def __enter__(self):
+        """The span (``_NULL_SPAN`` in an unsampled round), for a site
+        that tells it something while it is open."""
         if self._span is _NULL_SPAN:
             self._t0 = time.perf_counter()
         else:
             self._span.__enter__()
-        return self
+        return self._span
 
     def __exit__(self, *exc):
         span = self._span
@@ -300,8 +302,14 @@ class JaxBackend(MergeBackend):
         if isinstance(acc, np.ndarray):
             return acc
         with self._timed("be.d2h", "merge_device_ms", acc.key,
-                         4 * acc.elems):
-            host = np.asarray(self._reduced(acc))  # block + one D2H
+                         4 * acc.elems) as sp:
+            dev = self._reduced(acc)
+            if sp is not _NULL_SPAN:
+                # a sampled round says how much of the span is the wait
+                # for the programs that produce the value (``wait_us``);
+                # the copy below would block there anyway
+                sp.await_device(dev)
+            host = np.asarray(dev)  # block + one D2H
             with self._mu:
                 self.d2h_bytes += host.nbytes
             if self._platform == "cpu":
@@ -539,7 +547,9 @@ class DeviceWeight:
     def host(self) -> np.ndarray:
         if self._host is None:
             with self._be._tr.span("be.d2h", key=self.key,
-                                   nbytes=self.ref.nbytes):
+                                   nbytes=self.ref.nbytes) as sp:
+                if sp is not _NULL_SPAN:
+                    sp.await_device(self.ref)  # ``wait_us``
                 h = np.asarray(self.ref)  # one D2H (zero-copy view on cpu)
             self._be._bill_d2h(h.nbytes)
             self._host = h
@@ -861,7 +871,9 @@ class CodecStage:
         """Full-tensor D2H for the fallback event paths (degraded-round
         absorb, adaptive raw stash) — billed to ``codec_host_bytes`` so
         the steady-state "host copies == 0" contract stays auditable."""
-        with self._be._tr.span("be.d2h", nbytes=v.nbytes):
+        with self._be._tr.span("be.d2h", nbytes=v.nbytes) as sp:
+            if sp is not _NULL_SPAN:
+                sp.await_device(v)  # ``wait_us``
             host = np.asarray(v)
         with self._be._mu:
             self._be.codec_host_bytes += host.nbytes
@@ -885,7 +897,9 @@ class CodecStage:
         THE single D2H of the device encode path (compressed bytes only,
         billed to ``codec_d2h_bytes``).  The returned view keeps the
         device buffer alive; senders ship it donated and never mutate."""
-        with self._be._tr.span("be.d2h", nbytes=payload.nbytes):
+        with self._be._tr.span("be.d2h", nbytes=payload.nbytes) as sp:
+            if sp is not _NULL_SPAN:
+                sp.await_device(payload)  # ``wait_us``: the encoder
             host = np.asarray(payload)
         with self._be._mu:
             self._be.codec_d2h_bytes += host.nbytes

@@ -47,6 +47,7 @@ from geomx_tpu.optim import DCASGD, ServerOptimizer, Sgd, make_optimizer
 from geomx_tpu.ps import KVPairs, KVServer, KVWorker, Postoffice
 from geomx_tpu.ps.postoffice import split_range
 from geomx_tpu.trace import context as _tctx
+from geomx_tpu.trace.recorder import _NULL_SPAN
 from geomx_tpu.transport.message import Control, Domain, Message
 
 
@@ -59,6 +60,16 @@ def _note_key_groups(tracer, msg):
     for group, keys in (groups or {}).items():
         tracer.key_groups.update(dict.fromkeys(map(int, keys), group))
     return groups
+
+
+def _one_span(prof, tracer, name: str, msg):
+    """A handler's span, recorded ONCE: the tracer's in a sampled round
+    (it carries the causal ids, and records into the profiler's own
+    buffer whether or not the remote profiler runs), else the
+    profiler's (which records only while that runs).  Side by side the
+    two put the event into the one buffer twice."""
+    sp = tracer.span(name, of=msg)
+    return prof.span(name) if sp is _NULL_SPAN else sp
 
 
 def _ctx_bound(fn, lane_tracer=None, key=None):
@@ -601,17 +612,12 @@ class LocalServer:
             # routed here because the KVServer owns the PS app id
             self.ts_push_inter._on_merge_msg(msg)
         elif msg.push:
-            # the tracer span nests inside the profiler span: same
-            # buffer, but the tracer one carries the causal ids and is
-            # gated on the round's sampling, not on profiler.running
-            with prof.span("local.push"), \
-                    self._tr.span("local.push", of=msg):
+            with _one_span(prof, self._tr, "local.push", msg):
                 self._handle_push(msg, kvs)
             if prof.running:
                 prof.count("push_bytes", float(msg.nbytes))
         elif msg.pull:
-            with prof.span("local.pull"), \
-                    self._tr.span("local.pull", of=msg):
+            with _one_span(prof, self._tr, "local.pull", msg):
                 self._handle_pull(msg, kvs)
 
     def _handle_init(self, msg: Message, kvs: KVPairs):
@@ -1565,7 +1571,10 @@ class LocalServer:
 
         def merge_one(k: int, v: np.ndarray):
             bundle = None
-            with self._mu.stripe(k):
+            # in a sampled round the wait for the key's stripe is the
+            # open span's ``lock_us`` (``local.push``, or the ``lane``)
+            with (self._mu.stripe(k) if not _tctx.ACTIVE
+                  else self._tr.locked(self._mu.stripe(k))):
                 st = self._keys.setdefault(k, _KeyState())
                 st.contributors.add(sender_s)
                 if hfa_n:
@@ -2434,7 +2443,9 @@ class LocalServer:
         with self._tr.span("local.pull_down", of=kvs):
             live = []
             for k, v in kvs.slices():
-                with self._mu.stripe(k):
+                with (self._mu.stripe(k) if not _tctx.ACTIVE
+                      else self._tr.locked(
+                          self._mu.stripe(k))):  # ``lock_us``
                     if (epochs is not None
                             and k in self._keys
                             and self._keys[k].epoch != epochs.get(k)):
@@ -2486,7 +2497,8 @@ class LocalServer:
         pull re-acquires stripes in its own key order."""
         to_retry: List[Message] = []
         for k in keys:
-            with self._mu.stripe(k):
+            with (self._mu.stripe(k) if not _tctx.ACTIVE
+                  else self._tr.locked(self._mu.stripe(k))):
                 st = self._keys[k]
                 st.in_flight = max(0, st.in_flight - 1)
                 st.version += 1
@@ -2536,7 +2548,8 @@ class LocalServer:
         sender_s = str(req.sender)
         for k in req.keys:
             k = int(k)
-            with self._mu.stripe(k):
+            with (self._mu.stripe(k) if not _tctx.ACTIVE
+                  else self._tr.locked(self._mu.stripe(k))):
                 st = self._keys.get(k)
                 if st is None:
                     st = self._keys.setdefault(k, _KeyState())
@@ -3188,7 +3201,7 @@ class GlobalServer:
             prof.count("push_bytes", float(msg.nbytes))
         span_name = ("global.init" if msg.cmd == Cmd.INIT
                      else "global.push" if msg.push else "global.pull")
-        with prof.span(span_name), self._tr.span(span_name, of=msg):
+        with _one_span(prof, self._tr, span_name, msg):
             self._handle_inner(msg, kvs, server)
 
     def _handle_inner(self, msg: Message, kvs: Optional[KVPairs],
@@ -3523,7 +3536,9 @@ class GlobalServer:
             k_reparks: List[Message] = []
             completed = False
             opened = False
-            with self._mu.stripe(k):
+            # ``lock_us`` of the open span (``global.push``, or the lane)
+            with (self._mu.stripe(k) if not _tctx.ACTIVE
+                  else self._tr.locked(self._mu.stripe(k))):
                 st = self._keys.setdefault(k, _GlobalKeyState())
                 if (gate and st.accum is not None
                         and sender_s in st.contributors):
@@ -3642,42 +3657,60 @@ class GlobalServer:
                         k, self.store[k],
                         _mutable_round(self._backend, accum),
                         1.0 / self.num_contributors)
-            with self._wv_mu:
+            # the swap waits for ``_wv_mu`` while a pull's read holds it
+            # across a device-resident key's copy off the chip
+            # (:meth:`_weight_wv`): ``global.swap``'s ``lock_us``
+            with self._tr.span("global.swap", key=k) as sp, \
+                    (self._wv_mu if sp is _NULL_SPAN
+                     else sp.locked(self._wv_mu)):
                 self.store[k] = new_w
                 st.ver += 1
         st.accum = None
         st.count = 0
         st.contributors.clear()
-        with self._ack_mu:
+        # the rest of the close, a child span each, so that what is left
+        # of ``global.close``'s and ``global.opt``'s self time is theirs
+        with self._tr.span("global.acks", key=k), self._ack_mu:
             for ent in st.parked_pushes:
                 ent[1].discard(k)
                 if not ent[1]:
                     to_ack.append((ent[0], None))
         st.parked_pushes.clear()
-        reparks.extend(self._serve_parked_pulls_locked(k))
+        if st.parked_pulls:
+            with self._tr.span("global.serve", key=k,
+                               pulls=len(st.parked_pulls)):
+                reparks.extend(self._serve_parked_pulls_locked(k))
         if st.deferred:
-            # replay pushes the same-sender fence parked for the round
-            # that just opened.  An item whose sender is already in the
-            # NEW round (two deferred rounds from one party) re-defers;
-            # per-sender FIFO is preserved.  A cascade close recurses —
-            # depth is bounded by the backlog / num_contributors
-            backlog, st.deferred = st.deferred, []
-            for item in backlog:
-                d_sender, v, ent, donated = item
-                if st.accum is not None and d_sender in st.contributors:
-                    st.deferred.append(item)
-                    continue
-                if st.accum is None:
-                    st.accum = self._backend.seed(v, donated, key=k)
-                else:
-                    st.accum = self._backend.accumulate(st.accum, v)
-                st.count += 1
-                st.parked_pushes.append(ent)
-                st.contributors.add(d_sender)
-                if st.count >= self.num_contributors:
-                    # _merge_finish only counts the outer close
-                    self.key_rounds += 1
-                    self._complete_key_locked(k, False, to_ack, reparks)
+            with self._tr.span("global.replay", key=k,
+                               pushes=len(st.deferred)):
+                self._replay_deferred_locked(k, st, to_ack, reparks)
+
+    def _replay_deferred_locked(self, k: int, st: "_GlobalKeyState",
+                                to_ack: List[tuple],
+                                reparks: List[Message]) -> None:
+        """The tail of :meth:`_close_key_locked` (same stripe held):
+        replay pushes the same-sender fence parked for the round
+        that just opened.  An item whose sender is already in the
+        NEW round (two deferred rounds from one party) re-defers;
+        per-sender FIFO is preserved.  A cascade close recurses —
+        depth is bounded by the backlog / num_contributors"""
+        backlog, st.deferred = st.deferred, []
+        for item in backlog:
+            d_sender, v, ent, donated = item
+            if st.accum is not None and d_sender in st.contributors:
+                st.deferred.append(item)
+                continue
+            if st.accum is None:
+                st.accum = self._backend.seed(v, donated, key=k)
+            else:
+                st.accum = self._backend.accumulate(st.accum, v)
+            st.count += 1
+            st.parked_pushes.append(ent)
+            st.contributors.add(d_sender)
+            if st.count >= self.num_contributors:
+                # _merge_finish only counts the outer close
+                self.key_rounds += 1
+                self._complete_key_locked(k, False, to_ack, reparks)
 
     def _merge_finish(self, to_ack: List[tuple],
                       reparks: List[Message],
@@ -3932,7 +3965,8 @@ class GlobalServer:
         arrived in separate INITs)."""
         for k in m.keys:
             k = int(k)
-            with self._mu.stripe(k):
+            with (self._mu.stripe(k) if not _tctx.ACTIVE
+                  else self._tr.locked(self._mu.stripe(k))):
                 if k not in self.store:
                     self._keys.setdefault(
                         k, _GlobalKeyState()).parked_pulls.append(m)
@@ -3989,7 +4023,11 @@ class GlobalServer:
         the stamp never under-reporting.  The term rides the high bits:
         a promoted standby restarts per-key counters at 0 but its
         bumped term keeps the stamps monotonic across the failover."""
-        with self._wv_mu:
+        # the read materializes a device-resident key (``be.d2h``) with
+        # ``_wv_mu`` held: a round close's swap waits for it, and says so
+        # (``global.swap``'s ``lock_us``, holder known)
+        with (self._wv_mu if not _tctx.ACTIVE
+              else self._tr.locked(self._wv_mu)):
             st = self._keys.get(k)
             return self.store[k], ((self.term << 48)
                                    + (st.ver if st is not None else 0))
@@ -4012,7 +4050,9 @@ class GlobalServer:
         # cache and rng are shared across keys — a leaf lock (taken
         # under a stripe or the barrier, never the reverse) keeps them
         # coherent now that pull serving runs outside the big lock
-        with self._tr.span("codec.encode", of=req), self._pc_mu:
+        with self._tr.span("codec.encode", of=req) as sp, \
+                (self._pc_mu if sp is _NULL_SPAN
+                 else sp.locked(self._pc_mu)):  # ``lock_us``
             self._respond_pull_compressed_inner(req, typ, size_bound)
 
     def _respond_pull_compressed_inner(self, req: Message, typ,

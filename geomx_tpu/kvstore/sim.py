@@ -250,7 +250,9 @@ class Simulation:
 
     def flush_traces(self, timeout: float = 5.0) -> int:
         """Ship every node's pending spans and wait for the collector's
-        event count to settle; returns the number of collected events."""
+        event count to settle; returns the number of events it has
+        received (it keeps them all only where ``Config.trace_dir`` asks
+        for a dump: docs/tracing.md)."""
         if self.trace_collector is None:
             return 0
         from geomx_tpu.trace import get_tracer
@@ -262,7 +264,7 @@ class Simulation:
         deadline = _time.monotonic() + timeout
         last = -1
         while _time.monotonic() < deadline:
-            cur = len(self.trace_collector.merged_events())
+            cur = self.trace_collector.events_received
             if cur == last:
                 break
             last = cur
@@ -736,7 +738,28 @@ class Simulation:
         recv += sum(gs.po.van.wan_recv_bytes for gs in self.global_servers)
         return {"wan_send_bytes": send, "wan_recv_bytes": recv}
 
+    def _write_trace_report(self) -> None:
+        """``geomx_trace_report.json`` into ``Config.trace_dir`` (what
+        ``launch.py`` writes there for a deployment): every round's
+        blocking chain.  Best effort: a shutdown goes on without it."""
+        import json
+        import logging
+        import os
+
+        try:
+            self.flush_traces()
+            os.makedirs(self.config.trace_dir, exist_ok=True)
+            path = os.path.join(self.config.trace_dir,
+                                "geomx_trace_report.json")
+            with open(path, "w") as f:
+                json.dump(self.trace_collector.critical_path(), f, indent=1)
+        except Exception:
+            logging.getLogger(__name__).warning(
+                "trace report not written", exc_info=True)
+
     def shutdown(self):
+        if self.trace_collector is not None and self.config.trace_dir:
+            self._write_trace_report()
         for p in self.metrics_pumps.values():
             p.stop()
         if self.health is not None:

@@ -1104,7 +1104,9 @@ def main(argv=None):
         coll = getattr(po, "trace_collector", None)
         if coll is not None:
             # grace for the last TRACE_REPORT batches to land, then dump
-            # the merged timeline + critical-path report
+            # the merged timeline (the whole run where --trace-dir was
+            # given, else what the collector still holds: the last round
+            # or two) + the report of every round's blocking chain
             time.sleep(1.0)
             out_dir = cfg.trace_dir or "."
             os.makedirs(out_dir, exist_ok=True)

@@ -185,9 +185,15 @@ def run_worker_overlapped(
                   scale=scale)
 
     def _adopt_stage(i: int):
-        tracker.wait(i, round_no)
-        stage_params[i] = unflatten_params(
-            treedefs[i], [pulled[t] for t in stage_tids[i]])
+        # under the NEXT step's root (a stage's pulls gate that step's
+        # forward): ``worker.wait`` until the stage's pulls are in,
+        # ``edge.h2d`` for their way back onto the chip
+        with kv.trace_span("worker.wait", key=stage_tids[i][0]):
+            tracker.wait(i, round_no)
+        arrs = [pulled[t] for t in stage_tids[i]]
+        with kv.trace_span("edge.h2d", of=lambda: {
+                "nbytes": sum(a.nbytes for a in arrs)}):
+            stage_params[i] = unflatten_params(treedefs[i], arrs)
 
     history: List[Tuple[float, float]] = []
     round_no = 0

@@ -190,7 +190,8 @@ class Customer:
         The ``handle`` span is the boundary of every server and worker
         customer: how long the handler ran and, as ``queued_us``, how
         long the message waited from ``Van.send`` to this line (in-proc
-        delivery only: the stamp does not cross the wire)."""
+        delivery only: the stamp does not cross the wire); ``lane``
+        names the serial channel it ran on."""
         queued_us = ((time.monotonic() - msg.sent_mono) * 1e6
                      if msg.sent_mono else None)
         tr = self._tracer
@@ -200,10 +201,20 @@ class Customer:
             tr = self._tracer = get_tracer(str(self.postoffice.node))
         op = (("push_pull" if msg.pull else "push") if msg.push
               else "pull" if msg.pull else "ctrl")
+        # which of this customer's serial channels runs the handler (the
+        # split pull lane is a second one): on the reactor a channel
+        # hops between the pool's threads, and the collector's chain
+        # needs to know what ran in series with what
+        lane = f"c{self.app_id}.{self.customer_id}"
+        if (msg.request and msg.pull and not msg.push
+                and (self._pull_chan is not None
+                     or self._pull_q is not None)):
+            lane += "p"
         prev = _tctx.swap(_tctx.TraceContext(msg.trace_id, msg.span_id))
         try:
             with tr.span("handle", of=msg, cmd=int(msg.cmd), op=op,
-                         request=int(msg.request), queued_us=queued_us):
+                         request=int(msg.request), queued_us=queued_us,
+                         lane=lane):
                 self._handler(msg)
         finally:
             _tctx.restore(prev)

@@ -5,7 +5,10 @@ Causal spans (trace_id / span_id / parent_span_id carried on every
 push → local-merge → WAN → global-merge → pull chain across every node
 role; a collector on the global scheduler merges all parties' spans into
 one Chrome-trace/perfetto timeline (clock-corrected from heartbeat RTTs)
-and distills a per-round critical-path report.
+and gives every sampled round its blocking chain: each microsecond from
+the first worker's root opening to the last one's close put down to the
+one span, or recorded wait, that held it (``collector.blocking_chain``;
+``round.path`` is the chain as one instant on the profiler's clock).
 
 Off by default (``Config.trace_sample_every = 0``): every hot-path hook
 gates on one module flag and the span factory returns a shared no-op, so

@@ -64,13 +64,17 @@ def trace_id_for_round(round_idx: int) -> int:
 class TraceContext:
     """Immutable-by-convention (trace_id, span_id) the current thread is
     working under.  ``span_id`` is the id new child spans and outbound
-    messages use as their parent."""
+    messages use as their parent.  ``span`` is the open span object
+    where the context was installed by one (None where it was installed
+    from a message's ids): what a wait recorded further down the call
+    stack is added to (``Tracer.locked``)."""
 
-    __slots__ = ("trace_id", "span_id")
+    __slots__ = ("trace_id", "span_id", "span")
 
-    def __init__(self, trace_id: int, span_id: int):
+    def __init__(self, trace_id: int, span_id: int, span=None):
         self.trace_id = trace_id
         self.span_id = span_id
+        self.span = span
 
 
 def current() -> Optional[TraceContext]:

@@ -21,6 +21,27 @@ the causal ids and the site's arguments (``key``, ``nbytes``,
 session the spans sit on the host plane of the device's own trace.  With
 no session the annotation is one no-op call.
 
+Waits: a span that waited says so itself, in fields the collector's
+blocking chain reads (``trace/collector.py``): ``wait_us`` (a device
+value was not ready: :meth:`_Span.await_device`) and ``lock_us`` (a
+stripe or a leaf lock was held by another thread: :meth:`Tracer.locked`),
+each with the wait's place inside the span (``waits``).  A site tests
+``span is not _NULL_SPAN`` before it measures one, so an unsampled
+message pays that branch and nothing else.
+
+Memory: a worker's ``round`` root says, when it closes, the most the
+process has held (``rss_peak_MB``: ``ru_maxrss``, one syscall a sampled
+round).
+
+Nothing recorded here keeps anything alive: a span, a context and a
+wait record hold ids and numbers only (``of=`` is read once, when the
+span opens; a lock is known by ``id()`` and its holder by span id).
+
+Shipping: a batch of ``batch_events`` as before, and a worker also
+when its ``round`` root closes, which is what tells the collector that
+the round is over.  A flush never raises: what cannot be shipped (the
+collector's node is gone) stays pending, under ``_cap``.
+
 Overhead: ``span()`` / ``round()`` return the shared ``_NULL_SPAN``
 whenever tracing is inactive or the current thread carries no sampled
 context — no allocation, no branch beyond the gate, nothing stamped.
@@ -28,12 +49,17 @@ context — no allocation, no branch beyond the gate, nothing stamped.
 
 from __future__ import annotations
 
+import logging
+import resource
 import threading
 import time
 from typing import Dict, List, Optional
 
 from geomx_tpu.trace import context as _ctx
 from geomx_tpu.utils.profiler import Profiler, get_profiler
+
+
+_log = logging.getLogger(__name__)
 
 
 class _NullSpan:
@@ -57,6 +83,7 @@ DEFAULT_GROUP = "dense"
 # (NOT the benchmark's ``bench:``, which names the workers' phases)
 ANNOTATION_PREFIX = "geomx:"
 _annotate = None
+_block = None   # jax.block_until_ready, fetched by the first device wait
 
 
 def _annotation(name: str, args: dict):
@@ -69,6 +96,55 @@ def _annotation(name: str, args: dict):
 
         _annotate = TraceAnnotation
     return _annotate(name, **args)
+
+
+# a wait shorter than this is summed into its field (``wait_us``,
+# ``lock_us``) and not kept as an interval of the chain
+MIN_WAIT_US = 20.0
+# lock -> the span id of the sampled span that holds it (id(lock) keys:
+# a few stripes and leaf locks a server), so that a waiter can name the
+# holder; written on the sampled path only
+_HOLDERS: Dict[int, int] = {}
+
+
+class _TimedLock:
+    """``with`` a lock on behalf of an open span: the time to acquire it
+    goes to the span's ``lock_us``, and where it had to wait, to its
+    ``waits`` with the holder's span id (0 where no sampled span held
+    it)."""
+
+    __slots__ = ("_span", "_lock", "_prev")
+
+    def __init__(self, span: "_Span", lock):
+        self._span = span
+        self._lock = lock
+
+    def __enter__(self):
+        lock, span = self._lock, self._span
+        if lock.acquire(False):
+            span.add("lock_us", 0.0)
+        else:
+            holder = _HOLDERS.get(id(lock), 0)
+            t0 = time.monotonic()
+            lock.acquire()
+            span.waited("lock", "lock_us", t0, holder)
+        self._prev = _HOLDERS.get(id(lock), 0)
+        _HOLDERS[id(lock)] = span.span_id
+        return lock
+
+    def __exit__(self, *exc):
+        if self._prev:
+            _HOLDERS[id(self._lock)] = self._prev   # a re-entrant hold
+        else:
+            _HOLDERS.pop(id(self._lock), None)
+        self._lock.release()
+        return False
+
+
+def _rss_peak_MB() -> float:
+    """The most this process has held (``ru_maxrss``, KiB on Linux), in
+    MB of 1e6 bytes."""
+    return resource.getrusage(resource.RUSAGE_SELF).ru_maxrss * 1024 / 1e6
 
 
 def _carried(of) -> dict:
@@ -89,7 +165,8 @@ def _carried(of) -> dict:
 
 class _Span:
     __slots__ = ("_tr", "name", "cat", "_prev", "span_id", "parent",
-                 "trace_id", "args", "_ann", "_t0", "dur_us")
+                 "trace_id", "args", "_ann", "_t0", "dur_us", "_late",
+                 "waits")
 
     def __init__(self, tracer: "Tracer", name: str, cat: str,
                  trace_id: int, parent: int, of=None, args=None):
@@ -111,9 +188,49 @@ class _Span:
             self.args["group"] = tracer.key_groups.get(
                 self.args["key"], DEFAULT_GROUP)
         self.dur_us = 0.0
+        self._late = None   # fields known only at the close
+        self.waits = None   # [[kind, offset_us, dur_us, holder], ...]
+
+    def add(self, field: str, us: float) -> None:
+        """``us`` more of a field that sums (``wait_us``, ``lock_us``)."""
+        late = self._late
+        if late is None:
+            late = self._late = {}
+        late[field] = late.get(field, 0.0) + us
+
+    def waited(self, kind: str, field: str, t0: float, holder: int = 0):
+        """A wait of this span that began at monotonic ``t0`` and ended
+        now: summed into ``field``, and from ``MIN_WAIT_US`` up kept with
+        its place inside the span for the blocking chain."""
+        us = (time.monotonic() - t0) * 1e6
+        self.add(field, us)
+        if us >= MIN_WAIT_US:
+            if self.waits is None:
+                self.waits = []
+            self.waits.append([kind, (t0 - self._t0) * 1e6, us, holder])
+
+    def locked(self, lock) -> _TimedLock:
+        """``with span.locked(lock):`` the lock, the time to acquire it
+        in this span's ``lock_us``."""
+        return _TimedLock(self, lock)
+
+    def await_device(self, value):
+        """Wait HERE for a device value the site is about to copy off
+        the chip, and say how long as ``wait_us``: the copy would block
+        there anyway, so the schedule is the same and no device
+        operation is added."""
+        global _block
+        if _block is None:
+            from jax import block_until_ready
+
+            _block = block_until_ready
+        t0 = time.monotonic()
+        _block(value)
+        self.waited("device", "wait_us", t0)
 
     def __enter__(self):
-        self._prev = _ctx.swap(_ctx.TraceContext(self.trace_id, self.span_id))
+        self._prev = _ctx.swap(
+            _ctx.TraceContext(self.trace_id, self.span_id, self))
         self._ann = _annotation(
             self._tr.annotation_name(self.name),
             dict(self.args, trace_id=self.trace_id, span=self.span_id,
@@ -125,10 +242,21 @@ class _Span:
 
     def __exit__(self, *exc):
         self.dur_us = (time.monotonic() - self._t0) * 1e6
+        if self.cat == "round":
+            self._late = dict(self._late or (), rss_peak_MB=_rss_peak_MB())
+        if self._late:
+            self.args.update(self._late)
+            self._ann.set_metadata(**self._late)
         self._ann.__exit__(*exc)
         _ctx.restore(self._prev)
-        self._tr._record(self.name, self.cat, self.dur_us, self.trace_id,
-                         self.span_id, self.parent, self._t0, **self.args)
+        if self.waits:
+            self.args["waits"] = self.waits
+        tr = self._tr
+        tr._record(self.name, self.cat, self.dur_us, self.trace_id,
+                   self.span_id, self.parent, self._t0, **self.args)
+        if self.cat == "round":
+            # a worker's round is over, and the collector is told so
+            tr.flush()
         return False
 
 
@@ -174,6 +302,18 @@ class Tracer:
         if cur is None:
             return _NULL_SPAN
         return _Span(self, name, cat, cur.trace_id, cur.span_id, of, args)
+
+    def locked(self, lock):
+        """``with tracer.locked(lock):`` in place of ``with lock:`` at a
+        site that may wait for a key's stripe or a leaf lock: under an
+        open sampled span the time to acquire goes to that span's
+        ``lock_us``; with none the lock itself comes back.  Sites gate
+        on ``context.ACTIVE`` first, so that with tracing off they pay
+        one branch."""
+        cur = _ctx.current()
+        if cur is None or cur.span is None:
+            return lock
+        return cur.span.locked(lock)
 
     def round(self, round_idx: int, sample_every: int):
         """Root span of one sampled round: every node derives the same
@@ -247,32 +387,38 @@ class Tracer:
 
     def flush(self) -> int:
         """Ship every pending span to the collector; returns the count.
-        Safe to call with nothing attached (spans just keep pending)."""
+        Safe to call with nothing attached (spans just keep pending),
+        and never raises: it runs where a worker's ``round`` root closes
+        and where a node is being shut down, and a collector that is
+        down, unreachable or already stopped must cost the round
+        nothing.  What could not be shipped is re-queued (bounded by
+        ``_cap`` like everything else)."""
         with self._mu:
-            if not self._pending or self._po is None:
+            po, collector = self._po, self._collector
+            if not self._pending or po is None:
                 return 0
             batch, self._pending = self._pending, []
-        body = {"node": self.node, "spans": batch,
-                "offsets": self._po.clock_offsets()}
-        if self._collector is not None:
-            self._collector.ingest(body)
-            return len(batch)
-        from geomx_tpu.kvstore.common import APP_PS, Ctrl
-        from geomx_tpu.transport.message import Domain, Message
+        try:
+            body = {"node": self.node, "spans": batch,
+                    "offsets": po.clock_offsets()}
+            if collector is not None:
+                collector.ingest(body)
+                return len(batch)
+            from geomx_tpu.kvstore.common import APP_PS, Ctrl
+            from geomx_tpu.transport.message import Domain, Message
 
-        with _ctx.suppressed():  # trace traffic never traces itself
-            try:
-                self._po.van.send(Message(
-                    recipient=self._po.topology.global_scheduler(),
+            with _ctx.suppressed():  # trace traffic never traces itself
+                po.van.send(Message(
+                    recipient=po.topology.global_scheduler(),
                     domain=Domain.GLOBAL, app_id=APP_PS, customer_id=0,
                     request=True, cmd=int(Ctrl.TRACE_REPORT), body=body))
-            except (KeyError, OSError):
-                # collector down/unreachable: re-queue rather than lose
-                # the batch (bounded by _cap like everything else)
-                with self._mu:
-                    self._pending = batch + self._pending
-                    del self._pending[self._cap:]
-                return 0
+        except Exception:
+            _log.debug("%s: trace report not shipped", self.node,
+                       exc_info=True)
+            with self._mu:
+                self._pending = batch + self._pending
+                del self._pending[self._cap:]
+            return 0
         return len(batch)
 
     def pending(self) -> int:

@@ -26,6 +26,7 @@ import numpy as np
 
 from geomx_tpu.kvstore.client import WorkerKVStore
 from geomx_tpu.kvstore.keys import DENSE, leaf_groups
+from geomx_tpu.trace.recorder import _NULL_SPAN
 
 
 def save_params(path: str, params) -> None:
@@ -287,7 +288,12 @@ def _edge_to_host(kv: WorkerKVStore, tid: int, g, scale: float,
                            nbytes=getattr(g, "nbytes", None)):
             g = g / scale if divide else g * scale
     with kv.trace_span("edge.d2h", key=tid,
-                       nbytes=getattr(g, "nbytes", None)):
+                       nbytes=getattr(g, "nbytes", None)) as sp:
+        if sp is not _NULL_SPAN:
+            # a sampled round tells the wait for the leaf from its copy
+            # (``wait_us``: about 0 in ``run_worker``, which blocks on
+            # the gradient before the exchange; not in the staged loop)
+            sp.await_device(g)
         return np.asarray(g)
 
 
@@ -443,7 +449,10 @@ def run_worker(
         # step issues joins the round's cross-node trace.  Rounds are
         # numbered by exchanges, the same on every worker.
         with kv.trace_round(step // k1) if due else contextlib.nullcontext():
-            with m.phase("grad"):
+            # ``worker.grad``: the gradient program(s) up to the block
+            # below (the ``bench:`` phase of the same name is the
+            # benchmark's hook's, this is the program's own span)
+            with m.phase("grad"), kv.trace_span("worker.grad"):
                 ran, grads = [], None
                 while batch is not None:
                     # a model with routed experts hands back a fourth
@@ -514,8 +523,13 @@ def run_worker(
                         comm_s = time.perf_counter() - t1
                 with m.phase("pull_wait"):
                     kv.wait_all()
-        if due:
-            params = unflatten_params(treedef, buf)  # type: ignore[arg-type]
+                # the round ends when this worker holds the parameters
+                # it will step on: the pulled weights' way back onto the
+                # chip is inside the root
+                with kv.trace_span("edge.h2d", of=lambda: {
+                        "nbytes": sum(a.nbytes for a in buf)}):
+                    params = unflatten_params(
+                        treedef, buf)  # type: ignore[arg-type]
         m.step_end()
         history.extend((float(lo), float(ac)) for lo, ac in ran)
         if esync is not None:

@@ -259,6 +259,13 @@ class Message:
     # wan.recv pairing stays the source.
     sent_mono: float = dataclasses.field(default=0.0, repr=False,
                                          compare=False)
+    # the span open on the thread that called Van.send (sampled messages
+    # only; a response's ``parent_span_id`` is its REQUEST, not what sent
+    # it): the message's ``lan.send`` / ``wan.send`` instant carries it
+    # as ``by``, which is how the collector's blocking chain gets from a
+    # ``handle`` to the sender's thread.  Not on the wire either: the
+    # instant is recorded by the sender.
+    sent_by: int = dataclasses.field(default=0, repr=False, compare=False)
 
     _nbytes_cache: Optional[int] = dataclasses.field(
         default=None, repr=False, compare=False

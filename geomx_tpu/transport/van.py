@@ -753,18 +753,21 @@ class Van:
             # carries a trace (a response, a retransmit, a retarget
             # replay) keeps its ORIGINAL ids — replays show up as extra
             # children of the original round, never as a new trace.
-            if msg.trace_id == 0:
-                ctx = _tctx.current()
-                if ctx is not None:
-                    msg.trace_id = ctx.trace_id
-                    msg.parent_span_id = ctx.span_id
-                    msg.sampled = True
+            ctx = _tctx.current()
+            if msg.trace_id == 0 and ctx is not None:
+                msg.trace_id = ctx.trace_id
+                msg.parent_span_id = ctx.span_id
+                msg.sampled = True
             if msg.trace_id > 0:
                 if msg.span_id == 0:
                     msg.span_id = _tctx.new_span_id()
                 # the receiver's ``handle`` span reads it: send to the
                 # handler's first line, the van's own queue included
                 msg.sent_mono = time.monotonic()
+                # ...and the collector's chain, from that ``handle`` back
+                # to the span this thread has open (a response's parent
+                # is its request, not its sender)
+                msg.sent_by = ctx.span_id if ctx is not None else 0
         if self._use_send_thread and msg.control is Control.EMPTY:
             # negative: PriorityQueue pops smallest first, we want highest first
             self._pq.put((-msg.priority, next(self._pq_tie), msg))
@@ -820,7 +823,7 @@ class Van:
                                   span=msg.span_id,
                                   parent=msg.parent_span_id,
                                   trace_id=msg.trace_id, nbytes=n,
-                                  peer=str(msg.recipient))
+                                  peer=str(msg.recipient), by=msg.sent_by)
         if self.config.verbose >= 2:
             self._log_wire("SEND", msg, n)
 

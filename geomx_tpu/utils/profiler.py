@@ -17,17 +17,23 @@ server profiles showed.
 
 from __future__ import annotations
 
+import collections
 import json
 import threading
 import time
 from contextlib import contextmanager
-from typing import Dict, List, Optional
+from typing import Deque, Dict, Optional
+
+# the newest events a node's buffer keeps: the buffer lives as long as
+# the process (a registry by name), and the distributed tracer records
+# every sampled span into it whether or not anything will dump it
+MAX_EVENTS = 100_000
 
 
 class Profiler:
     def __init__(self, process_name: str = "geomx"):
         self.process_name = process_name
-        self._events: List[dict] = []
+        self._events: Deque[dict] = collections.deque(maxlen=MAX_EVENTS)
         self._counters: Dict[str, float] = {}
         self._mu = threading.Lock()
         self.running = False

@@ -3,7 +3,9 @@
 # (2 parties x 2 workers, 1 global server) with trace_sample_every=1,
 # training the demo CNN for a few rounds.  Asserts the merged trace is
 # non-empty, spans from >= 3 node roles are causally connected, and the
-# critical-path report names a dominant stage per round — then leaves
+# report gives every round its blocking chain (the labels sum to the
+# round's wall time, the chain is never lost) and names the dominant
+# stage by it — prints the chain, then leaves
 # the artifacts in ${GEOMX_TRACE_DIR:-/tmp/geomx_trace_demo} for
 # chrome://tracing / https://ui.perfetto.dev.
 set -euo pipefail
@@ -15,7 +17,6 @@ OUT="${GEOMX_TRACE_DIR:-/tmp/geomx_trace_demo}"
 mkdir -p "$OUT"
 
 python - "$OUT" <<'PY'
-import json
 import sys
 
 import jax
@@ -30,7 +31,11 @@ from geomx_tpu.training import run_worker
 out_dir = sys.argv[1]
 sim = Simulation(Config(topology=Topology(num_parties=2,
                                           workers_per_party=2),
-                        trace_sample_every=1))
+                        trace_sample_every=1,
+                        # a dump is asked for: the collector keeps the
+                        # merged timeline (and shutdown leaves the
+                        # report here too)
+                        trace_dir=out_dir))
 try:
     ws = sim.all_workers()
     ws[0].set_optimizer({"type": "sgd", "lr": 0.05})
@@ -64,8 +69,8 @@ try:
     assert report["rounds"], "critical-path report has no rounds"
     for r in report["rounds"]:
         assert r["dominant_stage"], r
-    with open(f"{out_dir}/geomx_trace_report.json", "w") as f:
-        json.dump(report, f, indent=1)
+        assert sum(r["path"].values()) == r["wall_us"] > 0, r
+        assert "chain_lost_at" not in r, r["chain_lost_at"]
     print(sim.trace_collector.report_text())
     print(f"OK: {len(evs)} events across {len(roles)} roles, "
           f"{len(report['rounds'])} rounds -> {out_dir}/geomx_trace.json")

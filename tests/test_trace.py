@@ -44,17 +44,41 @@ def _run_rounds(sim, rounds, tid=0, n=64):
             w.wait_all()
 
 
+def _train_rounds(sim, rounds, tid=0, n=64):
+    """The rounds as ``training.run_worker`` drives them: a thread a
+    worker, the wait for the pulls under the round's root (a round ends
+    when the worker holds what it pulled)."""
+    import threading
+
+    def loop(w):
+        for r in range(rounds):
+            with w.trace_round(r):
+                w.push(tid, np.full(n, 0.1, np.float32))
+                w.pull(tid, lambda t, a: None)
+                w.wait_all()
+
+    ths = [threading.Thread(target=loop, args=(w,))
+           for w in sim.all_workers()]
+    for t in ths:
+        t.start()
+    for t in ths:
+        t.join(60)
+    assert not any(t.is_alive() for t in ths)
+
+
 def test_e2e_chain_across_three_roles_and_critical_path(tmp_path):
     """Acceptance: merged trace connects one round's chain across
     worker / local server / global server, and the critical-path report
-    names a dominant stage per round."""
+    names a dominant stage per round: since ISSUE 39 the stage with the
+    largest share of the round's BLOCKING CHAIN, not of the summed
+    durations."""
     sim = Simulation(_trace_cfg())
     try:
         ws = sim.all_workers()
         ws[0].set_optimizer({"type": "sgd", "lr": 0.1})
         for w in ws:
             w.init(0, np.zeros(64, np.float32))
-        _run_rounds(sim, 3)
+        _train_rounds(sim, 3)
         assert sim.flush_traces() > 0
         evs = sim.trace_collector.merged_events()
         roles = {e["pid"].split(":")[0] for e in evs}
@@ -82,13 +106,25 @@ def test_e2e_chain_across_three_roles_and_critical_path(tmp_path):
         rounds = {r["round"]: r for r in rep["rounds"]}
         assert {0, 1, 2} <= set(rounds)
         for r in rounds.values():
+            # the chain covers the round exactly, reaches every role,
+            # and the dominant stage is the one it spent longest in
+            assert sum(r["path"].values()) == r["wall_us"] > 0
+            assert r.get("chain_lost_at") is None
+            assert {k.split(":")[0] for k in r["path"]} >= {
+                "worker", "server", "global_server"}
             assert r["dominant_stage"] in (
-                "lan_push", "local_merge", "codec", "wan", "global_merge",
-                "pull_fanout", "barrier")
-            assert r["stages"][r["dominant_stage"]]["worst_node"]
-        # the merged file dump is valid JSON with the same events
+                "compute", "edge", "lan_push", "local_merge", "codec",
+                "wan", "global_merge", "pull_fanout", "barrier", "queue",
+                "other", "unexplained")
+            dom = r["stages"][r["dominant_stage"]]
+            assert dom["worst_node"]
+            assert dom["path_us"] == max(
+                st["path_us"] for st in r["stages"].values())
+            assert all(st["busy_us"] >= 0 for st in r["stages"].values())
+        # the merged file dump is valid JSON with the same events (and a
+        # ``round.path`` instant the collector's own node shipped since)
         out = sim.dump_trace(str(tmp_path / "trace.json"))
-        assert len(out["traceEvents"]) == len(evs)
+        assert len(out["traceEvents"]) >= len(evs)
     finally:
         sim.shutdown()
 
@@ -244,6 +280,102 @@ def _backend_sites_get_the_null_span():
     assert st["merge_device_ms"] > 0 and st["opt_device_ms"] > 0
     fn = lambda: None  # noqa: E731
     assert _ctx_bound(fn, spy, 1) is fn
+
+
+def test_disabled_wait_sites_measure_and_allocate_nothing():
+    """ISSUE 40's sites with ``context.ACTIVE`` false, on whole rounds
+    of a ``Simulation`` with tracing off: every span site (the new
+    ``worker.grad``-side ones included) gets ``_NULL_SPAN``; no wait is
+    measured (``await_device``), no lock is wrapped (``locked``), no
+    message is stamped with its sender's span; and a disabled ``locked``
+    / ``await_device`` site allocates nothing that stays."""
+    import sys
+    import threading
+
+    was_active = tctx.ACTIVE
+    tctx.ACTIVE = False
+    try:
+        _new_sites_do_nothing_unsampled()
+        tr = Tracer("overhead-guard-waits")
+        lock = threading.Lock()
+        value = np.ones(4, np.float32)
+
+        def site():
+            # the two shapes the sites are written in (kvstore/server.py
+            # ``merge_one``; kvstore/jax_backend.py ``DeviceWeight.host``)
+            with (lock if not tctx.ACTIVE else tr.locked(lock)):
+                pass
+            with tr.span("be.d2h", key=1, nbytes=16) as sp:
+                if sp is not _NULL_SPAN:
+                    sp.await_device(value)
+
+        for _ in range(100):
+            site()
+        before = sys.getallocatedblocks()
+        for _ in range(10_000):
+            site()
+        assert sys.getallocatedblocks() - before < 50
+    finally:
+        tctx.ACTIVE = was_active
+
+
+def _new_sites_do_nothing_unsampled():
+    import threading
+
+    from geomx_tpu.trace import recorder
+    from geomx_tpu.training import _edge_to_host
+
+    seen, stamped = [], []
+    real_span = Tracer.span
+
+    def span(self, name, *a, **kw):
+        got = real_span(self, name, *a, **kw)
+        seen.append((name, got))
+        return got
+
+    def never(*a, **kw):
+        raise AssertionError("measured with tracing off")
+
+    patched = [(Tracer, "span", span), (Tracer, "locked", never),
+               (recorder._Span, "await_device", never),
+               (recorder._Span, "waited", never),
+               (recorder._Span, "add", never),
+               (recorder._TimedLock, "__init__", never)]
+    saved = [(o, n, getattr(o, n)) for o, n, _ in patched]
+    for o, n, f in patched:
+        setattr(o, n, f)
+    sim = Simulation(Config(topology=Topology(num_parties=1,
+                                              workers_per_party=1),
+                            merge_backend="jax"))
+    try:
+        w = sim.all_workers()[0]
+        w.set_optimizer({"type": "adam", "lr": 0.1})
+        w.init(0, np.zeros(4096, np.float32))
+        van_send = w.po.van.send
+
+        def send(msg, *a, **kw):
+            van_send(msg, *a, **kw)
+            stamped.append((msg.sent_by, msg.sent_mono, msg.trace_id))
+
+        w.po.van.send = send
+        done = threading.Event()
+        for r in range(2):
+            with w.trace_round(r):
+                w.push(0, _edge_to_host(w, 0, np.ones(4096, np.float32), 1.0))
+                w.pull(0, lambda t, a: done.set())
+                w.wait_all()
+        assert done.is_set()
+    finally:
+        sim.shutdown()
+        for o, n, f in saved:
+            setattr(o, n, f)
+    names = {n for n, _ in seen}
+    assert {"edge.d2h", "worker.push", "worker.pull", "worker.wait",
+            "worker.pull_decode", "local.push", "local.close", "be.d2h",
+            "local.pull_down", "global.push", "global.close", "global.opt",
+            "global.swap", "global.acks", "global.pull"} <= names, names
+    assert all(got is _NULL_SPAN for _, got in seen)
+    assert stamped and all(s == (0, 0.0, 0) for s in stamped)
 
 
 def test_disabled_tracing_no_per_message_work():
