@@ -70,6 +70,9 @@ class MergeBackend:
 
     name = "abstract"
     max_lanes: Optional[int] = None
+    # True where a closed round's copy to the host can be started and
+    # waited for apart (``materialize_async``: the jax backend)
+    async_copies = False
 
     def seed(self, v: np.ndarray, donated: bool, key: Optional[int] = None):
         """First push of a round: build and return the accumulator

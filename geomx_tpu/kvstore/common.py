@@ -139,6 +139,7 @@ class ShardExecutor:
                 import traceback
 
                 traceback.print_exc()
+            fn = None  # an idle lane keeps nothing of its last item alive
 
     def submit(self, key: int, fn: Callable[[], None]) -> None:
         if self.inline:

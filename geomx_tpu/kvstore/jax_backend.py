@@ -110,6 +110,62 @@ class _DeviceAccum:
         return total.tobytes()
 
 
+# Bytes of one backend's round closes that may be on their way off the
+# chip together (:meth:`JaxBackend.materialize_async`).  Four of the
+# flagship's largest leaves (16.7M float32, 67 MB): one v5e chip gave
+# 0.9 GB/s with one copy in flight, 1.85 with three and 2.9 with eight
+# (PERF.md section 6, PR 41), and a worker's slice edge feeds a local
+# server 1.8 GB/s, so three or four keep up with the feed; more would
+# only hold staged device buffers and host buffers for longer.  A key
+# larger than the bound is admitted alone.
+_COPIES_IN_FLIGHT_BYTES = 256 << 20
+
+
+class _HostCopy:
+    """One closed round on its way to the host: the reduction and the
+    copy were issued when this was built
+    (:meth:`JaxBackend.materialize_async`), :meth:`land` waits for the
+    rest of it.  ONE thread lands it (the local server's closer);
+    whoever asks again is handed the landed value."""
+
+    __slots__ = ("_be", "_dev", "_host", "_reserved", "key", "inflight")
+
+    def __init__(self, be: "JaxBackend", dev, key, reserved: int):
+        self._be = be
+        self._dev = dev
+        self._host: Optional[np.ndarray] = None
+        self._reserved = reserved   # bytes held against the bound
+        self.key = key
+        self.inflight = be._issue_copy(dev)
+
+    def land(self) -> np.ndarray:
+        """The round as host bytes nothing else sees (what
+        :meth:`JaxBackend.materialize` returns): the time a thread is
+        held for it is the span ``be.d2h``, as ever."""
+        if self._host is not None:
+            return self._host
+        be, dev = self._be, self._dev
+        try:
+            with be._timed("be.d2h", "merge_device_ms", self.key,
+                           dev.nbytes) as sp:
+                host = be._land(dev, sp, self.inflight)
+                be._bill_d2h(host.nbytes)
+                if be._platform == "cpu":
+                    # jax hands out a READ-ONLY array on every platform.
+                    # On an accelerator it is a fresh host buffer nothing
+                    # else sees, and goes on frozen: whoever builds in the
+                    # round copies at that point (count_cow).  On the CPU
+                    # client it is a VIEW of the device buffer, which may
+                    # itself alias the sender's non-donated payload (see
+                    # accumulate): this copy is the isolation copy
+                    host = host.copy()
+        finally:
+            self._dev = None   # the staged device buffer is let go
+            be._copy_done(self._reserved)
+        self._host = host
+        return host
+
+
 class _Timed:
     """One clock pair for a site that feeds an operator gauge
     (``merge_device_ms`` / ``opt_device_ms``) and, in a sampled round,
@@ -152,6 +208,9 @@ class JaxBackend(MergeBackend):
     # a device stream serializes dispatch; more lanes than this only
     # contend on the dispatch lock without overlapping device work
     max_lanes = 4
+    # a round close need not hold its thread for the copy off the chip
+    # (:meth:`materialize_async`): the local server keeps a closer
+    async_copies = True
 
     def __init__(self, config=None, tracer=None):
         import jax  # deliberate: constructing this backend IS the opt-in
@@ -209,6 +268,12 @@ class JaxBackend(MergeBackend):
         # the key's merge lane; the dict itself is GIL-safe per key.
         self._residuals: Dict[int, tuple] = {}
         self._mu = threading.Lock()  # counters + caches (leaf lock)
+        # copies off the chip issued and not read yet, and the bytes of
+        # them held against ``_COPIES_IN_FLIGHT_BYTES`` (``_room`` wakes
+        # a round close that waits for its turn)
+        self._room = threading.Condition(self._mu)
+        self._copies_in_flight = 0
+        self._bytes_in_flight = 0
         self.h2d_bytes = 0
         self.d2h_bytes = 0
         # bytes of materialized rounds a consumer copied because it had
@@ -301,27 +366,86 @@ class JaxBackend(MergeBackend):
     def materialize(self, acc) -> np.ndarray:
         if isinstance(acc, np.ndarray):
             return acc
-        with self._timed("be.d2h", "merge_device_ms", acc.key,
-                         4 * acc.elems) as sp:
-            dev = self._reduced(acc)
-            if sp is not _NULL_SPAN:
-                # a sampled round says how much of the span is the wait
-                # for the programs that produce the value (``wait_us``);
-                # the copy below would block there anyway
-                sp.await_device(dev)
-            host = np.asarray(dev)  # block + one D2H
-            with self._mu:
-                self.d2h_bytes += host.nbytes
-            if self._platform == "cpu":
-                # jax hands out a READ-ONLY array on every platform.  On
-                # an accelerator it is a fresh host buffer nothing else
-                # sees, and goes on frozen: whoever builds in the round
-                # copies at that point (count_cow).  On the CPU client
-                # it is a VIEW of the device buffer, which may itself
-                # alias the sender's non-donated payload (see
-                # accumulate): this copy is the isolation copy
-                host = host.copy()
-        return host
+        return _HostCopy(self, self._reduced(acc), acc.key, 0).land()
+
+    def materialize_async(self, acc: _DeviceAccum) -> _HostCopy:
+        """:meth:`materialize` of a device round in two halves: the
+        round's reduction and its copy off the chip are issued here,
+        and whoever needs the bytes waits for the rest in
+        :meth:`_HostCopy.land`, so that the caller's thread goes on to
+        the next message (whose H2D then runs beside this D2H) and
+        several keys' copies are in flight together.  Waits first, with
+        NO lock of the server held (the local tier calls it stripe
+        released), until the copies issued here and not landed leave
+        room under ``_COPIES_IN_FLIGHT_BYTES``.  Room is made by
+        :meth:`_HostCopy.land` alone: the caller hands each copy to the
+        thread that lands it BEFORE it asks for the next."""
+        nbytes = 4 * acc.elems
+        if not self._take_room(nbytes, wait=False):
+            # the thread is held for earlier copies to land: a
+            # ``be.d2h`` of its own (no ``inflight``: nothing was
+            # issued), opened with no lock held, so that the span's sum
+            # stays every second a thread was held for a landing
+            with self._tr.span("be.d2h", key=acc.key, nbytes=nbytes):
+                self._take_room(nbytes, wait=True)
+        return _HostCopy(self, self._reduced(acc), acc.key, nbytes)
+
+    def _take_room(self, nbytes: int, wait: bool) -> bool:
+        """``nbytes`` more in flight if the bound leaves room (a key
+        larger than the whole bound goes alone); else wait for it, or
+        say no."""
+        with self._room:
+            while (self._bytes_in_flight and self._bytes_in_flight + nbytes
+                   > _COPIES_IN_FLIGHT_BYTES):
+                if not wait:
+                    return False
+                self._room.wait()
+            self._bytes_in_flight += nbytes
+        return True
+
+    # ---- copies off the chip ------------------------------------------------
+    def _issue_copy(self, dev) -> int:
+        """Start ``dev``'s copy to the host (it begins once the value
+        exists; nothing waits here).  Returns the copies of this backend
+        in flight now, this one included (``inflight`` of its
+        ``be.d2h``)."""
+        with self._mu:
+            self._copies_in_flight += 1
+            n = self._copies_in_flight
+        dev.copy_to_host_async()
+        return n
+
+    def _land(self, dev, sp, inflight: int) -> np.ndarray:
+        """The host value of ``dev`` inside its open ``be.d2h`` span
+        ``sp``: blocks for what is left of the copy.  A sampled round
+        says how much of the span is the wait for the programs that
+        produce the value (``wait_us``; the copy would block there
+        anyway) and how many copies were in flight when this one was
+        issued (``inflight``)."""
+        if sp is not _NULL_SPAN:
+            sp.await_device(dev)
+            sp.add("inflight", inflight)
+        return np.asarray(dev)
+
+    def _copy_done(self, reserved: int = 0) -> None:
+        """A copy of :meth:`_issue_copy` was read (or its handle
+        replaced unread)."""
+        with self._room:
+            self._copies_in_flight -= 1
+            if reserved:
+                self._bytes_in_flight -= reserved
+                self._room.notify_all()
+
+    def _d2h(self, dev) -> np.ndarray:
+        """One value off the chip while the caller waits (the codec
+        stage's frames): span ``be.d2h`` around the issue and the
+        landing."""
+        inflight = self._issue_copy(dev)
+        try:
+            with self._tr.span("be.d2h", nbytes=dev.nbytes) as sp:
+                return self._land(dev, sp, inflight)
+        finally:
+            self._copy_done()
 
     def count_cow(self, nbytes: int) -> None:
         with self._mu:
@@ -527,15 +651,34 @@ class DeviceWeight:
     The update never donates the weight buffer: an in-flight pull
     response may still alias a previous ``host()`` view, and a donated
     (deleted) buffer under it would be a use-after-free on accelerator
-    backends."""
+    backends.
 
-    __slots__ = ("ref", "_be", "_host", "key")
+    ``prefetch``: the copy starts with the handle, at the round close
+    that made the value, and :meth:`host` finds it landed or waits for
+    the rest.  The round close asks for it where the handle this one
+    replaces was read (:meth:`replaced`): a key whose last version was
+    pulled will be pulled again, and a key updated several times
+    between reads (the async tier, HFA's off-rounds) is not copied for
+    nothing.  The one D2H is billed to ``d2h_bytes`` where it STARTS
+    (at the close for a prefetch, else at the read): a copy nobody
+    comes for has crossed all the same.  :meth:`host` is safe from
+    several threads (the pull channel and a round close's parked
+    pulls): the server holds no lock of its own across the copy."""
 
-    def __init__(self, be: "JaxBackend", ref, key=None):
+    __slots__ = ("ref", "_be", "_host", "key", "_mu", "_inflight",
+                 "_counted")
+
+    def __init__(self, be: "JaxBackend", ref, key=None,
+                 prefetch: bool = False):
         self.ref = ref
         self._be = be
         self._host: Optional[np.ndarray] = None
         self.key = key  # for the be.d2h span of host()
+        self._mu = threading.Lock()
+        self._inflight = 0      # copies in flight when this one started
+        self._counted = False   # this one is among the backend's still
+        if prefetch:
+            self._issue()
 
     @property
     def nbytes(self) -> int:  # store_bytes accounting without a D2H
@@ -544,15 +687,45 @@ class DeviceWeight:
     def __len__(self) -> int:
         return int(self.ref.shape[0])
 
+    @property
+    def was_read(self) -> bool:
+        return self._host is not None
+
+    def _issue(self) -> None:
+        self._inflight = self._be._issue_copy(self.ref)
+        self._counted = True
+        self._be._bill_d2h(self.ref.nbytes)
+
+    def _uncount(self) -> None:
+        if self._counted:
+            self._counted = False
+            self._be._copy_done()
+
+    def replaced(self) -> bool:
+        """The round close that swaps this handle out asks, once: was it
+        read?  A prefetch nobody came for leaves the backend's count of
+        copies in flight here.  Never waits: a reader that holds the
+        handle for its landing right now IS the read."""
+        if not self._mu.acquire(False):
+            return True
+        try:
+            self._uncount()
+            return self._host is not None
+        finally:
+            self._mu.release()
+
     def host(self) -> np.ndarray:
         if self._host is None:
-            with self._be._tr.span("be.d2h", key=self.key,
-                                   nbytes=self.ref.nbytes) as sp:
-                if sp is not _NULL_SPAN:
-                    sp.await_device(self.ref)  # ``wait_us``
-                h = np.asarray(self.ref)  # one D2H (zero-copy view on cpu)
-            self._be._bill_d2h(h.nbytes)
-            self._host = h
+            with self._mu:
+                if self._host is None:
+                    if not self._inflight:
+                        self._issue()
+                    with self._be._tr.span("be.d2h", key=self.key,
+                                           nbytes=self.ref.nbytes) as sp:
+                        # zero-copy view on cpu
+                        h = self._be._land(self.ref, sp, self._inflight)
+                    self._uncount()
+                    self._host = h
         return self._host
 
 
@@ -600,7 +773,8 @@ class DeviceOptimizer:
             w = self._weight_ref(raw_w)
             g = self._grad_ref(accum)
             new = self._update(k, w, g, float(scale))
-        return DeviceWeight(self._be, new, key=k)
+        return DeviceWeight(self._be, new, key=k,
+                            prefetch=self._was_read(raw_w))
 
     def add_delta(self, raw_w, accum) -> DeviceWeight:
         """HFA milestone-delta close: ``weight + accum`` on device (no
@@ -610,7 +784,13 @@ class DeviceOptimizer:
             w = self._weight_ref(raw_w)
             g = self._grad_ref(accum)
             new = w + g  # NOT the donated add: w must stay alive (aliases)
-        return DeviceWeight(self._be, new, key=key)
+        return DeviceWeight(self._be, new, key=key,
+                            prefetch=self._was_read(raw_w))
+
+    @staticmethod
+    def _was_read(raw) -> bool:
+        """The prefetch rule: the handle a close replaces was read."""
+        return isinstance(raw, DeviceWeight) and raw.replaced()
 
     def _weight_ref(self, raw):
         if isinstance(raw, DeviceWeight):
@@ -871,10 +1051,7 @@ class CodecStage:
         """Full-tensor D2H for the fallback event paths (degraded-round
         absorb, adaptive raw stash) — billed to ``codec_host_bytes`` so
         the steady-state "host copies == 0" contract stays auditable."""
-        with self._be._tr.span("be.d2h", nbytes=v.nbytes) as sp:
-            if sp is not _NULL_SPAN:
-                sp.await_device(v)  # ``wait_us``
-            host = np.asarray(v)
+        host = self._be._d2h(v)
         with self._be._mu:
             self._be.codec_host_bytes += host.nbytes
         return host
@@ -897,10 +1074,7 @@ class CodecStage:
         THE single D2H of the device encode path (compressed bytes only,
         billed to ``codec_d2h_bytes``).  The returned view keeps the
         device buffer alive; senders ship it donated and never mutate."""
-        with self._be._tr.span("be.d2h", nbytes=payload.nbytes) as sp:
-            if sp is not _NULL_SPAN:
-                sp.await_device(payload)  # ``wait_us``: the encoder
-            host = np.asarray(payload)
+        host = self._be._d2h(payload)  # its ``wait_us``: the encoder
         with self._be._mu:
             self._be.codec_d2h_bytes += host.nbytes
         return host

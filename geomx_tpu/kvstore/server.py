@@ -29,6 +29,7 @@ loudly.
 
 from __future__ import annotations
 
+import queue
 import threading
 import time
 from typing import Dict, List, Optional
@@ -72,7 +73,7 @@ def _one_span(prof, tracer, name: str, msg):
     return prof.span(name) if sp is _NULL_SPAN else sp
 
 
-def _ctx_bound(fn, lane_tracer=None, key=None):
+def _ctx_bound(fn, lane_tracer=None, key=None, name: str = "lane"):
     """Carry the calling (handler) thread's trace context onto a merge
     lane: a sampled round's merge spans — and the WAN push-up messages
     the lane sends at round completion — must stay children of the
@@ -82,7 +83,9 @@ def _ctx_bound(fn, lane_tracer=None, key=None):
     ``lane_tracer`` is the server's tracer where its lanes are THREADS
     (``ShardExecutor.inline`` false; None where they run inline, the
     reactor default): the bound item then runs under a ``lane`` span
-    that carries its own wait, submit to start, as ``queued_us``."""
+    that carries its own wait, submit to start, as ``queued_us``
+    (``name``: the local server's closer calls its turns
+    ``local.land``)."""
     if not _tctx.ACTIVE:
         return fn
     ctx = _tctx.current()
@@ -97,13 +100,66 @@ def _ctx_bound(fn, lane_tracer=None, key=None):
                 fn()
             else:
                 with lane_tracer.span(
-                        "lane", key=key,
+                        name, key=key,
                         queued_us=(time.monotonic() - submitted) * 1e6):
                     fn()
         finally:
             _tctx.restore(prev)
 
     return bound
+
+
+# how long a handler waits for the lanes and the closer before a state
+# change (:meth:`LocalServer._quiesce`): ``ShardExecutor.drain``'s own
+_QUIESCE_S = 30.0
+
+
+class _RoundCloser:
+    """The local server's closer: ONE thread that runs what it is
+    handed in the order it was handed (the landings of closed rounds'
+    copies off the chip, then their acks and push-ups:
+    :meth:`LocalServer._after_landing`)."""
+
+    def __init__(self, name: str):
+        self._q: "queue.SimpleQueue" = queue.SimpleQueue()
+        self._mu = threading.Lock()
+        self._pending = 0
+        threading.Thread(target=self._run, name=name, daemon=True).start()
+
+    def _run(self):
+        while True:
+            fn = self._q.get()
+            if fn is None:
+                return
+            try:
+                fn()
+            except Exception:  # pragma: no cover - surfaced via logs
+                import traceback
+
+                traceback.print_exc()
+            fn = None  # an idle closer keeps no round alive
+            with self._mu:
+                self._pending -= 1
+
+    def submit(self, fn) -> None:
+        with self._mu:
+            self._pending += 1
+        self._q.put(fn)
+
+    @property
+    def pending(self) -> int:
+        """Items handed over and not finished."""
+        return self._pending
+
+    def drain(self, timeout: Optional[float]) -> bool:
+        """Everything handed over before this call has run; False at
+        the timeout.  Never from the closer itself."""
+        done = threading.Event()
+        self.submit(done.set)
+        return done.wait(timeout)
+
+    def stop(self):
+        self._q.put(None)
 
 
 def _handle_profiler_cmd(po: Postoffice, msg: Message, server: KVServer):
@@ -462,6 +518,15 @@ class LocalServer:
             self.config, postoffice.node, self._backend)
         # the ``lane`` span of _ctx_bound: only where lanes are threads
         self._lane_tr = None if self._shards.inline else self._tr
+        # the round closer: where the backend can start a closed
+        # round's copy off the chip and wait for it apart (jax), ONE
+        # serial thread of this server waits for each copy to land, in
+        # the order the rounds were decided, and then acks and ships
+        # (:meth:`_after_landing`); the push channel and the lanes go on
+        # to the next message meanwhile.  None on the numpy backend
+        # (deterministic mode included): every close is inline there.
+        self._closer = (_RoundCloser(f"close-{postoffice.node}")
+                        if self._backend.async_copies else None)
         self._ctr_mu = threading.Lock()  # leaf lock for shared counters
         #                                  bumped from parallel lanes
         # flight recorder (obs/flight.py): fence/fold/round events +
@@ -625,7 +690,7 @@ class LocalServer:
         # arrived after earlier pushes must not be applied while those
         # pushes still sit queued on merge lanes (they would merge into
         # the restored state); quiesce the lanes first
-        self._shards.drain()
+        self._quiesce()
         # replay dedup: a replayed overwrite-init re-applied after
         # training resumed would silently revert the store (plain init
         # replay was idempotent; overwrite replay is destructive)
@@ -711,6 +776,7 @@ class LocalServer:
             # leaver HAD contributed to a mid-flight round, one later
             # push leaks into the next round (one stale gradient, the
             # same staleness class the async tier tolerates).
+            self._quiesce()
             with self._mu:
                 if self._fold_member_out_locked(node_s):
                     self.left_workers += 1
@@ -852,6 +918,7 @@ class LocalServer:
             return False  # party_fold/unfold belong to the global tier
         node_s = str(body["node"])
         boot = int(body.get("boot", 0))
+        self._quiesce()
         with self._mu:
             folded = self._fold_member_out_locked(node_s)
             if folded:
@@ -884,6 +951,7 @@ class LocalServer:
         early) and restore it verbatim when heartbeats resume.
         Idempotent both ways."""
         node_s = str(body["node"])
+        self._quiesce()
         with self._mu:
             if action == "quarantine":
                 rank = self._members.get(node_s)
@@ -952,11 +1020,17 @@ class LocalServer:
         crosses ``poison_quarantine_n``.  Returns the typed error body
         the push's ack path sends instead of a clean ack."""
         quarantined = False
+        n = self.config.poison_quarantine_n
+        if (n and self._poison_strikes.get(sender_s, 0) + 1 >= n
+                and sender_s in self._members):
+            # this strike folds the sender out: like every fold, behind
+            # the rounds already decided (a strike that does not is on
+            # the push ingest path and waits for nothing)
+            self._quiesce()
         with self._mu:
             self.integrity_poison_rejects += 1
             strikes = self._poison_strikes.get(sender_s, 0) + 1
             self._poison_strikes[sender_s] = strikes
-            n = self.config.poison_quarantine_n
             if n and strikes >= n and sender_s in self._members:
                 rank = self._members.get(sender_s)
                 if self._fold_member_out_locked(sender_s):
@@ -1063,8 +1137,8 @@ class LocalServer:
         and install them — aborting any stale in-flight aggregation
         state (a revived zombie's open rounds refer to a world that
         moved on).  Returns the number of keys adopted."""
-        self._shards.drain()  # stale pre-crash merges must not land on
-        #                       the adopted state
+        self._quiesce()  # stale pre-crash merges must not land on
+        #                  the adopted state
         keys = set()
         for gs in list(self.up.targets):
             # retried + timeout-bounded: control commands have no
@@ -1390,7 +1464,9 @@ class LocalServer:
             else self.config.preempt_drain_s
         deadline = t0 + budget
         # 1. flush: wait for open WAN push batches to collect their acks
-        #    (bounded — a dark global tier must not eat the whole notice)
+        #    (bounded — a dark global tier must not eat the whole notice);
+        #    a round still with the closer becomes such a batch first
+        self._quiesce(budget)
         while time.monotonic() < deadline:
             with self._ctr_mu:
                 inflight = self._wan_inflight
@@ -1604,18 +1680,68 @@ class LocalServer:
                 pending[0] -= 1
                 last = pending[0] == 0
             if last:
-                self._push_merged(msg, kvs, bundles)
+                self._after_landing(bundles, lambda: self._push_merged(
+                    msg, kvs, bundles))
 
         for k, v in slices:
             self._shards.submit(k, _ctx_bound(
                 lambda k=k, v=v: merge_one(k, v), self._lane_tr, k))
 
+    def _quiesce(self, timeout: Optional[float] = None) -> bool:
+        """Every merge queued before this call has run and every round
+        it closed has been acked and shipped: the lanes, then the closer
+        they feed.  What a handler calls before it changes the state
+        those would land on; never from a lane or the closer itself.
+
+        False at the timeout (``_QUIESCE_S`` unless given), and says so
+        loudly: the caller goes on, as it always did past a lane that
+        would not drain, and what is still with the closer lands on the
+        state as it is then.  The rounds themselves keep their order
+        whatever the caller does next (:meth:`_after_landing` queues
+        behind what is pending)."""
+        if timeout is None:
+            timeout = _QUIESCE_S
+        ok = self._shards.drain(timeout)
+        if self._closer is not None:
+            ok = self._closer.drain(timeout) and ok
+        if not ok:
+            left = self._closer.pending if self._closer is not None else 0
+            print(f"{self.po.node}: NOT quiesced after {timeout:.1f}s "
+                  f"(lanes {self._shards.depth()} deep, {left} with the "
+                  "closer): the state change goes ahead of merges still "
+                  "queued", flush=True)
+        return ok
+
+    def _after_landing(self, bundles: List[dict], then) -> None:
+        """Run ``then()`` (the ack and :meth:`_dispatch_rounds` of the
+        rounds in ``bundles``) once every copy of theirs has landed, and
+        after everything the closer was handed earlier.  Inline where
+        the closer has nothing (the numpy backend, the device codec's
+        handle, copies that have landed since) or the push closed no
+        round; else on the closer, behind the landings
+        :meth:`_materialize_round` handed it (``be.d2h``), while the
+        caller goes on.  Per key, rounds and acks so leave in the order
+        they were decided; a push is acked only behind the landing of
+        the copy that read its staged buffer."""
+        def close():
+            for b in bundles:
+                if "copy" in b:
+                    b["v"] = b.pop("copy").land()
+            then()
+
+        if not bundles or self._closer is None or not self._closer.pending:
+            close()
+        else:
+            self._closer.submit(_ctx_bound(
+                close, self._tr, bundles[0]["k"], "local.land"))
+
     def _push_merged(self, msg: Message, kvs: KVPairs,
                      bundles: List[dict]):
-        """Post-merge step of one push message, on the lane that
-        finished its last slice: ack (or park the piggyback pull), then
-        dispatch any rounds the message completed.  Runs with no
-        stripes held."""
+        """Post-merge step of one push message: ack (or park the
+        piggyback pull), then dispatch any rounds the message
+        completed.  On the lane that finished its last slice, or on
+        the closer once those rounds' copies have landed
+        (:meth:`_after_landing`).  Runs with no stripes held."""
         poisoned = getattr(msg, "_gx_poisoned", None)
         if not self.sync_mode:
             # async local tier: no rounds — clear the aggregation state
@@ -1768,13 +1894,13 @@ class LocalServer:
                 if (st.count >= (st.expected or self.num_workers)
                         and not st.completing):
                     bundle = self._take_completed_locked(key)
+            bundles = []
             if bundle is not None:
-                self._materialize_round(bundle)
-            err = getattr(msg, "_gx_poisoned", None)
-            self._recent.mark_done(msg, err)
-            self.server.response(msg, body=err)
-            if bundle is not None:
-                self._dispatch_rounds([bundle])
+                bundles.append(self._materialize_round(bundle))
+            # the ack (or the typed reject), then the round: the dense
+            # push's tail, behind any earlier close still with the closer
+            self._after_landing(bundles, lambda: self._push_merged(
+                msg, kvs, bundles))
 
         self._shards.submit(key, _ctx_bound(merge_rs, self._lane_tr, key))
 
@@ -1860,22 +1986,36 @@ class LocalServer:
         st.row_sparse = False  # describes this round only
         return bundle
 
-    def _materialize_round(self, bundle: dict) -> dict:
+    def _materialize_round(self, bundle: dict, wait: bool = True) -> dict:
         """The detached accumulator becomes the value
         :meth:`_dispatch_rounds` ships: the device codec's handle, or
-        the wait for the device and the round's one D2H (``be.d2h``).
-        No stripe is needed (the accumulator is this thread's alone
-        since the detach), so none is held on the hot path: the key's
-        own pulls stay parked on ``in_flight``, every other key's
-        pulls and pull-downs go on meanwhile.  Runs on the thread that
-        detached the round and BEFORE the completing push is acked: a
-        worker's push aliases its caller's buffer until the ack and the
-        staged H2D reads that buffer asynchronously — this blocking D2H
-        is what retires the alias."""
+        the round's one D2H (``be.d2h``).  No stripe is needed (the
+        accumulator is this thread's alone since the detach), so none
+        is held on the hot path: the key's own pulls stay parked on
+        ``in_flight``, every other key's pulls and pull-downs go on
+        meanwhile.
+
+        Where the server has a closer a device round's copy is only
+        STARTED here (``bundle["copy"]``, the value on its way in place
+        of ``"v"``), after a wait for room among the copies in flight
+        (``wait``; not under the fold's barrier, where the copy is
+        waited for as before), and its landing is handed to the closer
+        AT ONCE, before the message's next key asks for room: room is
+        made by landings alone, so one message of many keys never waits
+        on copies only its own tail could land.  Either way the copy
+        has landed BEFORE the completing push is acked
+        (:meth:`_after_landing`): a worker's push aliases its caller's
+        buffer until the ack and the staged H2D reads that buffer
+        asynchronously — the landed D2H is what retires the alias."""
         acc = bundle.pop("acc")
-        bundle["v"] = (self._codec_stage.round_value(acc)
-                       if bundle.pop("keep_device")
-                       else self._backend.materialize(acc))
+        if bundle.pop("keep_device"):
+            bundle["v"] = self._codec_stage.round_value(acc)
+        elif self._closer is None or not wait or isinstance(acc, np.ndarray):
+            bundle["v"] = self._backend.materialize(acc)
+        else:
+            copy = bundle["copy"] = self._backend.materialize_async(acc)
+            self._closer.submit(_ctx_bound(
+                copy.land, self._tr, bundle["k"], "local.land"))
         return bundle
 
     def _dispatch_rounds(self, bundles: List[dict]):
@@ -1923,9 +2063,11 @@ class LocalServer:
         """Complete rounds already decided for ``keys`` — the
         membership-fold path (caller holds the all-stripes barrier, so
         the per-key takes below just re-enter their stripes)."""
-        self._dispatch_rounds(
-            [self._materialize_round(self._take_completed_locked(k))
-             for k in sorted(keys)])
+        bundles = [self._materialize_round(self._take_completed_locked(k),
+                                           wait=False)
+                   for k in sorted(keys)]
+        self._after_landing(bundles,
+                            lambda: self._dispatch_rounds(bundles))
 
     def _apply_local(self, kvs: KVPairs):
         """HFA off-round: the merged push is already the party-mean weight
@@ -2646,7 +2788,7 @@ class LocalServer:
                        Ctrl.SET_HFA):
             # these flip how queued merges would be interpreted; keep
             # the handler-thread program order vs. the merge lanes
-            self._shards.drain()
+            self._quiesce()
         if msg.cmd == Ctrl.SET_SYNC_MODE:
             self.sync_mode = bool(body["sync"])
         elif msg.cmd == Ctrl.SET_COMPRESSION:
@@ -2839,6 +2981,9 @@ class LocalServer:
         if self.ts_push_inter is not None:
             self._merge_q.put(None)
         self._shards.stop()
+        if self._closer is not None:
+            self._closer.drain(5.0)  # nothing pending when it goes
+            self._closer.stop()
         self._backend.stop()
         self.server.stop()
         self.up.stop()
@@ -3657,9 +3802,10 @@ class GlobalServer:
                         k, self.store[k],
                         _mutable_round(self._backend, accum),
                         1.0 / self.num_contributors)
-            # the swap waits for ``_wv_mu`` while a pull's read holds it
-            # across a device-resident key's copy off the chip
-            # (:meth:`_weight_wv`): ``global.swap``'s ``lock_us``
+            # ``_wv_mu`` pairs the store write with the ver bump; a
+            # pull's read holds it for the pairing alone
+            # (:meth:`_weight_wv`), so ``global.swap``'s ``lock_us`` is
+            # the wait for another key's swap at most
             with self._tr.span("global.swap", key=k) as sp, \
                     (self._wv_mu if sp is _NULL_SPAN
                      else sp.locked(self._wv_mu)):
@@ -4023,14 +4169,16 @@ class GlobalServer:
         the stamp never under-reporting.  The term rides the high bits:
         a promoted standby restarts per-key counters at 0 but its
         bumped term keeps the stamps monotonic across the failover."""
-        # the read materializes a device-resident key (``be.d2h``) with
-        # ``_wv_mu`` held: a round close's swap waits for it, and says so
-        # (``global.swap``'s ``lock_us``, holder known)
+        # ``_wv_mu`` pairs the handle with its version and no more: a
+        # device-resident key comes off the chip (``be.d2h``: the rest
+        # of the copy its round close started, or all of it) with the
+        # lock released, so a round close's swap never waits for a copy
         with (self._wv_mu if not _tctx.ACTIVE
               else self._tr.locked(self._wv_mu)):
             st = self._keys.get(k)
-            return self.store[k], ((self.term << 48)
-                                   + (st.ver if st is not None else 0))
+            w = self.store.raw(k)
+            wv = (self.term << 48) + (st.ver if st is not None else 0)
+        return (w if isinstance(w, np.ndarray) else w.host()), wv
 
     def _respond_pull_compressed(self, req: Message):
         """Pull-direction compression (the second half of Bi-Sparse,

@@ -41,6 +41,7 @@ _STAGES = (
     ("worker.push", "lan_push"),
     ("local.push", "local_merge"),
     ("local.close", "local_merge"),
+    ("local.land", "local_merge"),
     ("local.init", "local_merge"),
     ("codec.", "codec"),
     ("wan.", "wan"),

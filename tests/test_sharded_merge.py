@@ -239,10 +239,29 @@ def test_deterministic_mode_forces_single_shard():
 
 # ---- the round close off the server's lock (ISSUE 27) ------------------------
 
+def _before_landing(be, hook):
+    """Run ``hook(key)`` where a closed round's copy off the chip is
+    waited for (``_HostCopy.land``, on the server's closer since
+    ISSUE 41; the copy itself was started by ``materialize_async``)."""
+    start = be.materialize_async
+
+    class _Hooked:
+        def __init__(self, copy):
+            self._copy, self._hook = copy, hook
+
+        def land(self):     # the closer lands it; the ack's turn re-reads
+            if self._hook is not None:
+                self._hook, first = None, self._hook
+                first(self._copy.key)
+            return self._copy.land()
+
+    be.materialize_async = lambda acc: _Hooked(start(acc))
+
+
 def test_round_close_d2h_holds_no_stripe():
     """The stripe is held to DECIDE and DETACH a round, never to wait
     for the device or to copy the model.  Under the reactor default a
-    server has ONE stripe: wedge ``materialize`` (the round close's
+    server has ONE stripe: wedge the landing (the round close's
     D2H) of key B and everything else that takes the lock must go on
     — a worker's pull of key A, a pull-down of key A — while key B's
     own pull stays parked behind its round (``in_flight``, set at the
@@ -260,15 +279,13 @@ def test_round_close_d2h_holds_no_stripe():
         assert ls._mu.n == 1, "the reactor default is one lock a server"
         ka, kb = (w.plan.parts(t, 64)[0].ps_key for t in (0, 1))
         wedged, release = threading.Event(), threading.Event()
-        materialize = ls._backend.materialize
 
-        def wedge(acc):
-            if acc.key == kb:
+        def wedge(key):
+            if key == kb:
                 wedged.set()
                 assert release.wait(20)
-            return materialize(acc)
 
-        ls._backend.materialize = wedge
+        _before_landing(ls._backend, wedge)
         try:
             w.push(1, np.ones(64, np.float32))   # one worker: closes B
             assert wedged.wait(5)
@@ -308,10 +325,10 @@ def test_round_close_d2h_holds_no_stripe():
 
 def test_pushes_up_keep_per_key_order_across_lanes():
     """With merge lanes as threads (three stripes, three keys) each
-    lane detaches, materializes and ships its key's rounds in turn, so
-    a key's rounds reach the global tier in the order they closed
-    whatever the D2H of each took — 20 back-to-back rounds, the D2H
-    jittered."""
+    lane detaches its key's rounds in turn and the closer lands and
+    ships them in the order they were decided, so a key's rounds reach
+    the global tier in the order they closed whatever the D2H of each
+    took — 20 back-to-back rounds, the D2H jittered."""
     cfg = Config(topology=Topology(num_parties=1, workers_per_party=1),
                  server_shards=3, merge_backend="jax")
     sim = Simulation(cfg, lightweight=False)   # lanes are threads
@@ -325,13 +342,8 @@ def test_pushes_up_keep_per_key_order_across_lanes():
         assert len({id(ls._mu.stripe(k)) for k in keys}) == 3
         rng = np.random.default_rng(0)
         naps = {k: iter(rng.uniform(0, 0.01, 20)) for k in keys}
-        materialize = ls._backend.materialize
-
-        def jittered(acc):
-            time.sleep(next(naps[acc.key]))
-            return materialize(acc)
-
-        ls._backend.materialize = jittered
+        _before_landing(ls._backend,
+                        lambda key: time.sleep(next(naps[key])))
         shipped = {k: [] for k in keys}
         push_up = ls._push_up
 
