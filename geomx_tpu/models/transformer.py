@@ -96,9 +96,11 @@ class TransformerConfig:
     #                          output, 134 MB a layer at [1, 8192, 32,
     #                          128] in bf16, so that the recompute runs
     #                          no scan) and ops/dsa.py KEPT_NAMES (a
-    #                          dsa layer's selection, so that the
-    #                          recompute selects nothing); no other
-    #                          layer names anything
+    #                          dsa layer's selection and its forward
+    #                          kernel's o, lse and lsei, 136.5 MB a layer
+    #                          at [1, 16384, 32, 128] in bf16, so that
+    #                          the recompute selects and attends
+    #                          nothing); no other layer names anything
     # ---- off by default: all off is the flagship's GPT block ----------
     d_head: int = 0          # channels of an attention head (0 =
     #                          d_model // n_heads)
@@ -696,10 +698,13 @@ def _sparse_attention(cfg: TransformerConfig, layer, h):
     index_wq``, one k head ``LN(h index_wk)``, both rotated, and the
     weights ``h index_w`` times ``(index_heads index_head_dim)^-1/2``.
     Returns ``(y, select)``: ``select`` the counts for the tracer
-    (``ops/dsa.py`` ``dsa_attention``'s) and ``indexer_loss``, the
+    (``ops/dsa.py`` ``dsa_attention``'s), ``indexer_loss``, the
     indexer's cross-entropy summed over positions, mean over the batch,
     which the loss adds (its gradient reaches the indexer alone, and
-    the LM's never does)."""
+    the LM's never does), and ``kept_bytes``, what the layer's
+    checkpoint keeps of the sparse attention under ``cfg.remat``
+    (``ops/dsa.py`` ``named_bytes``; 0 without, and on the ``xla`` path,
+    which names nothing)."""
     cd, theta = cfg.compute_dtype, cfg.rope_theta
     proj = lambda x, w: jnp.einsum(                      # noqa: E731
         "btd,d...->bt...", x, layer[w].astype(cd))
@@ -717,10 +722,14 @@ def _sparse_attention(cfg: TransformerConfig, layer, h):
     if cfg.dsa_impl == "xla":
         o, ce, select = dsa_ops.dsa_attention_xla(
             q, k, v, qi, ki, w, topk=cfg.index_topk, tile=tile)
+        named = 0
     else:
         o, ce, select = dsa_ops.dsa_attention(
             q, k, v, qi, ki, w, topk=cfg.index_topk, tile=tile)
+        named = dsa_ops.named_bytes(q, tile)
     select["indexer_loss"] = jnp.sum(ce) / h.shape[0]
+    # what make_apply's checkpoint of the layer keeps of it
+    select["kept_bytes"] = jnp.float32(named if cfg.remat else 0)
     return jnp.einsum("bthk,hkd->btd", o, layer["wo"].astype(cd)), select
 
 

@@ -43,8 +43,15 @@ softmax, the threshold and the KL are float32; matmuls take the inputs'
 dtype with float32 accumulation.
 
 What a layer's checkpoint keeps (``KEPT_NAMES``): the selection
-(``tau``, ``sigma``, the tiles visited), so that the backward pass's
-recompute of the layer does not select again.
+(``tau``, ``sigma``, the tiles visited) and what the forward kernel
+returned that the backward kernels read (``o``, the heads' ``lse`` and
+the indexer's ``lsei``), :func:`named_bytes` a layer (136.5 MB at [1,
+16384, 32, 128] in bfloat16).  The forward kernel runs on values
+without a gradient and :func:`_attend` takes its outputs as inputs, so
+with those kept nothing in the backward pass's recompute of the layer
+needs a kernel call: it neither selects nor attends again, and the
+forward kernel runs once a layer (PERF.md section 6).  ``ce``
+is not kept: the backward pass does not read it.
 
 Off the chip the kernels run under the TPU interpreter
 (``pltpu.force_tpu_interpret_mode()``: ``tests/test_dsa.py``), which runs
@@ -72,7 +79,9 @@ TILE = 512
 NEG = -1e30         # finite: exp() of a masked score under it is 0
 INT_MIN = np.int32(np.iinfo(np.int32).min)
 TAU_NAME, SIGMA_NAME, VISITED_NAME = "dsa_tau", "dsa_sigma", "dsa_visited"
-KEPT_NAMES = (TAU_NAME, SIGMA_NAME, VISITED_NAME)
+O_NAME, LSE_NAME, LSEI_NAME = "dsa_o", "dsa_lse", "dsa_lsei"
+KEPT_NAMES = (TAU_NAME, SIGMA_NAME, VISITED_NAME, O_NAME, LSE_NAME,
+              LSEI_NAME)
 # the kernels hold a tile of all heads at once (and dsa_index a q
 # tile's keys against the whole sequence): past the default scoped VMEM
 VMEM_BYTES = 100 * 2 ** 20
@@ -592,6 +601,9 @@ def dsa_attention(q, k, v, qi, ki, w, *, topk: int, tile: int = TILE):
     o, lse, lsei, ce, attended = _fwd(
         sg(qh), sg(kh), sg(vh), sg(qit), sg(ki), sg(w), tau, sigma, visited,
         tile)
+    o = checkpoint_name(o, O_NAME)
+    lse = checkpoint_name(lse, LSE_NAME)
+    lsei = checkpoint_name(lsei, LSEI_NAME)
     o, ce = _attend(qh, kh, vh, qit, ki, w, o, lse, lsei, ce, tau, sigma,
                     visited, tile)
     # int32: T x topk pairs pass float32's 2^24
@@ -600,6 +612,17 @@ def dsa_attention(q, k, v, qi, ki, w, *, topk: int, tile: int = TILE):
              "tiles_visited": jnp.sum(visited),
              "tiles_causal": jnp.int32(B * n * (n + 1) // 2)}
     return o.swapaxes(1, 2), ce[..., 0], stats
+
+
+def named_bytes(q, tile: int = TILE) -> int:
+    """What :func:`dsa_attention` names for a checkpoint around it to
+    keep (:data:`KEPT_NAMES`) at ``q`` [B, T, H, D], in bytes from
+    shapes: ``o`` in q's dtype, ``lse`` [B, T, H] and ``lsei`` [B, T,
+    1] float32, ``tau`` and ``sigma`` [B, T, 1] and the tiles visited
+    [B (T / tile)^2] int32."""
+    B, T, H, _ = q.shape
+    n = T // tile
+    return q.size * q.dtype.itemsize + 4 * B * (T * H + 3 * T + n * n)
 
 
 def dsa_attention_xla(q, k, v, qi, ki, w, *, topk: int, tile: int = TILE):

@@ -356,7 +356,9 @@ def _select_args(select) -> dict:
     (equal, or the kernels saw different scores); ``tiles_visited_pct``
     the share of the causal tiles that the attention kernel computed;
     ``indexer_loss`` the indexers' cross-entropy, summed over the
-    layers."""
+    layers; ``kept_MB`` what a layer's checkpoint keeps of the sparse
+    attention (the selection and the forward kernel's output and row
+    statistics under ``remat``, 0 without)."""
     visited = np.asarray(select["tiles_visited"], np.int64).sum()
     causal = np.asarray(select["tiles_causal"], np.int64).sum()
     return {
@@ -366,7 +368,8 @@ def _select_args(select) -> dict:
         "attended_pairs": int(np.asarray(select["attended_pairs"],
                                          np.int64).sum()),
         "tiles_visited_pct": float(100.0 * visited / max(causal, 1)),
-        "indexer_loss": float(np.sum(select["indexer_loss"]))}
+        "indexer_loss": float(np.sum(select["indexer_loss"])),
+        "kept_MB": float(np.max(select["kept_bytes"]) / 1e6)}
 
 
 def _exchange(kv: WorkerKVStore, tids: Sequence[int], leaves: list,

@@ -291,11 +291,13 @@ def test_the_sigmoid_router_is_the_default_and_keeps_its_bias():
 # ---------------------------------------------------------------------------
 
 def test_the_recompute_does_not_select_again():
-    """Under ``remat`` the layer's checkpoint keeps the selection
+    """Under ``remat`` the layer's checkpoint keeps the selection and
+    what the forward kernel returned that the backward kernels read
     (``ops/dsa.py`` ``KEPT_NAMES``): the gradient program selects once a
-    layer, runs the attention forward twice (forward, and again for the
-    backward pass's residuals) and each backward kernel once.  Counted
-    in the program's jaxpr, which needs no chip."""
+    layer, runs the attention forward once (the recompute needs no
+    kernel call, and jax's dead-code elimination drops it) and each
+    backward kernel once.  Counted in the program's jaxpr, which needs
+    no chip."""
     init, grad_fn = FAMILY.system.build(
         {**TINY, "dsa_impl": "pallas", "remat": True}, "float32")
     p = jax.eval_shape(init, jax.random.PRNGKey(0))
@@ -303,10 +305,50 @@ def test_the_recompute_does_not_select_again():
     text = str(jax.make_jaxpr(grad_fn)(p, x, x))
     calls = {n: text.count(f"name={n}") for n in (
         "dsa_index", "dsa_attn_fwd", "dsa_attn_bwd_dq", "dsa_attn_bwd_dkv")}
-    assert calls == {"dsa_index": 4, "dsa_attn_fwd": 8,
+    assert calls == {"dsa_index": 4, "dsa_attn_fwd": 4,
                      "dsa_attn_bwd_dq": 4, "dsa_attn_bwd_dkv": 4}, calls
     with pytest.raises(ValueError, match="dsa_impl"):
         FAMILY.system.config({**TINY, "dsa_impl": "interpret"}, "float32")
+
+
+@pytest.mark.parametrize("impl, remat", [
+    ("pallas", True), ("pallas", False), ("xla", True)])
+def test_the_selects_counts_say_what_the_layers_checkpoint_keeps(impl, remat):
+    """``_sparse_attention``'s ``kept_bytes`` and the ``dsa.select``
+    span's ``kept_MB``: under ``remat`` the bytes of every value the
+    layer's program names for the checkpoint (found in its jaxpr: the
+    selection, ``o``, ``lse`` and ``lsei``), 0 without; the plain XLA
+    path names nothing and keeps nothing."""
+    from jax._src.ad_checkpoint import name_p
+
+    from geomx_tpu.ops import dsa
+
+    cfg = FAMILY.system.config({**TINY, "dsa_impl": impl, "remat": remat},
+                               "float32")
+    params, _ = _build("float32")
+    layer = params["layers"][0]
+    h = jax.random.normal(jax.random.PRNGKey(5), (2, 32, 32))
+
+    def select(layer, h):
+        return tf._sparse_attention(cfg, layer, h)[1]
+
+    named = {}
+    for eqn in jax.make_jaxpr(select)(layer, h).eqns:
+        if eqn.primitive is name_p and eqn.params["name"] in dsa.KEPT_NAMES:
+            aval = eqn.outvars[0].aval
+            named[eqn.params["name"]] = aval.size * aval.dtype.itemsize
+    assert set(named) == (set(dsa.KEPT_NAMES) if impl == "pallas" else set())
+    # o [2, 4, 32, 8] float32, lse [2, 32, 4], lsei, tau, sigma [2, 32,
+    # 1], the tiles visited [2 x 4 x 4]
+    assert sum(named.values()) == (
+        4 * 2 * (4 * 32 * 8 + 32 * 4 + 3 * 32 + 16) if named else 0)
+    kept = sum(named.values()) if remat else 0
+    got = _interpreted(jax.jit(select))(layer, h)
+    assert float(got["kept_bytes"]) == kept
+    args = training._select_args(jax.tree_util.tree_map(
+        lambda a: jnp.stack([a, a]), got))
+    assert args["layers"] == 2
+    assert args["kept_MB"] == pytest.approx(kept / 1e6)
 
 
 # ---------------------------------------------------------------------------
@@ -395,6 +437,7 @@ def test_dsa_select_is_recorded_in_the_sampled_round_only(trained):
     assert a["chosen_pairs"] == a["attended_pairs"] == 4 * 2 * 228
     assert 0 < a["tiles_visited_pct"] <= 100.0
     assert a["indexer_loss"] > 0
+    assert a["kept_MB"] == 0         # the plain XLA path names nothing
     routes = [e for e in events if e["name"] == "moe.route"]
     assert len(routes) == 1 and routes[0]["args"]["dropped"] == 0
 
@@ -405,6 +448,8 @@ def test_select_args_of_skipped_tiles():
     args = training._select_args({
         "chosen_pairs": row(10, 12), "attended_pairs": row(10, 12),
         "tiles_visited": row(10, 5), "tiles_causal": row(10, 10),
-        "indexer_loss": jnp.asarray([1.5, 2.0])})
+        "indexer_loss": jnp.asarray([1.5, 2.0]),
+        "kept_bytes": jnp.asarray([2.5e6, 2.5e6], jnp.float32)})
     assert args == {"layers": 2, "chosen_pairs": 22, "attended_pairs": 22,
-                    "tiles_visited_pct": 75.0, "indexer_loss": 3.5}
+                    "tiles_visited_pct": 75.0, "indexer_loss": 3.5,
+                    "kept_MB": 2.5}

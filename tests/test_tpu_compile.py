@@ -359,6 +359,46 @@ def test_a_kda_layer_under_the_layers_checkpoint_scans_twice(one_chip):
     assert compiled.memory_analysis().temp_size_in_bytes < 2.15e9
 
 
+def test_a_dsa_layer_under_the_layers_checkpoint_attends_once(one_chip):
+    """One ``dsa`` layer (``_layer_forward`` under ``make_apply``'s
+    checkpoint, ``remat`` true) at Keye-VL's widths: 2,048 wide, 32 q
+    and 4 kv heads of 128, an indexer of 16 x 64, tiles of 512, over
+    2,048 positions with topk 1,024; a narrow FFN and a small
+    vocabulary, which are not the point.  The layer's checkpoint keeps
+    the selection and the forward kernel's ``o``, ``lse`` and ``lsei``
+    (``ops/dsa.py`` ``KEPT_NAMES``), so the compiled gradient calls
+    every kernel once: the recompute of the layer needs none, where
+    under the selection alone it ran ``dsa_attn_fwd`` a second time
+    (PERF.md section 6).  Temporaries 0.1028 GB, where they were
+    0.1092 GB with the forward run twice: the recompute's own ``o`` and
+    its buffers outweigh the kept one at this length."""
+    import jax
+    import jax.numpy as jnp
+
+    from geomx_tpu.models import transformer as tf
+
+    cfg = tf.TransformerConfig(
+        vocab=256, d_model=2048, n_heads=32, n_kv_heads=4, d_head=128,
+        n_layers=1, d_ff=256, max_seq=2048, layer_types=("dsa",),
+        qk_norm=True, rope_theta=1e7, gated_ffn=True, tied_head=False,
+        index_heads=16, index_head_dim=64, index_topk=1024, index_tile=512,
+        remat=True, compute_dtype=jnp.bfloat16)
+    apply = tf.make_apply(cfg)
+    params = jax.tree_util.tree_map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip),
+        jax.eval_shape(lambda k: tf.init_params(cfg, k),
+                       jax.random.PRNGKey(0)))
+    x = jax.ShapeDtypeStruct((1, 2048), jnp.int32, sharding=one_chip)
+    compiled = jax.jit(jax.grad(
+        lambda p, x: tf.lm_loss(apply, p, x))).lower(params, x).compile()
+    calls = _kernel_calls(compiled.as_text())
+    counts = {n: sum(bool(re.match(rf"(jvp_)?{n}_*[ .]", c)) for c in calls)
+              for n in ("dsa_index", "dsa_attn_fwd", "dsa_attn_bwd_dq",
+                        "dsa_attn_bwd_dkv")}
+    assert counts == dict.fromkeys(counts, 1) and len(calls) == 4, calls
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.106e9
+
+
 def test_the_sparse_attention_kernels_for_the_v5e(one_chip):
     """Keye-VL's learned sparse attention at its published widths (32 q
     heads and 4 kv heads of 128, an indexer of 16 x 64, tiles of 512),
