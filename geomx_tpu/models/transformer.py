@@ -28,8 +28,13 @@ convolution ``conv``, the gated delta-rule scan ``kda`` of
 ``ops/kda.py``, latent attention ``mla``: keys and values through a
 low-rank latent, wider q/k heads than v heads, or learned sparse
 attention ``dsa``: grouped-query heads over the keys a lightning
-indexer chose for each query, ``ops/dsa.py``), heads wider than
-``d_model / n_heads`` (``d_head``), no positions at all
+indexer chose for each query, ``ops/dsa.py``, or sliding-window
+attention ``sliding_attention``: each query over the ``window`` keys
+ending at its own, ``window_heads`` q heads, a rotary of its own,
+``ops/window_attention.py``), heads wider than
+``d_model / n_heads`` (``d_head``), a partial rotary and YaRN's
+frequencies on full attention (``rope_dim``, ``yarn``), a sigmoid gate
+on each head's output (``head_gate``), no positions at all
 (``no_positions``), an untied head (``tied_head=False``), and after
 ``n_dense_layers`` leading dense layers one chip's share of routed
 experts with no capacity and no dropped token (``router_experts``;
@@ -45,6 +50,7 @@ forward.  bf16 activations, f32 params/accumulators.
 from __future__ import annotations
 
 import dataclasses
+import math
 from typing import Any, Dict, Optional, Tuple
 
 import jax
@@ -54,10 +60,44 @@ from jax import lax, shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from geomx_tpu.ops import dsa as dsa_ops
+from geomx_tpu.ops import window_attention as window_ops
 from geomx_tpu.ops.kda import KEPT_NAMES, chunk_kda
 from geomx_tpu.parallel.ring_attention import (
     dense_attention, fast_dense_attention, ring_attention)
 from geomx_tpu.parallel.ulysses import ulysses_attention
+
+
+@dataclasses.dataclass(frozen=True)
+class Yarn:
+    """YaRN's rotary frequencies, as HF transformers'
+    ``_compute_yarn_parameters`` defines them: each pair's frequency a
+    blend of the interpolated ``1 / (factor theta^(2i/d))`` and the
+    extrapolated ``1 / theta^(2i/d)``, the share of the latter falling
+    linearly from 1 to 0 between the correction dims of ``beta_fast``
+    and ``beta_slow`` rotations over ``original_max_positions``
+    (floored and ceiled); cos and sin are multiplied by
+    ``attention_factor``."""
+    factor: float
+    original_max_positions: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    attention_factor: float = 1.0
+
+    def inv_freq(self, theta: float, dim: int) -> np.ndarray:
+        """The ``dim / 2`` frequencies of a rotary over ``dim`` channels,
+        float64."""
+        pos = theta ** (np.arange(0, dim, 2) / dim)
+
+        def corr(rotations):
+            return (dim * math.log(self.original_max_positions
+                                   / (rotations * 2 * math.pi))
+                    / (2 * math.log(theta)))
+
+        low = max(math.floor(corr(self.beta_fast)), 0)
+        high = min(math.ceil(corr(self.beta_slow)), dim - 1)
+        ramp = np.clip((np.arange(dim // 2) - low)
+                       / (high - low if high > low else 0.001), 0, 1)
+        return ramp / (self.factor * pos) + (1 - ramp) / pos
 
 
 @dataclasses.dataclass(frozen=True)
@@ -117,8 +157,10 @@ class TransformerConfig:
     layer_types: Tuple[str, ...] = ()   # per layer "full_attention",
     #                          "conv" (gated short convolution), "kda"
     #                          (gated delta-rule scan), "mla" (latent
-    #                          attention) or "dsa" (learned sparse
-    #                          attention); empty = attention everywhere
+    #                          attention), "dsa" (learned sparse
+    #                          attention) or "sliding_attention"
+    #                          (over the window alone); empty =
+    #                          attention everywhere
     conv_kernel: int = 3     # taps of the short convolution
     n_dense_layers: int = 0  # leading layers that keep the dense FFN
     router_experts: int = 0  # > 0: every later layer routes over this
@@ -173,13 +215,29 @@ class TransformerConfig:
     index_topk: int = 0
     index_tile: int = dsa_ops.TILE
     dsa_impl: str = "pallas"
+    # "sliding_attention" layers: each query over the window keys ending
+    # at its own position (ops/window_attention.py under attn_impl
+    # "flash", a masked dense attention otherwise), window_heads q heads
+    # (0 = n_heads) over the n_kv_heads k/v heads, rotary positions at
+    # window_rope_theta over the whole head (0 = rope_theta)
+    window: int = 0
+    window_heads: int = 0
+    window_rope_theta: float = 0.0
+    # the other attention layers' rotary: over the first rope_dim
+    # channels of a head (0 = all of it), the rest passed through, at
+    # YaRN's frequencies where yarn is set
+    rope_dim: int = 0
+    yarn: Optional[Yarn] = None
+    head_gate: bool = False  # each attention head's output times
+    #                          sigmoid(h wg), a gate a head read from
+    #                          the block's normed input
 
     def __post_init__(self):
         if self.layer_types and len(self.layer_types) != self.n_layers:
             raise ValueError(f"layer_types names {len(self.layer_types)} "
                              f"layers, n_layers is {self.n_layers}")
         unknown = set(self.layer_types) - {"full_attention", "conv", "kda",
-                                           "mla", "dsa"}
+                                           "mla", "dsa", "sliding_attention"}
         if unknown:
             raise ValueError(f"unknown layer type(s) {sorted(unknown)}")
         if "kda" in self.layer_types and not (
@@ -196,15 +254,18 @@ class TransformerConfig:
                 and self.rope_theta > 0):
             raise ValueError("a dsa layer needs index_heads, index_head_dim, "
                              "index_topk, qk_norm and rope_theta")
+        if "sliding_attention" in self.layer_types and self.window < 1:
+            raise ValueError("a sliding_attention layer needs a window")
         if self.dsa_impl not in ("pallas", "xla"):
             raise ValueError(f"unknown dsa_impl {self.dsa_impl!r}")
         if self.router_score not in ("sigmoid", "softmax"):
             raise ValueError(f"unknown router_score {self.router_score!r}")
         if self.no_positions and self.rope_theta:
             raise ValueError("no_positions and rope_theta exclude each other")
-        if self.n_heads % self.kv_heads:
-            raise ValueError(f"{self.n_heads} q heads do not divide over "
-                             f"{self.kv_heads} k/v heads")
+        for heads in {self.n_heads, self.window_heads or self.n_heads}:
+            if heads % self.kv_heads:
+                raise ValueError(f"{heads} q heads do not divide over "
+                                 f"{self.kv_heads} k/v heads")
         if self.router_experts and (self.moe_every or not (
                 0 < self.moe_top_k <= self.router_experts
                 and 0 <= self.first_expert
@@ -225,6 +286,12 @@ class TransformerConfig:
     @property
     def kv_heads(self) -> int:
         return self.n_kv_heads or self.n_heads
+
+    def q_heads(self, layer: int) -> int:
+        """The q heads of an attention layer."""
+        if self.kind(layer) == "sliding_attention":
+            return self.window_heads or self.n_heads
+        return self.n_heads
 
     def is_moe(self, layer: int) -> bool:
         return self.moe_every > 0 and (layer + 1) % self.moe_every == 0
@@ -275,10 +342,15 @@ def init_params(cfg: TransformerConfig, rng: jax.Array) -> Dict:
         elif cfg.kind(i) == "mla":
             layer.update(_init_mla(cfg, k[0], dense))
         else:
+            H = cfg.q_heads(i)
             layer["wq"] = dense(k[0], (D, H, Dh))
             layer["wk"] = dense(k[1], (D, Hkv, Dh))
             layer["wv"] = dense(k[2], (D, Hkv, Dh))
             layer["wo"] = dense(k[3], (H, Dh, D), scale=1.0 / np.sqrt(D))
+            if cfg.head_gate:
+                # a key of its own: the layer's eight are all taken
+                layer["wg"] = dense(jax.random.fold_in(keys[3 + i], 9),
+                                    (D, H))
             if cfg.qk_norm:
                 layer["q_norm"] = jnp.ones((Dh,), jnp.float32)
                 layer["k_norm"] = jnp.ones((Dh,), jnp.float32)
@@ -419,6 +491,8 @@ def param_specs(cfg: TransformerConfig) -> Dict:
                          wv=P(None, "tp", None), wo=P("tp", None, None))
             if cfg.qk_norm:
                 layer.update(q_norm=P(None), k_norm=P(None))
+            if cfg.head_gate:
+                layer["wg"] = P(None, "tp")
             if cfg.kind(i) == "dsa":
                 # not split yet: whole on every device
                 layer.update(index_wq=P(None, None, None),
@@ -452,18 +526,29 @@ def _rms_norm(x, scale, eps: float = 1e-6):
     return (x.astype(jnp.float32) * jax.lax.rsqrt(var + eps) * scale).astype(x.dtype)
 
 
-def _rope(x, theta: float):
-    """Rotary positions over the whole head of ``x`` [B, T, H, Dh],
-    rotate-half pairing: channel i turns with channel i + Dh/2 by the
-    angle ``t * theta ** (-2i / Dh)``; float32 inside."""
-    T, half = x.shape[1], x.shape[-1] // 2
-    freq = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
+def _rope(x, theta: float, dim: int = 0, yarn: Optional[Yarn] = None):
+    """Rotary positions over the first ``dim`` channels of each head of
+    ``x`` [B, T, H, Dh] (0: the whole head), rotate-half pairing within
+    them: channel i turns with channel i + dim/2 by the angle ``t *
+    theta ** (-2i / dim)``, or at ``yarn``'s frequencies with cos and
+    sin times its attention factor; the other channels pass through.
+    float32 inside."""
+    T, Dh = x.shape[1], x.shape[-1]
+    half = (dim or Dh) // 2
+    if yarn is None:
+        freq = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
+    else:
+        freq = jnp.asarray(yarn.inv_freq(theta, 2 * half), jnp.float32)
     ang = jnp.arange(T, dtype=jnp.float32)[:, None] * freq[None]
     cos, sin = jnp.cos(ang)[None, :, None], jnp.sin(ang)[None, :, None]
+    if yarn is not None:
+        cos, sin = cos * yarn.attention_factor, sin * yarn.attention_factor
     x1 = x[..., :half].astype(jnp.float32)
-    x2 = x[..., half:].astype(jnp.float32)
-    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
-                           axis=-1).astype(x.dtype)
+    x2 = x[..., half:2 * half].astype(jnp.float32)
+    out = [x1 * cos - x2 * sin, x2 * cos + x1 * sin]
+    if 2 * half < Dh:
+        out.append(x[..., 2 * half:].astype(jnp.float32))
+    return jnp.concatenate(out, axis=-1).astype(x.dtype)
 
 
 def _short_conv(cfg: "TransformerConfig", layer, h):
@@ -499,11 +584,12 @@ def make_apply(cfg: TransformerConfig, mesh: Optional[Mesh] = None,
     without any): int32 ``rows`` [routed layers, experts held],
     ``held_pairs``, ``empty_tokens``, ``chunks`` and ``buffer_rows``
     [routed layers], as ``parallel/moe.py`` ``routed_ffn`` counts them;
-    and, for a model with ``kda`` or ``dsa`` layers, a dict of what their
-    operators saw by span name, a row a layer of that kind
-    (``"kda_scan"``: ``ops/kda.py`` ``chunk_kda``'s ``stats``;
-    ``"dsa_select"``: ``ops/dsa.py`` ``dsa_attention``'s and the
-    indexer's loss)."""
+    and, for a model with ``kda``, ``dsa`` or ``sliding_attention``
+    layers, a dict of what their operators saw by span name, a row a
+    layer of that kind (``"kda_scan"``: ``ops/kda.py`` ``chunk_kda``'s
+    ``stats``; ``"dsa_select"``: ``ops/dsa.py`` ``dsa_attention``'s and
+    the indexer's loss; ``"attn_window"``: ``_window_counts``, a row an
+    attention layer, full or windowed)."""
     if cfg.moe_every > 0 and cfg.moe_top_k > 0 and not return_aux:
         import warnings
 
@@ -515,6 +601,9 @@ def make_apply(cfg: TransformerConfig, mesh: Optional[Mesh] = None,
         raise ValueError(
             f"sp_attn must be 'ring' or 'ulysses', got {cfg.sp_attn!r}")
     use_ring = mesh is not None and "sp" in mesh.axis_names and mesh.shape["sp"] > 1
+    if use_ring and "sliding_attention" in cfg.layer_types:
+        raise ValueError("sequence-parallel attention is causal over the "
+                         "whole sequence: no sliding_attention layer")
 
     def attn_op(q, k, v):
         if not use_ring:
@@ -655,30 +744,93 @@ def _single_device_attention(cfg: TransformerConfig, q, k, v):
     raise ValueError(f"unknown attn_impl {cfg.attn_impl!r}")
 
 
-def _attention(cfg: TransformerConfig, layer, h, attn_op, shard=None):
+def _window_attention(cfg: TransformerConfig, q, k, v):
+    """A ``sliding_attention`` layer's attention over its window: under
+    ``attn_impl`` "flash" the kernels of ``ops/window_attention.py``,
+    which read the grouped k/v heads as they are; otherwise the dense
+    path under the window's mask, k and v repeated to the q heads."""
+    if cfg.attn_impl == "flash":
+        return window_ops.window_attention(q, k, v, window=cfg.window)
+    group = q.shape[2] // k.shape[2]
+    k, v = jnp.repeat(k, group, axis=2), jnp.repeat(v, group, axis=2)
+    dense = (dense_attention if cfg.attn_impl == "dense"
+             else fast_dense_attention)
+    return dense(q, k, v, causal=True, window=cfg.window)
+
+
+def _window_counts(cfg: TransformerConfig, T: int, B: int, sliding: bool,
+                   gate) -> Dict:
+    """An attention layer's row of what span ``attn.window`` reports, in
+    a model with windowed layers: ``window`` (0 on a full layer), the
+    tiles the window kernels compute and the causal ones, the pairs in
+    the window (every count from shapes, for ``B`` sequences of ``T``),
+    and, with ``head_gate``, the gates' sum, those under 0.05 and their
+    number.  The dense path computes every causal tile."""
+    row = {"window": jnp.int32(0), "tiles_visited": jnp.int32(0),
+           "tiles_causal": jnp.int32(0), "pairs": jnp.int32(0)}
+    if sliding:
+        tile = min(window_ops.TILE, T)
+        visited, causal = window_ops.tiles(T, cfg.window, tile)
+        if cfg.attn_impl != "flash":
+            visited = causal
+        row = {"window": jnp.int32(cfg.window),
+               "tiles_visited": jnp.int32(B * visited),
+               "tiles_causal": jnp.int32(B * causal),
+               "pairs": jnp.int32(B * window_ops.pairs(T, cfg.window))}
+    if gate is not None:
+        row.update(gate_sum=jnp.sum(gate),
+                   gate_closed=jnp.sum(gate < 0.05, dtype=jnp.int32),
+                   gates=jnp.int32(gate.size))
+    return row
+
+
+def _attention(cfg: TransformerConfig, layer, h, attn_op, shard=None,
+               kind: str = "full_attention", seen: Optional[Dict] = None):
     """Causal self-attention of one block on the normed stream ``h``:
     the flagship's multi-head form, and with ``n_kv_heads`` /
     ``qk_norm`` / ``rope_theta`` set grouped-query heads (each k/v head
     repeated for the consecutive q heads it serves, so that every
     attention implementation sees equal head counts), RMSNorm over each
-    head's channels of q and k, and rotary positions."""
+    head's channels of q and k, and rotary positions (``rope_dim``,
+    ``yarn``).  A ``sliding_attention`` layer attends over its window
+    (:func:`_window_attention`) with ``window_heads`` q heads, rotated
+    at ``window_rope_theta`` over the whole head.  ``head_gate``: each
+    head's output times ``sigmoid(h wg)`` before ``wo``.  In a model
+    with a ``window`` the layer's :func:`_window_counts` go into
+    ``seen["attn_window"]`` where a ``seen`` is given."""
     cd = cfg.compute_dtype
+    sliding = kind == "sliding_attention"
     q = jnp.einsum("btd,dhk->bthk", h, layer["wq"].astype(cd))
     k = jnp.einsum("btd,dhk->bthk", h, layer["wk"].astype(cd))
     v = jnp.einsum("btd,dhk->bthk", h, layer["wv"].astype(cd))
     if cfg.qk_norm:
         q = _rms_norm(q, layer["q_norm"], cfg.norm_eps)
         k = _rms_norm(k, layer["k_norm"], cfg.norm_eps)
-    if cfg.rope_theta:
-        q, k = _rope(q, cfg.rope_theta), _rope(k, cfg.rope_theta)
-    if cfg.kv_heads != cfg.n_heads:
-        group = cfg.n_heads // cfg.kv_heads
-        k, v = jnp.repeat(k, group, axis=2), jnp.repeat(v, group, axis=2)
-    if shard is not None:
-        q = lax.with_sharding_constraint(q, shard)
-        k = lax.with_sharding_constraint(k, shard)
-        v = lax.with_sharding_constraint(v, shard)
-    a = attn_op(q, k, v)
+    if sliding:
+        theta = cfg.window_rope_theta or cfg.rope_theta
+        if theta:
+            q, k = _rope(q, theta), _rope(k, theta)
+        a = _window_attention(cfg, q, k, v)
+    else:
+        if cfg.rope_theta:
+            q, k = (_rope(x, cfg.rope_theta, cfg.rope_dim, cfg.yarn)
+                    for x in (q, k))
+        if cfg.kv_heads != cfg.n_heads:
+            group = cfg.n_heads // cfg.kv_heads
+            k, v = jnp.repeat(k, group, axis=2), jnp.repeat(v, group, axis=2)
+        if shard is not None:
+            q = lax.with_sharding_constraint(q, shard)
+            k = lax.with_sharding_constraint(k, shard)
+            v = lax.with_sharding_constraint(v, shard)
+        a = attn_op(q, k, v)
+    gate = None
+    if cfg.head_gate:
+        gate = jax.nn.sigmoid(jnp.einsum(
+            "btd,dh->bth", h, layer["wg"].astype(cd)).astype(jnp.float32))
+        a = (a.astype(jnp.float32) * gate[..., None]).astype(cd)
+    if cfg.window and seen is not None:
+        seen["attn_window"] = _window_counts(cfg, h.shape[1], h.shape[0],
+                                             sliding, gate)
     return jnp.einsum("bthk,hkd->btd", a, layer["wo"].astype(cd))
 
 
@@ -805,13 +957,15 @@ def _kda(cfg: TransformerConfig, layer, h):
 
 def _layer_forward(cfg: TransformerConfig, i: int, layer, x, attn_op,
                    shard=None):
-    """One block: the layer's operator (attention, latent or learned
-    sparse attention, the gated short convolution or the delta-rule
-    scan) and its FFN (dense, MoE or routed share), each a residual.
-    Returns ``(x, aux, route, seen)``: the capacity MoE's load-balancing
-    loss (0 elsewhere), a routed layer's counts (None elsewhere) and
-    what a ``kda`` or ``dsa`` layer's operator saw, under the name of
-    its span (``{"kda_scan": ...}``, ``{"dsa_select": ...}``, empty
+    """One block: the layer's operator (attention, full or over a
+    window, latent or learned sparse attention, the gated short
+    convolution or the delta-rule scan) and its FFN (dense, MoE or
+    routed share), each a residual.  Returns ``(x, aux, route, seen)``:
+    the capacity MoE's load-balancing loss (0 elsewhere), a routed
+    layer's counts (None elsewhere) and what a ``kda`` or ``dsa``
+    layer's operator saw, or an attention layer's of a model with a
+    ``window``, under the name of its span (``{"kda_scan": ...}``,
+    ``{"dsa_select": ...}``, ``{"attn_window": ...}``, empty
     elsewhere)."""
     cd = cfg.compute_dtype
     h = _rms_norm(x, layer["ln1"], cfg.norm_eps)
@@ -827,7 +981,7 @@ def _layer_forward(cfg: TransformerConfig, i: int, layer, x, attn_op,
     elif cfg.kind(i) == "mla":
         x = x + _latent_attention(cfg, layer, h, attn_op)
     else:
-        x = x + _attention(cfg, layer, h, attn_op, shard)
+        x = x + _attention(cfg, layer, h, attn_op, shard, cfg.kind(i), seen)
     h = _rms_norm(x, layer["ln2"], cfg.norm_eps)
     aux = jnp.zeros((), jnp.float32)
     route = None
@@ -964,12 +1118,14 @@ def make_lm_grad_fn(cfg: "TransformerConfig"):
     the layers' int32 counts, which the worker loop reads to the host in
     a sampled round only (span ``moe.route``); one with ``kda`` layers
     adds ``"kda_scan"`` to it, their scans' counts (span ``kda.scan``),
-    and one with ``dsa`` layers ``"dsa_select"``, their selections'
-    (span ``dsa.select``).  ``dsa`` layers add their indexer's loss to
+    one with ``dsa`` layers ``"dsa_select"``, their selections' (span
+    ``dsa.select``), and one with ``sliding_attention`` layers
+    ``"attn_window"``, its attention layers' (span ``attn.window``).
+    ``dsa`` layers add their indexer's loss to
     the objective; the loss returned is the LM's alone."""
     use_aux = cfg.moe_every > 0 and cfg.moe_top_k > 0
     routed = any(cfg.is_routed(i) for i in range(cfg.n_layers))
-    kinds = {"kda", "dsa"} & set(cfg.layer_types)
+    kinds = {"kda", "dsa", "sliding_attention"} & set(cfg.layer_types)
     apply_fn = make_apply(cfg, return_aux=use_aux,
                           return_route=routed or bool(kinds))
 

@@ -372,6 +372,35 @@ def _select_args(select) -> dict:
         "kept_MB": float(np.max(select["kept_bytes"]) / 1e6)}
 
 
+def _window_args(att) -> dict:
+    """The arguments of a step's ``attn.window`` span from the counts the
+    gradient program returned (``models/transformer.py``
+    ``_window_counts``, a row an attention layer), read to the host HERE:
+    ``layers_window`` and ``layers_full``; ``window``; ``tiles_visited``
+    the tiles the window kernels computed and ``tiles_causal`` the
+    causal ones, all windowed layers together, and ``tiles_visited_pct``
+    the one over the other; ``pairs_in_window`` the (query, key) pairs
+    the windows hold; and where the heads are gated ``gate_mean`` the
+    mean gate over tokens, heads and layers and ``gate_closed_pct`` the
+    share of them under 0.05."""
+    window = np.asarray(att["window"], np.int64)
+    total = lambda key: int(np.asarray(att[key], np.int64).sum())  # noqa: E731
+    visited, causal = total("tiles_visited"), total("tiles_causal")
+    out = {"layers_window": int(np.count_nonzero(window)),
+           "layers_full": int(np.size(window) - np.count_nonzero(window)),
+           "window": int(window.max()), "tiles_visited": visited,
+           "tiles_causal": causal,
+           "tiles_visited_pct": float(100.0 * visited / max(causal, 1)),
+           "pairs_in_window": total("pairs")}
+    if "gates" in att:
+        gates = total("gates")
+        out.update(
+            gate_mean=float(np.asarray(att["gate_sum"], np.float64).sum()
+                            / gates),
+            gate_closed_pct=float(100.0 * total("gate_closed") / gates))
+    return out
+
+
 def _exchange(kv: WorkerKVStore, tids: Sequence[int], leaves: list,
               on_pulled: Callable[[int, np.ndarray], None],
               scale: float = 1.0, divide: bool = False,
@@ -519,6 +548,10 @@ def run_worker(
                     if extra and extra[0].get("dsa_select") is not None:
                         with kv.trace_span("dsa.select", of=lambda: (
                                 _select_args(extra[0]["dsa_select"]))):
+                            pass
+                    if extra and extra[0].get("attn_window") is not None:
+                        with kv.trace_span("attn.window", of=lambda: (
+                                _window_args(extra[0]["attn_window"]))):
                             pass
             grad_s = time.perf_counter() - t0
             if due:

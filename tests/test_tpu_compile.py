@@ -438,3 +438,83 @@ def test_the_sparse_attention_kernels_for_the_v5e(one_chip):
             assert sum(bool(re.search(k["pattern"], c)) for c in calls) == 1, (
                 k, calls)
     assert compiled.memory_analysis().temp_size_in_bytes < 0.2e9
+
+
+def test_the_window_kernels_for_the_v5e(one_chip):
+    """Laguna's sliding-window attention at the cell's shapes: 18 q heads
+    over 2 k/v heads of 128, a 512 window over 8,192 positions in 512 x
+    512 tiles (``ops/window_attention.py``).  The v5e's compiler takes
+    the three kernels at the module's VMEM limit, the gradient calls
+    each once, each is found by the pattern ``window_attn_roofline_pct``
+    reads it by and by none of ``full_attn_roofline_pct``'s, and no [T,
+    T] array exists in the program (its temporaries are under 80 MB)."""
+    import jax
+    import jax.numpy as jnp
+
+    from geomx_tpu.ops import window_attention as wa
+
+    def arg(shape):
+        return jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+
+    def loss(q, k, v):
+        o = wa.window_attention(q, k, v, window=512)
+        return jnp.sum(o.astype(jnp.float32))
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        arg((1, 8192, 18, 128)), arg((1, 8192, 2, 128)),
+        arg((1, 8192, 2, 128))).compile()
+    calls = _kernel_calls(compiled.as_text())
+    assert len(calls) == 3, calls
+    window = _reader("window_attn_roofline_pct")["kernels"]
+    assert {k["fn"] for k in window} == {
+        "window_attn_fwd", "window_attn_bwd_dq", "window_attn_bwd_dkv"}
+    for k in window:
+        assert sum(bool(re.search(k["pattern"], c)) for c in calls) == 1, (
+            k, calls)
+    for k in _reader("full_attn_roofline_pct")["kernels"]:
+        assert not any(re.search(k["pattern"], c) for c in calls), (k, calls)
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.08e9
+
+
+def test_a_laguna_period_reads_its_kernels_apart_for_the_v5e(one_chip):
+    """A full attention layer and a sliding one at Laguna's widths (3,072
+    wide, 12 and 18 q heads over 2 k/v heads of 128, per-head gates,
+    YaRN over 64 channels), 8,192 positions under the layers'
+    checkpoint; a narrow FFN and a small vocabulary, which are not the
+    point.  The compiled gradient runs jax's flash kernels for the full
+    layer and the window kernels for the sliding one, and each reader's
+    patterns find only their own layer's calls: each layer's forward
+    kernel twice (again under the checkpoint) and its two backward
+    kernels."""
+    import jax
+    import jax.numpy as jnp
+
+    from geomx_tpu.models import transformer as tf
+
+    cfg = tf.TransformerConfig(
+        vocab=256, d_model=3072, n_heads=12, window_heads=18, n_kv_heads=2,
+        d_head=128, n_layers=2, d_ff=256, max_seq=8192,
+        layer_types=("full_attention", "sliding_attention"), window=512,
+        rope_theta=5e5, rope_dim=64, yarn=tf.Yarn(128.0, 8192),
+        window_rope_theta=1e4, head_gate=True, gated_ffn=True,
+        tied_head=False, attn_impl="flash", remat=True,
+        compute_dtype=jnp.bfloat16)
+    apply = tf.make_apply(cfg)
+    params = jax.tree_util.tree_map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip),
+        jax.eval_shape(lambda k: tf.init_params(cfg, k),
+                       jax.random.PRNGKey(0)))
+    x = jax.ShapeDtypeStruct((1, 8192), jnp.int32, sharding=one_chip)
+    compiled = jax.jit(jax.grad(
+        lambda p, x: tf.lm_loss(apply, p, x))).lower(params, x).compile()
+    calls = _kernel_calls(compiled.as_text())
+
+    def found(name):
+        return sorted(c for c in calls for k in _reader(name)["kernels"]
+                      if re.search(k["pattern"], c))
+
+    window, full = found("window_attn_roofline_pct"), found(
+        "full_attn_roofline_pct")
+    assert len(window) == 4 and all("window_attn" in c for c in window)
+    assert len(full) == 4 and not set(window) & set(full), (full, calls)
+    assert len(calls) == len(window) + len(full), calls
