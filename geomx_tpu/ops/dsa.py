@@ -33,7 +33,15 @@ kv chunks), named so that the trace finds them:
 * ``dsa_attn_fwd``: flash-style attention of all heads over the chosen
   set, the scores computed again by the same code (bit for bit what the
   selection saw), with the online log-sum-exp of the indexer's scores
-  over the chosen set and the cross-entropy above.
+  over the chosen set and the cross-entropy above.  Each head's row
+  statistics (running max, sum and the sum against the indexer's
+  scores) live in tiles of their own, ``[H, tile, 128]`` with the value
+  in every lane: no two heads share a state word, so the heads' softmax
+  chains do not wait on each other's stores, and the broadcast to a
+  score tile is a repeat of whole vregs, not a lane permute.  They are
+  gathered into ``[tile, H]`` once a q tile, when the row is done.  A
+  score off the chosen set is masked once, to ``MASKED`` (below every
+  running max), whose ``exp`` is 0.
 * ``dsa_attn_bwd_dq`` / ``dsa_attn_bwd_dkv``: the backward pass, q side
   and k/v side, each with the indexer's gradient on its side.
 
@@ -77,6 +85,10 @@ from jax.experimental.pallas import tpu as pltpu
 
 TILE = 512
 NEG = -1e30         # finite: exp() of a masked score under it is 0
+# a score off the chosen set, below NEG: a row's running max starts at
+# NEG and stays there until a chosen score, exp(MASKED - max) is 0
+MASKED = 2 * NEG
+LANES = 128         # the forward's row statistics fill a vreg's lanes
 INT_MIN = np.int32(np.iinfo(np.int32).min)
 TAU_NAME, SIGMA_NAME, VISITED_NAME = "dsa_tau", "dsa_sigma", "dsa_visited"
 O_NAME, LSE_NAME, LSEI_NAME = "dsa_o", "dsa_lse", "dsa_lsei"
@@ -224,13 +236,20 @@ def select(qi, ki, w, *, topk: int, tile: int = TILE):
 # dsa_attn_fwd
 # ---------------------------------------------------------------------------
 
+def _wide(x, n: int):
+    """A row statistic held in ``LANES`` equal lanes, ``[rows, LANES]``,
+    as ``[rows, n]``: repeated, or cut where ``n`` is narrower (the
+    tests' tiles and heads)."""
+    return x[:, :n] if n <= LANES else pltpu.repeat(x, n // LANES, 1)
+
+
 def _fwd_kernel(vis_ref, q_ref, k_ref, v_ref, qi_ref, ki_ref, w_ref,
                 tau_ref, sigma_ref, o_ref, lse_ref, lsei_ref, ce_ref,
                 n_ref, acc_ref, m_ref, l_ref, pi_ref, mi_ref, li_ref, *,
                 heads: int, scale: float):
     b, i, j = pl.program_id(0), pl.program_id(1), pl.program_id(2)
     nq, nk = pl.num_programs(1), pl.num_programs(2)
-    H, bq, _ = q_ref.shape
+    H, bq, D = q_ref.shape
     group = H // k_ref.shape[0]
     bk = k_ref.shape[1]
 
@@ -250,40 +269,49 @@ def _fwd_kernel(vis_ref, q_ref, k_ref, v_ref, qi_ref, ki_ref, w_ref,
         ch = _chosen(idx, tau_ref[...], sigma_ref[...], i * bq, j * bk)
         n_ref[...] += jnp.sum(jnp.where(ch, 1.0, 0.0), axis=1, keepdims=True)
         # the indexer's softmax over the chosen set, online
+        si = jnp.where(ch, idx, MASKED)
         mi = mi_ref[...]
-        mi_new = jnp.maximum(mi, jnp.max(jnp.where(ch, idx, NEG), axis=1,
-                                         keepdims=True))
+        mi_new = jnp.maximum(mi, jnp.max(si, axis=1, keepdims=True))
         li_ref[...] = li_ref[...] * jnp.exp(mi - mi_new) + jnp.sum(
-            jnp.where(ch, jnp.exp(idx - mi_new), 0.0), axis=1, keepdims=True)
+            jnp.exp(si - _wide(mi_new, bk)), axis=1, keepdims=True)
         mi_ref[...] = mi_new
         for h in range(H):
             g = h // group
             s = lax.dot_general(q_ref[h], k_ref[g], TRANS_B,
                                 preferred_element_type=jnp.float32) * scale
-            m = m_ref[:, h:h + 1]
-            m_new = jnp.maximum(m, jnp.max(jnp.where(ch, s, NEG), axis=1,
-                                           keepdims=True))
+            s = jnp.where(ch, s, MASKED)
+            m = m_ref[h]
+            m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
             alpha = jnp.exp(m - m_new)
-            p = jnp.where(ch, jnp.exp(s - m_new), 0.0)
-            l_ref[:, h:h + 1] = alpha * l_ref[:, h:h + 1] + jnp.sum(
-                p, axis=1, keepdims=True)
-            pi_ref[:, h:h + 1] = alpha * pi_ref[:, h:h + 1] + jnp.sum(
-                p * idx, axis=1, keepdims=True)
-            acc_ref[h] = alpha * acc_ref[h] + lax.dot_general(
+            p = jnp.exp(s - _wide(m_new, bk))
+            l_ref[h] = alpha * l_ref[h] + jnp.sum(p, axis=1, keepdims=True)
+            pi_ref[h] = alpha * pi_ref[h] + jnp.sum(p * idx, axis=1,
+                                                    keepdims=True)
+            acc_ref[h] = _wide(alpha, D) * acc_ref[h] + lax.dot_general(
                 p.astype(v_ref.dtype), v_ref[g], (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)
-            m_ref[:, h:h + 1] = m_new
+            m_ref[h] = m_new
 
     @pl.when(j == i)
     def _done():
-        l = l_ref[...]
+        lane = lax.broadcasted_iota(jnp.int32, (1, H), 1)
+
+        def by_head(ref):
+            """A lane-replicated ``[H, bq, LANES]`` statistic as ``[bq,
+            H]``."""
+            out = jnp.zeros(lse_ref.shape, jnp.float32)
+            for h in range(H):
+                out = jnp.where(lane == h, _wide(ref[h], H), out)
+            return out
+
         for h in range(H):
-            o_ref[h] = (acc_ref[h] / l[:, h:h + 1]).astype(o_ref.dtype)
-        lse_ref[...] = m_ref[...] + jnp.log(l)
-        lsei = mi_ref[...] + jnp.log(li_ref[...])
+            o_ref[h] = (acc_ref[h] / _wide(l_ref[h], D)).astype(o_ref.dtype)
+        l = by_head(l_ref)
+        lse_ref[...] = by_head(m_ref) + jnp.log(l)
+        lsei = mi_ref[:, :1] + jnp.log(li_ref[:, :1])
         lsei_ref[...] = lsei
         # H(p, softmax(I)) = lse(I) - sum_s p_s I_s, p the heads' mean
-        ce_ref[...] = lsei - jnp.sum(pi_ref[...] / l, axis=1,
+        ce_ref[...] = lsei - jnp.sum(by_head(pi_ref) / l, axis=1,
                                      keepdims=True) / H
 
 
@@ -318,11 +346,11 @@ def _fwd(q, k, v, qi, ki, w, tau, sigma, visited, tile):
             out_specs=[pl.BlockSpec((None, H, tile, D), heads_at),
                        vec(H), vec(1), vec(1), vec(1)],
             scratch_shapes=[pltpu.VMEM((H, tile, D), f32),
-                            pltpu.VMEM((tile, H), f32),
-                            pltpu.VMEM((tile, H), f32),
-                            pltpu.VMEM((tile, H), f32),
-                            pltpu.VMEM((tile, 1), f32),
-                            pltpu.VMEM((tile, 1), f32)]),
+                            pltpu.VMEM((H, tile, LANES), f32),
+                            pltpu.VMEM((H, tile, LANES), f32),
+                            pltpu.VMEM((H, tile, LANES), f32),
+                            pltpu.VMEM((tile, LANES), f32),
+                            pltpu.VMEM((tile, LANES), f32)]),
         out_shape=[jax.ShapeDtypeStruct((B, H, T, D), q.dtype),
                    jax.ShapeDtypeStruct((B, T, H), f32),
                    jax.ShapeDtypeStruct((B, T, 1), f32),

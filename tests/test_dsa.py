@@ -159,17 +159,71 @@ def test_rows_before_topk_are_dense_causal():
         head, (B, TOPK, T)))
 
 
-def test_forward_matches_the_equations():
+def rows_apart(case):
+    """Seed 3's inputs with the last q tile's rows split by the indexer
+    between kv tiles (every key of a tile points one way, a row's query
+    the way of the 8 keys it is to choose, which then score 2 and every
+    other key 0):
+
+    * ``"first_tile_holds_none"``: rows 24-27 choose kv tile 0, rows
+      28-31 kv tile 2, so tile 0 is the first the q tile visits and holds
+      none of rows 28-31's keys;
+    * ``"a_visited_tile_holds_none"``: rows 24-27 choose keys 0-7, rows
+      28-31 keys 0-3 and 16-19, so every row has keys in tile 0 and rows
+      24-27 none in tile 2, which is visited.
+
+    The last q tile's attention scores sit near -50, so that a mask
+    that is not far below every score would move the row's running max
+    off its keys."""
     q, k, v, qi, ki, w = inputs(3)
-    o, ce, stats = run(q, k, v, qi, ki, w)
-    o_ref, ce_ref, ch = plain(q, k, v, qi, ki, w)
+    u = np.eye(D, dtype=np.float32)[0]
+    k = 0.1 * k + u
+    q = q.at[:, 24:].set(0.1 * q[:, 24:] - 50 * np.sqrt(D) * u)
+    one = np.eye(E, dtype=np.float32)
+    keys = np.tile(one[3], (B, T, 1))      # no row below chooses them
+    qrow = np.abs(np.asarray(qi))
+    lo, hi = slice(24, 28), slice(28, 32)
+    if case == "first_tile_holds_none":
+        keys[:, 0:8], keys[:, 16:24] = one[0], one[2]
+        qrow[:, lo], qrow[:, hi] = one[0], one[2]
+    else:
+        keys[:, 0:4], keys[:, 4:8], keys[:, 16:20] = one[0], one[1], one[2]
+        qrow[:, lo], qrow[:, hi] = one[0] + one[1], one[0] + one[2]
+    w = jnp.abs(w).at[:, 24:].set(1.0)
+    return q, k, v, jnp.asarray(qrow), jnp.asarray(keys), w
+
+
+@pytest.mark.parametrize("case", ["seeded", "first_tile_holds_none",
+                                  "a_visited_tile_holds_none"])
+def test_forward_matches_the_equations(case):
+    """Seeded inputs, and the two edges of the one mask a score takes: a
+    row with no key yet keeps its running max at ``NEG`` through a
+    visited tile, and a row with none in a later visited tile adds
+    nothing there."""
+    args = inputs(3) if case == "seeded" else rows_apart(case)
+    o, ce, stats = run(*args)
+    o_ref, ce_ref, ch = plain(*args)
     np.testing.assert_allclose(o, o_ref, rtol=1e-5, atol=1e-5)
     np.testing.assert_allclose(ce, ce_ref, rtol=1e-5, atol=1e-5)
+    xo, xce, _ = jax.jit(lambda *a: dsa.dsa_attention_xla(
+        *a, topk=TOPK, tile=TILE))(*args)
+    np.testing.assert_allclose(o, xo, rtol=1e-5, atol=1e-5)
+    np.testing.assert_allclose(ce, xce, rtol=1e-5, atol=1e-5)
     pairs = int(np.minimum(np.arange(T) + 1, TOPK).sum()) * B
     assert int(stats["chosen_pairs"]) == pairs == int(
         stats["attended_pairs"]) == int(np.sum(ch))
     n = T // TILE
     assert int(stats["tiles_causal"]) == B * n * (n + 1) // 2
+    # the chosen keys of each row in each kv tile, and the tiles visited
+    per = np.asarray(ch).reshape(B, T, n, TILE).sum(-1)
+    visited = per.reshape(B, n, TILE, n).any(2)
+    last = per[:, 24:]                      # the last q tile's rows
+    first = int(np.argmax(visited[0, -1]))
+    if case == "first_tile_holds_none":
+        assert first == 0 and (last[:, :, first] == 0).any()
+    elif case == "a_visited_tile_holds_none":
+        assert (last[:, :, first] > 0).all()
+        assert visited[:, -1, 2].all() and (last[:, :, 2] == 0).any()
 
 
 def test_gradients_match_the_equations_and_stay_on_their_side():
