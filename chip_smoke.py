@@ -355,12 +355,18 @@ def train_phase(cfg: SmokeConfig, n_layers: int, devices,
     if compression != "none":
         assert sum(st["codec_device_ms"] for st in stats.values()) > 0, (
             "the device codecs never ran")
+    # on the chip every Bi-Sparse encode compacts with the kernel
+    kernel = sum(st.get("codec_bsc_kernel_encodes", 0)
+                 for st in stats.values())
+    picks = sum(st.get("mpq_bsc_picks", 0) for st in stats.values())
+    assert kernel == (picks if platform == "tpu" else 0), (kernel, picks)
     assert wan > 0, "nothing crossed the WAN tier"
 
     ends = out["ends"][0]
     walls = np.diff([t0] + ends[:-1])
     _say(f"{compression}: {layout}; loss "
          + " ".join(f"{x:.4f}" for x in out["losses"][0])
+         + f"; {kernel} Bi-Sparse encodes by the kernel"
          + f"; compile-step wall {walls[0]:.1f}s, then "
          + " ".join(f"{w:.2f}s" for w in walls[1:])
          + f" a step; WAN {wan / 1e6:.1f} MB/step; servers on jax/{platform}"

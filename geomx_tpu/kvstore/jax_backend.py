@@ -291,6 +291,8 @@ class JaxBackend(MergeBackend):
         self.codec_device_ms = 0.0
         self.codec_d2h_bytes = 0
         self.codec_host_bytes = 0
+        # Bi-Sparse encodes whose compaction the TPU kernel did
+        self.codec_bsc_kernel_encodes = 0
 
     # ---- staging ------------------------------------------------------------
     def _stage(self, v: np.ndarray, device):
@@ -634,6 +636,8 @@ class JaxBackend(MergeBackend):
                     "codec_device_ms": round(self.codec_device_ms, 3),
                     "codec_d2h_bytes": self.codec_d2h_bytes,
                     "codec_host_bytes": self.codec_host_bytes,
+                    "codec_bsc_kernel_encodes":
+                        self.codec_bsc_kernel_encodes,
                     "h2d_bytes": self.h2d_bytes,
                     "d2h_bytes": self.d2h_bytes,
                     "cow_bytes": self.cow_bytes}
@@ -1250,38 +1254,22 @@ class DeviceTwoBitCodec(DeviceCodec):
 _SUPPORT_BLOCK = 256
 
 
-def _topk_support(u, k: int):
-    """The exact top-``k`` of ``|u|`` without a sort: ``(idx, mask)``,
-    ``idx`` the ``k`` selected positions ascending (int32), ``mask``
-    their indicator over ``u``.  The set is the one a stable descending
-    sort of ``|u|`` picks: ties at the k-th magnitude go to the lowest
-    index, NaN ranks above ``inf``.
+def _threshold(bits, k: int):
+    """The exact top-``k`` of ``bits`` (``(rows, B)`` int32 order bits of
+    ``|u|``, NaN ``0x7FFFFFFF``, padding -1): ``(t, cut, p, mask)``, ``t``
+    the ``k``-th largest, ``cut`` the flat position of the last tie at
+    ``t`` taken, ``p`` each row's inclusive prefix count of the selected
+    and ``mask`` their indicator.  The set is the one a stable descending
+    sort picks: ties at the k-th magnitude go to the lowest index.
 
-    ``|u|`` orders like its bits as int32, so the k-th largest comes from
-    a radix search, 4 bits a pass, by counting (reductions over ``n``
-    only; one loop body for the 8 passes).  The mask is compacted at the
-    scale of ``k`` and ``n / B``: block counts, their prefix sums, each
-    output slot's block and its rank there from two ``k``-long arrays
-    that mark where a block ends, one row gather of ``(k, B)`` mask bits
-    and the position of that rank in the row.  No sort, and no scatter or
-    element gather over ``n``: a TPU prices those at 7-11 ns an element.
-
-    What a run pays for beside the device's time is the number of
-    operations the program EXECUTES: every one is an event that a
-    profiler has to convert and write when it stops (150 an encode cost
-    the benchmark 7 s of set-up, 100 cost nothing: PR 32).  So whatever
-    is a scalar stays a scalar (the scalar core runs it inside the
-    neighbouring operation), and two prefix sums ride on one scan."""
+    The k-th largest comes from a radix search, 4 bits a pass, by
+    counting (reductions over ``n`` only; one loop body for the 8
+    passes)."""
     import jax
     import jax.numpy as jnp
 
     i32 = jnp.int32
-    n, B = u.shape[0], _SUPPORT_BLOCK
-    nb = -(-n // B)
-    mag = jnp.abs(u)
-    bits = jnp.where(mag != mag, np.int32(0x7FFFFFFF),
-                     jax.lax.bitcast_convert_type(mag, i32))
-    bits = jnp.pad(bits, (0, nb * B - n), constant_values=-1).reshape(nb, B)
+    nb, B = bits.shape
 
     def radix_pass(i, carry):
         # t: the k-th largest's bits above this pass's, zeros below;
@@ -1317,11 +1305,45 @@ def _topk_support(u, k: int):
                               dtype=i32)
     flat = jnp.arange(nb * B, dtype=i32).reshape(nb, B)
     mask = (bits > t) | (tie & (flat <= cut))
+    return t, cut, pre[1] + jnp.minimum(p_tie, k - gt), mask
+
+
+def _topk_support(u, k: int):
+    """The exact top-``k`` of ``|u|`` without a sort, in plain XLA:
+    ``(idx, mask)``, ``idx`` the ``k`` selected positions ascending
+    (int32), ``mask`` their indicator over ``u``; NaN ranks above
+    ``inf`` (:func:`_threshold`).
+
+    The mask is compacted at the scale of ``k`` and ``n / B``: block
+    counts, their prefix sums, each output slot's block and its rank
+    there from two ``k``-long arrays that mark where a block ends, one
+    row gather of ``(k, B)`` mask bits and the position of that rank in
+    the row.  No sort, and no scatter or element gather over ``n``: a
+    TPU prices those at 7-11 ns an element.  On a TPU the encoder
+    compacts with a kernel instead (:func:`_topk_frame_on_chip`); this
+    is every other backend's path, and the kernel's oracle.
+
+    What a run pays for beside the device's time is the number of
+    operations the program EXECUTES: every one is an event that a
+    profiler has to convert and write when it stops (150 an encode cost
+    the benchmark 7 s of set-up, 100 cost nothing).  So whatever
+    is a scalar stays a scalar (the scalar core runs it inside the
+    neighbouring operation), and two prefix sums ride on one scan."""
+    import jax
+    import jax.numpy as jnp
+
+    i32 = jnp.int32
+    n, B = u.shape[0], _SUPPORT_BLOCK
+    nb = -(-n // B)
+    mag = jnp.abs(u)
+    bits = jnp.where(mag != mag, np.int32(0x7FFFFFFF),
+                     jax.lax.bitcast_convert_type(mag, i32))
+    bits = jnp.pad(bits, (0, nb * B - n), constant_values=-1).reshape(nb, B)
+    _, _, p, mask = _threshold(bits, k)
 
     # p[b]: selected up to and with block b, which is where it ends among
     # the output's slots.  A 1 there counts the blocks before a slot, the
     # block's size there sums to the slot its block starts at
-    p = pre[1] + jnp.minimum(p_tie, k - gt)
     sizes = jnp.diff(p, prepend=0)
     ends = jnp.zeros(k, i32)
     at = jax.lax.cumsum(
@@ -1336,30 +1358,87 @@ def _topk_support(u, k: int):
     return blk * B + pos, mask.reshape(-1)[:n]
 
 
-@functools.lru_cache(maxsize=None)
-def _bsc_encoder():
-    """The Bi-Sparse encoder, jitted ONCE for the process: it is pure
-    (state and momentum are arguments, ``k`` static), so every
-    :class:`DeviceBscCodec` calls this one and a tensor length is
-    traced, lowered and fetched once however many servers encode it."""
+def _topk_frame(u, k: int):
+    """Every backend but the TPU: ``(vals, idx, mask)`` from
+    :func:`_topk_support`, ``vals`` the selected raw words (int32)."""
     import jax
+    import jax.numpy as jnp
+
+    idx, mask = _topk_support(u, k)
+    return jax.lax.bitcast_convert_type(u[idx], jnp.int32), idx, mask
+
+
+def _topk_frame_on_chip(u, k: int):
+    """The TPU's :func:`_topk_frame`: the same selection, searched over
+    ``u``'s ``(rows, 128)`` words, and compacted by one Pallas kernel
+    (:func:`geomx_tpu.ops.compact.compact`) in place of the scatters, the
+    row gather and the element gather."""
+    import jax
+    import jax.numpy as jnp
+
+    from geomx_tpu.ops import compact
+
+    n, lanes = u.shape[0], compact.LANES
+    rows = compact.padded_rows(n)
+    words = jax.lax.bitcast_convert_type(u, jnp.int32)
+    bits = compact.order_bits(words)
+    if rows * lanes != n:
+        words = jnp.pad(words, (0, rows * lanes - n))
+        bits = jnp.pad(bits, (0, rows * lanes - n), constant_values=-1)
+    words, bits = words.reshape(rows, lanes), bits.reshape(rows, lanes)
+    t, cut, p, mask = _threshold(bits, k)
+    vals, idx = compact.compact(words, p, t, cut, k)
+    return vals, idx, mask.reshape(-1)[:n]
+
+
+def _bsc_step(frame):
+    """The Bi-Sparse encode with ``frame`` (:func:`_topk_frame` or its
+    TPU twin) choosing the support: velocity and accumulator updates,
+    the frame's ``[value words ‖ indices]`` and the state with the
+    support zeroed."""
     import jax.numpy as jnp
 
     def enc(v, u, g, m, k):
         v = m * v + g
         u = u + v
-        idx, mask = _topk_support(u, k)
-        vals = u[idx]
+        vals, idx, mask = frame(u, k)
         v = jnp.where(mask, np.float32(0.0), v)
         u = jnp.where(mask, np.float32(0.0), u)
         # the frame leaves the device as int32 WORDS: XLA:TPU lowers
         # a float concatenate to maximum(pad, pad), which flushes the
         # index bits (denormals as f32) next to the halves' seam
-        wire = jnp.concatenate([
-            jax.lax.bitcast_convert_type(vals, jnp.int32), idx])
-        return wire, v, u
+        return jnp.concatenate([vals, idx]), v, u
 
-    return jax.jit(enc, static_argnums=(4,), donate_argnums=(0, 1))
+    return enc
+
+
+def _lives_on_tpu(arr) -> bool:
+    """Whether ``arr`` lives on a TPU: then the encoder's program was
+    lowered for one, and its compaction is the kernel."""
+    return all(d.platform == "tpu" for d in arr.devices())
+
+
+def _frame_where_u_lives(u, k: int):
+    """The kernel's compaction where ``u`` lives on a TPU, plain XLA on
+    every other backend (chosen as the program is lowered)."""
+    import jax
+
+    return jax.lax.platform_dependent(
+        u, tpu=functools.partial(_topk_frame_on_chip, k=k),
+        default=functools.partial(_topk_frame, k=k))
+
+
+@functools.lru_cache(maxsize=None)
+def _bsc_encoder():
+    """The Bi-Sparse encoder, jitted ONCE for the process: it is pure
+    (state and momentum are arguments, ``k`` static), so every
+    :class:`DeviceBscCodec` calls this one and a tensor length is
+    traced, lowered and fetched once however many servers encode it.
+    The frame is the same bit for bit on either path."""
+    import jax
+
+    return jax.jit(_bsc_step(_frame_where_u_lives), static_argnums=(4,),
+                   donate_argnums=(0, 1))
 
 
 class DeviceBscCodec(DeviceCodec):
@@ -1395,9 +1474,14 @@ class DeviceBscCodec(DeviceCodec):
             v = self._jnp.zeros_like(g)
             u = self._jnp.zeros_like(g)
         k = max(1, int(self.ratio * n))
+        kernel = _lives_on_tpu(u)
         wire, v, u = self._enc(v, u, g, np.float32(self.momentum), k)
         self._velocity[key] = v
         self._accum[key] = u
+        if kernel:
+            be = self._stage._be
+            with be._mu:
+                be.codec_bsc_kernel_encodes += 1
         self._stage._bill(t0)
         return self._stage._wire(wire).view(np.float32)
 

@@ -3,6 +3,7 @@ without a chip.
 
     python scripts/kernel_bundles.py dsa_fwd --seq 4096
     python scripts/kernel_bundles.py dsa --seq 4096 --dump /tmp/llo
+    python scripts/kernel_bundles.py bsc_compact --seq 16777216
     python scripts/kernel_bundles.py some.module:build
 
 A child process compiles the target ahead of time for one chip of a
@@ -20,7 +21,8 @@ installed: that exit is read as success where the dump is there.  One
 process at a time may hold libtpu: run one profile at a time.
 
 Targets: ``dsa_fwd`` (the sparse attention's forward kernel) and ``dsa``
-(its gradient: all four kernels) at Keye-VL's widths, or
+(its gradient: all four kernels) at Keye-VL's widths, ``bsc_compact``
+(the Bi-Sparse encoder's compaction, ``--seq`` the tensor's length), or
 ``module:function``, a callable ``(sharding, seq) -> (fn, args)`` whose
 ``args`` are ``jax.ShapeDtypeStruct`` on that sharding.
 """
@@ -79,7 +81,26 @@ def dsa_grad(sharding, seq):
     return jax.grad(loss, argnums=tuple(range(6))), dsa_args(sharding, seq)
 
 
-TARGETS = {"dsa_fwd": dsa_fwd, "dsa": dsa_grad}
+def bsc_compact(sharding, seq):
+    """The Bi-Sparse encoder's compaction kernel for a tensor of ``seq``
+    elements at the codec's 1%."""
+    import jax
+    import jax.numpy as jnp
+
+    from geomx_tpu.ops import compact
+
+    rows = compact.padded_rows(seq)
+
+    def fn(words, p, tc):
+        return compact.compact(words, p, tc[0], tc[1], max(1, seq // 100))
+
+    return fn, (jax.ShapeDtypeStruct((rows, compact.LANES), jnp.int32,
+                                     sharding=sharding),
+                jax.ShapeDtypeStruct((rows,), jnp.int32, sharding=sharding),
+                jax.ShapeDtypeStruct((2,), jnp.int32, sharding=sharding))
+
+
+TARGETS = {"dsa_fwd": dsa_fwd, "dsa": dsa_grad, "bsc_compact": bsc_compact}
 
 
 def _child(target: str, seq: int) -> None:

@@ -331,6 +331,35 @@ def test_bsc_encoder_lowers_to_a_compact_module_named_enc():
     assert re.match(r"module @jit_enc\b", text), text[:80]
 
 
+def test_bsc_kernel_encodes_counts_only_the_kernels_compactions(
+        monkeypatch):
+    """``codec_bsc_kernel_encodes`` counts the Bi-Sparse encodes whose
+    compaction the TPU kernel did, which is those whose state lives on
+    a TPU: none here.  Told that a tensor's state lives on one, the
+    backend counts that tensor's Bi-Sparse encodes and nothing else (not
+    the fp16 rung of MPQ, not the other tensor)."""
+    from geomx_tpu.kvstore import jax_backend
+
+    stage = _stage()
+    mpq = stage.make_push_codec({"type": "mpq", "size_bound": 1000})
+    small, large, other = 512, 4096, 6000
+    for r in range(2):
+        for n in (small, large, other):
+            mpq.select(n).compress(n, _grad(n, seed=r))
+    stats = stage._be.stats()
+    assert stats["codec_bsc_kernel_encodes"] == 0
+    assert stats["codec_device_ms"] > 0
+
+    lives_on_tpu = jax_backend._lives_on_tpu
+    monkeypatch.setattr(jax_backend, "_lives_on_tpu",
+                        lambda arr: arr.shape[0] == large
+                        or lives_on_tpu(arr))
+    for r in range(3):
+        for n in (small, large, other):
+            mpq.select(n).compress(n, _grad(n, seed=r))
+    assert stage._be.stats()["codec_bsc_kernel_encodes"] == 3
+
+
 def test_mpq_selector_is_isinstance_compatible_and_splits():
     """The device MPQ subclasses the numpy selector (the server's
     isinstance dispatch and QUERY_STATS counters must keep working)

@@ -73,7 +73,8 @@ def test_bsc_encoder_for_the_v5e_has_no_sort_and_keeps_its_name(one_chip):
     keeps ``peak_hbm_GB`` where it was; and a call executes few enough
     operations that stopping a profiler's trace of six steps stays
     cheap (52 encodes a step: at 147 a call the benchmark's set-up grew
-    by 7 s, at 98 by nothing; PR 32)."""
+    by 7 s, at 98 by nothing).  The compaction is the
+    ``bsc_compact`` kernel there, with no gather or scatter beside it."""
     import jax
     import jax.numpy as jnp
 
@@ -88,6 +89,39 @@ def test_bsc_encoder_for_the_v5e_has_no_sort_and_keeps_its_name(one_chip):
     assert not re.search(r"\bsort\(", text)
     assert compiled.memory_analysis().temp_size_in_bytes <= 8 * n
     assert _executed_operations(text, trips=8) <= 115
+    _compacts_with_the_kernel(text)
+
+
+def _compacts_with_the_kernel(text: str) -> None:
+    """On the chip the compaction is one Pallas kernel, ``bsc_compact``,
+    and no element gather or scatter is left in the encoder."""
+    from benchmark.lib.trace import op_name
+
+    calls = [op_name(line.strip()) for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(calls) == 1 and calls[0].startswith("bsc_compact"), calls
+    assert not re.search(r"\b(gather|scatter)\(", text)
+
+
+def test_bsc_encoder_for_the_v5e_at_the_smaller_tensors_compacts_on_the_mxu(
+        one_chip):
+    """The flagship's 4,194,304-element tensors (34 of a step's 52
+    encodes): the same program under the same limits as the largest."""
+    import jax
+    import jax.numpy as jnp
+
+    from geomx_tpu.kvstore.jax_backend import _bsc_encoder
+
+    n = 4 * 1024 * 1024
+    vec = jax.ShapeDtypeStruct((n,), jnp.float32, sharding=one_chip)
+    m = jax.ShapeDtypeStruct((), jnp.float32, sharding=one_chip)
+    compiled = _bsc_encoder().lower(vec, vec, vec, m, int(0.01 * n)).compile()
+    text = compiled.as_text()
+    assert re.match(r"HloModule jit_enc\b", text), text[:80]
+    assert not re.search(r"\bsort\(", text)
+    assert compiled.memory_analysis().temp_size_in_bytes <= 8 * n
+    assert _executed_operations(text, trips=8) <= 115
+    _compacts_with_the_kernel(text)
 
 
 @pytest.mark.parametrize("shape", [(4, 2048, 16, 128), (4, 2048, 2, 1024)],
